@@ -28,15 +28,20 @@ _jax.config.update("jax_enable_x64", True)
 # Persistent XLA compilation cache: sort-heavy query programs cost tens
 # of seconds to minutes of TPU compile; the cache makes that a
 # once-per-shape cost across processes (reference analogue: compiled
-# PageProcessor caches, SURVEY.md §2.1 "Expression JIT"). Opt out with
-# PRESTO_TPU_COMPILE_CACHE=off.
-_cache_dir = _os.environ.get(
-    "PRESTO_TPU_COMPILE_CACHE",
-    _os.path.join(_os.path.dirname(_os.path.dirname(__file__)), ".jax_cache"),
-)
-if _cache_dir.lower() not in ("off", "0", "none", ""):
-    _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# PageProcessor caches, SURVEY.md §2.1 "Expression JIT"). Where
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and no directory
+# is set in code; otherwise the cache sits at a fixed path inside the
+# checkout (the path is part of the cache key, so it must not move).
+# JAX_ENABLE_COMPILATION_CACHE=0 is JAX's own off switch.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(
+            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+            ".jax_cache",
+        ),
+    )
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 __version__ = "0.1.0"
 

@@ -2000,9 +2000,10 @@ class ExprLowerer:
                 * jnp.uint64(0x9E3779B97F4A7C15)
             ) ^ d[..., 1].astype(jnp.uint64)
         elif at.name in ("double", "real"):
-            f = jnp.asarray(d, jnp.float64)
-            f = jnp.where(f == 0, 0.0, f)  # +0.0 and -0.0 are SQL-equal
-            x = f.view(jnp.int64).astype(jnp.uint64)
+            from presto_tpu.ops.common import float_bits_i64
+
+            # +0.0 and -0.0 are SQL-equal (one bit pattern)
+            x = float_bits_i64(d).astype(jnp.uint64)
         else:
             x = jnp.asarray(d).astype(jnp.int64).astype(jnp.uint64)
         # splitmix64 finalizer (public-domain mixing constants), folded

@@ -57,8 +57,13 @@ from jax import lax
 
 from presto_tpu import types as T
 from presto_tpu.expr import Expr, ExprLowerer
-from presto_tpu.ops.common import boundaries, sort_order
-from presto_tpu.page import Block, Page
+from presto_tpu.ops.common import (
+    boundaries,
+    cumsum,
+    lexsort_u32,
+    sort_order,
+)
+from presto_tpu.page import Block, Page, nonzero_1d
 
 
 @dataclasses.dataclass(frozen=True)
@@ -257,7 +262,7 @@ def _onehot_aggregate(
     overflow = num_groups > max_groups
 
     # occupied segments compacted to the front, ascending (lexicographic)
-    (sel,) = jnp.nonzero(occupied, size=max_groups, fill_value=nseg)
+    sel = nonzero_1d(occupied, max_groups, nseg)
     safe_sel = jnp.minimum(sel, nseg - 1).astype(jnp.int32)
 
     names: List[str] = []
@@ -400,7 +405,7 @@ def _group_spans(
     safe because rows past the live prefix carry neutral values for every
     accumulator (0 for cumsum deltas, +-inf fills for min/max scans).
     """
-    (starts,) = jnp.nonzero(bnd, size=max_groups, fill_value=cap)
+    starts = nonzero_1d(bnd, max_groups, cap)
     nxt = jnp.concatenate(
         [starts[1:], jnp.full((1,), cap, starts.dtype)]
     )
@@ -551,7 +556,7 @@ def _cumsum_span(
 ) -> jnp.ndarray:
     """Per-group totals of ``w`` via inclusive cumsum differenced over
     [start, end] spans (no scatter)."""
-    c = jnp.cumsum(w)
+    c = cumsum(w)
     return c[ends] - c[starts] + w[starts]
 
 
@@ -594,9 +599,7 @@ def _sorted_one_agg(
         # starts (cap-1) would read total-1 on a completely full page
         # and silently drop the last group's last element, so detect
         # padding from the UNCLAMPED boundary positions
-        (raw_starts,) = jnp.nonzero(
-            bnd, size=starts.shape[0], fill_value=cap
-        )
+        raw_starts = nonzero_1d(bnd, starts.shape[0], cap)
         start_off = jnp.where(
             raw_starts >= cap,
             total,
@@ -748,7 +751,7 @@ def _global_one_agg(
         )
         # stable-compact kept values to the front (single global array;
         # NULL inputs skipped — documented deviation from include-nulls)
-        order = jnp.argsort(~keep, stable=True)
+        order = lexsort_u32([(~keep).astype(jnp.uint32)])
         n = jnp.sum(keep).astype(jnp.int32)
         dictionary = None
         if agg.arg.dtype.is_string:

@@ -11,9 +11,41 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from presto_tpu import types as T
+
+
+def float_bits_i64(data: jnp.ndarray) -> jnp.ndarray:
+    """The IEEE754 binary64 bit pattern of a float column as int64,
+    with -0.0 read as +0.0 and every NaN as the canonical quiet NaN.
+
+    Built by arithmetic, not by ``f.view(int64)``: the v5e compiler
+    refuses a bitcast *from* float64 (``UNIMPLEMENTED ... X64 element
+    types ... bitcast-convert``; the int64 -> float64 direction, used
+    below for the exact power of two, compiles). ``log2`` only guesses
+    the exponent; the two corrections make it exact, so on IEEE
+    hardware the result equals the bitcast bit for bit (the host twin
+    ``exec.host_ops.orderable_np`` relies on that). Subnormals read as
+    zero, as they always have on the device: XLA flushes them."""
+    f = jnp.asarray(data, jnp.float64)
+    a = jnp.abs(f)
+    nan = f != f
+    inf = a == jnp.inf
+    normal = ~nan & ~inf & (a >= 2.0 ** -1022)
+    s = jnp.where(normal, a, 1.0)
+    e = jnp.clip(jnp.floor(jnp.log2(s)).astype(jnp.int64), -1022, 1023)
+    q = s / ((e + 1023) << 52).view(jnp.float64)  # s / 2**e, exact
+    low = q < 1.0
+    e, q = jnp.where(low, e - 1, e), jnp.where(low, q * 2.0, q)
+    high = q >= 2.0
+    e, q = jnp.where(high, e + 1, e), jnp.where(high, q * 0.5, q)
+    mag = ((e + 1023) << 52) + ((q - 1.0) * 2.0 ** 52).astype(jnp.int64)
+    mag = jnp.where(normal, mag, 0)
+    mag = jnp.where(inf, jnp.int64(0x7FF0000000000000), mag)
+    bits = jnp.where(f < 0, mag | jnp.int64(-(2 ** 63)), mag)
+    return jnp.where(nan, jnp.int64(0x7FF8000000000000), bits)
 
 
 def orderable_i64(data: jnp.ndarray, dtype: T.DataType) -> jnp.ndarray:
@@ -22,7 +54,8 @@ def orderable_i64(data: jnp.ndarray, dtype: T.DataType) -> jnp.ndarray:
     - ints/dates/decimals/dict-ids: widen to int64 (dict ids are
       order-preserving by construction, presto_tpu.page.Dictionary)
     - floats: sign-magnitude bit trick (IEEE754 totally ordered for
-      non-NaN; NaN sorts last as in the reference's ORDER BY)
+      non-NaN; -0.0 = +0.0; NaN sorts last as in the reference's
+      ORDER BY)
     """
     if dtype.is_long_decimal:
         # a (cap, 2) limb pair does not fit ONE orderable int64 — the
@@ -34,9 +67,7 @@ def orderable_i64(data: jnp.ndarray, dtype: T.DataType) -> jnp.ndarray:
             "int64 lane — use key_lanes()"
         )
     if dtype.name in ("double", "real"):
-        f = jnp.asarray(data, jnp.float64)
-        f = jnp.where(f == 0, 0.0, f)  # -0.0 and +0.0 are SQL-equal
-        bits = f.view(jnp.int64)
+        bits = float_bits_i64(data)
         # IEEE754 total order as signed int64: positives keep their bit
         # pattern in [0, 2^63); negatives map to ~bits with the sign bit
         # set, landing in [-2^63, 0) in reversed-magnitude order.
@@ -62,6 +93,70 @@ def key_lanes(data: jnp.ndarray, dtype: T.DataType) -> List[jnp.ndarray]:
     return [orderable_i64(data, dtype)]
 
 
+def cumsum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running sum along axis 0. Floats go through
+    ``lax.associative_scan``: ``jnp.cumsum`` lowers to a
+    ``reduce_window``, and over float64 — emulated on the TPU — the v5e
+    compiler spends minutes of code generation on it (65536 rows: more
+    than 150 s against 3.4 s for the scan's log-depth adds; PR 22,
+    compiled for the described chip). Integer cumsums compile in
+    seconds as they are."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jax.lax.associative_scan(jnp.add, x)
+    return jnp.cumsum(x)
+
+
+_SIGN32 = 0x80000000
+
+
+def _u32_lanes(lane: jnp.ndarray, narrow: bool) -> List[jnp.ndarray]:
+    """An int64 sort lane as uint32 lanes, LEAST significant first,
+    whose unsigned lexicographic order is the lane's signed order.
+    ``narrow``: the values are known to fit int32 (the column is
+    stored in 32 bits or fewer), so one lane carries them."""
+    if narrow:
+        return [lane.astype(jnp.int32).astype(jnp.uint32) ^ _SIGN32]
+    lo = (lane & 0xFFFFFFFF).astype(jnp.uint32)
+    hi = (lane >> 32).astype(jnp.int32).astype(jnp.uint32) ^ _SIGN32
+    return [lo, hi]
+
+
+def lexsort_u32(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    """``jnp.lexsort`` over uint32 lanes (last lane primary) as one
+    stable single-key sort per lane, least significant first, with the
+    int32 permutation riding as the only payload.
+
+    Why not ONE variadic sort: the v5e compiler's time for a sort grows
+    steeply with the comparator's operand count and hardly with the
+    row count — a three-key int64 ``lexsort`` (what a one-column ORDER
+    BY used to lower to) compiled for 374 s at 1 M rows and 125 s at
+    16,384, against 49 s and 7 s for the passes here, each the same
+    two-operand program (PR 22; compile times from compiling for the
+    described chip on the sandbox's CPU, run time not measured)."""
+    perm = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
+    for i, lane in enumerate(lanes):
+        key = lane if i == 0 else lane[perm]
+        _, perm = jax.lax.sort((key, perm), num_keys=1, is_stable=True)
+    return perm
+
+
+def argsort_i64(x: jnp.ndarray) -> jnp.ndarray:
+    """Stable argsort of one int64 key (int32 permutation), as two
+    uint32 passes — see :func:`lexsort_u32`."""
+    return lexsort_u32(_u32_lanes(jnp.asarray(x, jnp.int64), False))
+
+
+def _is_narrow(data: jnp.ndarray, dtype: T.DataType) -> bool:
+    """True when ``orderable_i64`` of this column fits int32."""
+    if dtype.is_long_decimal or dtype.name in ("double", "real"):
+        return False
+    dt = jnp.asarray(data).dtype
+    return dt == jnp.bool_ or (
+        jnp.issubdtype(dt, jnp.integer) and dt.itemsize <= 4
+        and dt != jnp.uint32
+    )
+
+
 def sort_order(
     keys: Sequence[Tuple[jnp.ndarray, Optional[jnp.ndarray], T.DataType]],
     live: jnp.ndarray,
@@ -80,23 +175,21 @@ def sort_order(
     descending = descending or [False] * n
     nulls_first = nulls_first or [d for d in descending]
     lex: List[jnp.ndarray] = []
-    # jnp.lexsort: LAST key is primary -> emit least-significant first
+    # lexsort order: LAST lane is primary -> emit least-significant first
     for (data, valid, dtype), desc, nf in zip(
         reversed(list(keys)), reversed(list(descending)), reversed(list(nulls_first))
     ):
-        lanes = key_lanes(data, dtype)
-        if desc:
+        narrow = _is_narrow(data, dtype)
+        for lane in reversed(key_lanes(data, dtype)):
             # bitwise-not reverses order without INT64_MIN overflow
-            lanes = [~k for k in lanes]
-        null_rank = (
-            jnp.zeros(lanes[0].shape, jnp.int64)
-            if valid is None
-            else jnp.where(valid, 0, -1 if nf else 1)
-        )
-        lex.extend(reversed(lanes))
-        lex.append(null_rank)  # more significant than the value
-    lex.append(jnp.where(live, 0, 1).astype(jnp.int64))  # live first
-    return jnp.lexsort(lex)
+            # (and keeps a narrow lane narrow)
+            lex.extend(_u32_lanes(~lane if desc else lane, narrow))
+        if valid is not None:  # more significant than the value
+            lex.append(
+                jnp.where(valid, 1, 0 if nf else 2).astype(jnp.uint32)
+            )
+    lex.append(jnp.where(live, 0, 1).astype(jnp.uint32))  # live first
+    return lexsort_u32(lex)
 
 
 def boundaries(

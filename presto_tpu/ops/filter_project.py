@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from presto_tpu import types as T
 from presto_tpu.expr import ColumnRef, Expr, ExprLowerer, eval_predicate
-from presto_tpu.page import Block, Page
+from presto_tpu.page import Block, Page, nonzero_1d
 
 
 def project(
@@ -408,7 +408,7 @@ def filter_project(
         return dataclasses.replace(out, num_valid=count, live=mask)
 
     cap = out_capacity if out_capacity is not None else page.capacity
-    (sel,) = jnp.nonzero(mask, size=cap, fill_value=0)
+    sel = nonzero_1d(mask, cap, 0)
 
     lowerer = ExprLowerer(page)
     names, blocks = [], []

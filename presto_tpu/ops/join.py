@@ -31,7 +31,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 from presto_tpu import types as T
-from presto_tpu.ops.common import orderable_i64
+from presto_tpu.ops.common import argsort_i64, orderable_i64
 from presto_tpu.page import Block, Page
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
@@ -138,7 +138,7 @@ def hash_join(
 
     # sort build by key; unmatchable rows carry the sentinel and sort last
     b_sort_key = jnp.where(b_ok, bk, _I64_MAX)
-    b_order = jnp.argsort(b_sort_key, stable=True)
+    b_order = argsort_i64(b_sort_key)
     bk_s = b_sort_key[b_order]
     nb = jnp.sum(b_ok).astype(jnp.int32)
 
@@ -295,7 +295,8 @@ def _append_unmatched_build(
     # membership of each build key among the live probe keys, by binary
     # search in the sorted probe keys; matches beyond the live count are
     # sentinel slots, not real keys — clip like the main probe path does
-    pk_sorted = jnp.sort(jnp.where(p_ok, pk_eff, _I64_MAX))
+    pk_sorted = jnp.where(p_ok, pk_eff, _I64_MAX)
+    pk_sorted = pk_sorted[argsort_i64(pk_sorted)]
     n_live = jnp.sum(p_ok)
     lo = jnp.minimum(jnp.searchsorted(pk_sorted, bk, side="left"), n_live)
     hi = jnp.minimum(jnp.searchsorted(pk_sorted, bk, side="right"), n_live)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from presto_tpu import types as T
 from presto_tpu.expr import Expr, ExprLowerer
-from presto_tpu.ops.common import boundaries, sort_order
+from presto_tpu.ops.common import boundaries, cumsum, sort_order
 from presto_tpu.ops.sort import SortKey
 from presto_tpu.page import Block, Page
 
@@ -413,7 +413,7 @@ def _window_agg(
         )
 
     if running:
-        cs = jnp.cumsum(x)
+        cs = cumsum(x)
         before_part = jnp.where(
             part_start[safe_pid] > 0,
             cs[jnp.maximum(part_start[safe_pid] - 1, 0)],

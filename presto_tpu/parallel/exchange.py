@@ -33,9 +33,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 
-from presto_tpu.ops.common import orderable_i64
-from presto_tpu.page import Block, Page
+from presto_tpu.ops.common import (
+    float_bits_i64,
+    lexsort_u32,
+    orderable_i64,
+)
+from presto_tpu.page import Block, Page, nonzero_1d
 
 _NULL_SENTINEL = 0xA5A5_A5A5_DEAD_BEEF
 
@@ -75,7 +80,7 @@ def compact_flat(
     page: Page, live: jnp.ndarray, num_valid: jnp.ndarray
 ) -> Page:
     """Compact rows where ``live`` to the front (static-shape nonzero)."""
-    (sel,) = jnp.nonzero(live, size=page.capacity, fill_value=0)
+    sel = nonzero_1d(live, page.capacity, 0)
     blocks = []
     for blk in page.blocks:
         blocks.append(
@@ -117,7 +122,8 @@ def partition_exchange(
     cap = page.capacity
     live = page.row_mask()
     d = jnp.where(live, dest.astype(jnp.int32), n)  # dead rows -> trash
-    order = jnp.argsort(d, stable=True)  # rows grouped by destination
+    # rows grouped by destination (d is in [0, n])
+    order = lexsort_u32([d.astype(jnp.uint32)])
     d_s = d[order]
     # offset of each sorted row within its destination's bucket
     offset = jnp.arange(cap, dtype=jnp.int32) - jnp.searchsorted(
@@ -301,9 +307,8 @@ def _wire_hash_image(
         )
         img = _mix64(hi) ^ lo
     elif blk.dtype.name in ("double", "real"):
-        f = data.astype(jnp.float64)
-        f = jnp.where(f == 0, 0.0, f)  # -0.0 hashes like +0.0
-        img = jax.lax.bitcast_convert_type(f, jnp.uint64)
+        # -0.0 hashes like +0.0 (one bit pattern)
+        img = float_bits_i64(data).astype(jnp.uint64)
     else:
         img = jax.lax.bitcast_convert_type(
             data.astype(jnp.int64), jnp.uint64
@@ -379,11 +384,6 @@ def ici_partition_counts(page: Page, dest: jnp.ndarray) -> jnp.ndarray:
 # the HTTP wire path's payload concatenation.
 
 _COLLECTIVE_AXIS = "xparts"
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 #: compiled collective-gather programs, keyed by (nparts, caps, column
 #: signature, mesh devices) — one compile per stage *shape*, reused by
@@ -501,7 +501,7 @@ def _dest_slots(D, nparts: int, seg_cap: int):
     each row's offset within its destination, and the flat slot
     ``dest * seg_cap + offset`` (trash/overflow rows land OOB)."""
     n = D.shape[0]
-    order = jnp.argsort(D, stable=True)
+    order = lexsort_u32([D.astype(jnp.uint32)])
     d_s = D[order]
     offset = jnp.arange(n, dtype=jnp.int32) - jnp.searchsorted(
         d_s, d_s, side="left"
@@ -564,9 +564,7 @@ def _make_collective_program(
                 counts, _COLLECTIVE_AXIS, 0, 0
             )
             live_recv = segmented_live_mask(out_counts, shard_cap)
-            (sel,) = jnp.nonzero(
-                live_recv, size=out_cap, fill_value=nparts * shard_cap
-            )
+            sel = nonzero_1d(live_recv, out_cap, nparts * shard_cap)
 
             def exchange(flat):
                 sent = _route_flat(flat, order, slot, nparts * shard_cap)
@@ -689,7 +687,7 @@ def ici_append(
     live = page.row_mask() & (dest == part)
     count = jnp.sum(live).astype(jnp.int32)
     cap = page.capacity
-    (sel,) = jnp.nonzero(live, size=cap, fill_value=0)
+    sel = nonzero_1d(live, cap, 0)
     idx = jnp.arange(cap, dtype=jnp.int32)
     new_out = {}
     for name, blk in zip(page.names, page.blocks):

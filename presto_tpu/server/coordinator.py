@@ -299,8 +299,8 @@ class MicrobatchQueue:
         if not leader:
             # the leader delivers this lane's result at dispatch; the
             # timeout is a belt — a wedged leader (a minutes-long cold
-            # vmapped compile on a tunneled backend) must never wedge
-            # a query. On timeout the follower CLAIMS itself: claim
+            # vmapped compile) must never wedge a query. On timeout
+            # the follower CLAIMS itself: claim
             # won -> the leader will skip this lane, scalar path here;
             # claim lost -> the leader owns the lane and always
             # delivers (finally below), so wait it out

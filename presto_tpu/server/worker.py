@@ -482,8 +482,15 @@ class WorkerServer:
         # boot-time device probe (utils/devicediag.py): once per
         # process — the structured diagnosis rides every announcement
         # and /v1/status from then on
-        if devicediag.last_diag() is None:
-            devicediag.probe_backend()
+        diag = devicediag.last_diag()
+        if diag is None or not diag.ok:
+            diag = devicediag.probe_backend()
+        if not diag.ok:
+            # a node that cannot compute must not announce itself
+            raise RuntimeError(
+                f"device probe failed at {diag.phase}: "
+                f"{diag.error_class}: {diag.error}"
+            )
         self._serve_thread.start()
         if self.coordinator_uris:
             self._announcer = threading.Thread(

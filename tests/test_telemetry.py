@@ -354,15 +354,26 @@ def test_backend_diag_forced_failure_shape():
     assert d["phase"] == "enumerate"
     assert d["error_class"] != "" and d["error"] != ""
     assert len(d["error"]) <= 300
-    # fallback note lands on the failed diag...
-    devicediag.note_fallback("cpu")
-    assert devicediag.last_diag_dict()["fallback"] == "cpu"
-    # ...and survives the successful re-probe (the bench's force-CPU
-    # path must keep "runs degraded" on record)
+    assert "fallback" not in d  # nothing falls back: a dead probe raises
+    # a worker refuses to start on a dead probe instead of announcing
+    # a node that cannot compute
+    from presto_tpu.server.worker import WorkerServer
+
+    w = WorkerServer()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(
+                devicediag, "probe_backend",
+                lambda: devicediag.record_diag(diag),
+            )
+            with pytest.raises(RuntimeError, match="device probe failed"):
+                w.start()
+    finally:
+        w.httpd.server_close()
+    # a later successful re-probe replaces the failed diag (and leaves
+    # a clean one for other tests in this process)
     again = devicediag.probe_backend()
-    assert again.ok and again.fallback == "cpu"
-    # leave a clean diag for other tests in this process
-    devicediag.probe_backend()
+    assert again.ok and devicediag.last_diag() is again
 
 
 def test_backend_diag_on_worker_status_and_nodes(cluster):

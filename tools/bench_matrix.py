@@ -1,11 +1,11 @@
 """Benchmark-matrix wrapper: one process per config, honest rc.
 
-Runs every ``bench.py --only <config>`` in its OWN subprocess (a tunnel
-backend crash on one config must not poison the rest — BASELINE.md
-"matrix walls") and records an HONEST status per config: a config
-counts as failed when the subprocess exits nonzero, times out, OR its
-JSON line carries an ``error``/zero value (VERDICT r3 weak 1: the old
-wrapper conflated "process exited" with "measurement succeeded").
+Runs every ``bench.py --only <config>`` in its OWN subprocess (a
+crash on one config must not poison the rest; one process at a time,
+since a chip belongs to one process) and records an HONEST status per
+config: a config counts as failed when the subprocess exits nonzero,
+times out, OR its JSON line carries an ``error``/zero value
+("process exited" is not "measurement succeeded").
 
 Usage:
     python tools/bench_matrix.py [--timeout SECONDS] [CONFIG ...]

@@ -6,12 +6,12 @@ Diffs consecutive ``BENCH_*.json`` artifacts (the bench driver's
 JSONL result lines) and flags any metric that degraded by more than
 the threshold (default 20%) between two consecutive rounds.
 
-Skip discipline (the BENCH_r04/r05 lesson, see bench.py ``_emit``):
+Skip discipline (see bench.py ``_emit``):
 
 - a line with ``skipped: true`` is a skip — it carries no value and
   never participates in a comparison, in either role;
 - a LEGACY line carrying ``error`` beside a value (the pre-contract
-  ``value: 0`` shape r04/r05 actually shipped) is treated as skipped
+  ``value: 0`` shape old artifacts shipped) is treated as skipped
   too — that zero was never a measurement and must neither flag a
   drop against the round before it nor serve as the baseline that
   makes the next real round look like an infinite improvement;
@@ -57,7 +57,7 @@ def is_skipped(line: dict) -> bool:
     if line.get("skipped"):
         return True
     if "error" in line:
-        # pre-contract artifacts (BENCH_r04/r05): value 0 beside the
+        # pre-contract artifacts: value 0 beside the
         # error — a failed measurement, not a measured zero
         return True
     value = line.get("value")
