@@ -46,3 +46,7 @@ _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 __version__ = "0.1.0"
 
 from presto_tpu.session import Session  # noqa: E402,F401
+from presto_tpu.utils import telemetry as _telemetry  # noqa: E402
+
+# true XLA compiles, process-wide (device_snapshot()["xla_compiles"])
+_telemetry.install_xla_listeners()

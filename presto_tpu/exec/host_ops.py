@@ -29,6 +29,7 @@ from presto_tpu import types as T
 from presto_tpu.expr import ColumnRef
 from presto_tpu.page import Block, Page
 from presto_tpu.plan import nodes as N
+from presto_tpu.utils import tracing
 
 
 def orderable_np(data: np.ndarray, dtype: T.DataType) -> np.ndarray:
@@ -101,9 +102,10 @@ def apply_host_ops(
     # the small slices (async dispatches pipeline; transfers batch).
     # A page that is ALREADY host-side (the speculative single-round-
     # trip materialization) skips the fetch entirely.
-    n = int(page.num_valid)
-    leaves = page.prefix_leaves(n)
-    fetched = leaves if page.is_host else jax.device_get(leaves)
+    with tracing.phase("fetch", site="host_ops"):
+        n = int(page.num_valid)
+        leaves = page.prefix_leaves(n)
+        fetched = leaves if page.is_host else jax.device_get(leaves)
     cols = {}  # name -> (np_data, np_valid, dtype, dictionary)
     i = 0
     for name, blk in zip(page.names, page.blocks):

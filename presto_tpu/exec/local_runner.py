@@ -66,6 +66,7 @@ from presto_tpu.plan.planner import Plan, plan_statement
 from presto_tpu.session import Session
 from presto_tpu.sql import parse_statement
 from presto_tpu.sql import ast
+from presto_tpu.utils import tracing
 from presto_tpu.utils.telemetry import DEVICE
 
 
@@ -395,9 +396,7 @@ class LocalQueryRunner:
         REGISTRY.counter("queries.submitted").update()
         t0 = time.perf_counter()
         try:
-            with REGISTRY.timer("query.wall_time").time(), trace.span(
-                "query", query_id=qs.query_id
-            ):
+            with trace.span("query", query_id=qs.query_id):
                 with trace.span("plan"):
                     if isinstance(stmt, ast.Select):
                         # the stats sink is live DURING planning so an
@@ -1689,11 +1688,9 @@ class LocalQueryRunner:
                     croot, cscan_ids, counted, False,
                     out_capacity=spec,
                 )
-                entry = (
-                    jax.jit(canonical.vmap_program(trace)),
-                    msgs_cell,
-                    nodes_cell,
-                )
+                batched = canonical.vmap_program(trace)
+                batched.__name__ = _program_name(croot, cfp)
+                entry = (jax.jit(batched), msgs_cell, nodes_cell)
                 self._compiled[key] = entry
         REGISTRY.counter(
             "compile.cache_miss" if fresh else "compile.cache_hit"
@@ -1701,7 +1698,7 @@ class LocalQueryRunner:
         fn, msgs_cell, nodes_cell = entry
         t_disp = time.perf_counter()
         try:
-            with self._device_scope():
+            with self._device_scope(), tracing.phase("dispatch"):
                 (
                     page, flags_arr, err_arr, cnt_arr, dyn_arr,
                     true_n_arr,
@@ -1725,7 +1722,8 @@ class LocalQueryRunner:
             if blk.valid is not None:
                 leaves.append(blk.valid[:, :k])
         t_disped = time.perf_counter()
-        fetched = jax.device_get(leaves)
+        with tracing.phase("fetch", site="microbatch"):
+            fetched = jax.device_get(leaves)
         t_fetched = time.perf_counter()
         # device-plane accounting: the batch is ONE real dispatch +
         # one fetch on the process counters; per-lane attribution
@@ -1909,6 +1907,7 @@ class LocalQueryRunner:
                     trace, msgs_cell, nodes_cell = self._make_trace(
                         croot, cscan_ids, counted, analyzed
                     )
+                    trace.__name__ = _program_name(croot, cfp)
                     entry = (jax.jit(trace), msgs_cell, nodes_cell)
                     self._compiled[key] = entry
             # compile-amortization counters (bench.py runs read these):
@@ -1921,7 +1920,7 @@ class LocalQueryRunner:
             fn, msgs_cell, nodes_cell = entry
             t_disp = time.perf_counter()
             try:
-                with self._device_scope():
+                with self._device_scope(), tracing.phase("dispatch"):
                     page, flags_arr, err_arr, cnt_arr, dyn_arr = fn(
                         pages, params
                     )
@@ -1963,7 +1962,8 @@ class LocalQueryRunner:
             if spec > 0:
                 leaves.extend(page.prefix_leaves(spec))
             t_disped = time.perf_counter()
-            fetched = jax.device_get(leaves)
+            with tracing.phase("fetch", site="control"):
+                fetched = jax.device_get(leaves)
             t_fetched = time.perf_counter()
             # device-plane accounting (utils/telemetry.py): one real
             # dispatch + its fetch bytes; a fresh entry's dispatch
@@ -2238,9 +2238,10 @@ class LocalQueryRunner:
             from presto_tpu.utils.metrics import REGISTRY
 
             t0 = time.perf_counter()
-            merged = self._load_merged_payload(scan)
-            with self._device_scope():
-                page = stage_page(merged, dict(scan.schema))
+            with tracing.phase("staging", site="load_table"):
+                merged = self._load_merged_payload(scan)
+                with self._device_scope():
+                    page = stage_page(merged, dict(scan.schema))
             nbytes = _page_nbytes(page)
             REGISTRY.distribution("staging.bytes").add(nbytes)
             # per-query h2d attribution (the process counter lives in
@@ -2342,18 +2343,19 @@ class LocalQueryRunner:
                     self._note_pinned_key(key)
                 return page, unpin
         t0 = time.perf_counter()
-        payload = (
-            page_source()
-            if page_source is not None
-            else conn.create_page_source(
-                ConnectorSplit(scan.handle, lo, hi),
-                list(scan.columns),
+        with tracing.phase("staging", site="stage_split"):
+            payload = (
+                page_source()
+                if page_source is not None
+                else conn.create_page_source(
+                    ConnectorSplit(scan.handle, lo, hi),
+                    list(scan.columns),
+                )
             )
-        )
-        with self._device_scope():
-            page = stage_page(
-                payload, dict(scan.schema), capacity=capacity
-            )
+            with self._device_scope():
+                page = stage_page(
+                    payload, dict(scan.schema), capacity=capacity
+                )
         from presto_tpu.utils.metrics import REGISTRY
 
         nbytes = _page_nbytes(page)
@@ -2574,9 +2576,9 @@ def materialize_page(page: Page, n: int) -> Page:
     still hits the per-bucket compile cache."""
     if not page.blocks or page.is_host:
         return page
-    return _page_from_prefix(
-        page, jax.device_get(page.prefix_leaves(n)), n
-    )
+    with tracing.phase("fetch", site="materialize"):
+        leaves = jax.device_get(page.prefix_leaves(n))
+    return _page_from_prefix(page, leaves, n)
 
 
 def page_np_dtype(blk: Block):
@@ -2608,6 +2610,25 @@ def _plan_weight(root: N.PlanNode) -> int:
 
 
 # ---------------------------------------------------------- trace helpers
+
+#: nodes that only rename, reorder or cut columns: a program is named
+#: after the first operator under them
+_WRAPPER_NODES = (N.OutputNode, N.ProjectNode)
+
+
+def _program_name(croot: N.PlanNode, cfp: str) -> str:
+    """``<root operator>_<6 hex of the canonical fingerprint>``: the
+    jitted fragment's name (XLA module ``jit_<name>``), so a device
+    profile tells one statement's programs from another's. At most 26
+    characters — the benchmark's reduction keeps 30 with ``jit_``."""
+    import hashlib
+
+    node = croot
+    while isinstance(node, _WRAPPER_NODES) and node.children():
+        node = node.children()[0]
+    op = type(node).__name__.removesuffix("Node").lower()
+    return f"{op[:19]}_{hashlib.sha1(cfp.encode()).hexdigest()[:6]}"
+
 
 
 def _node_depths(root: N.PlanNode) -> Dict[int, int]:
@@ -2695,9 +2716,14 @@ def _execute_node(
     the cardinality-determining ``_COUNTED_NODES``. ``dyn``
     accumulates the traced pruned-row count of every dynamic
     FilterNode (dynamic_filter.rows_pruned observability)."""
-    out = _execute_node_inner(
-        node, pages, scan_ids, flags, errors, counters, dyn, count_all
-    )
+    # the operator's name on every XLA op it lowers to (nested under
+    # its consumers'): a device profile says which plan node an
+    # operation belongs to
+    with jax.named_scope(type(node).__name__.removesuffix("Node")):
+        out = _execute_node_inner(
+            node, pages, scan_ids, flags, errors, counters, dyn,
+            count_all,
+        )
     if counters is not None and (
         count_all or isinstance(node, _COUNTED_NODES)
     ):

@@ -29,6 +29,7 @@ import numpy as np
 from presto_tpu import types as T
 from presto_tpu.connectors.tpch import DictColumn
 from presto_tpu.page import Block, Dictionary, Page
+from presto_tpu.utils import tracing
 from presto_tpu.utils.telemetry import DEVICE
 
 MIN_BUCKET = 1 << 10
@@ -105,6 +106,15 @@ def stage_page(
     capacity: Optional[int] = None,
 ) -> Page:
     """Build a device Page from SPI column payloads."""
+    with tracing.phase("staging", site="stage_page"):
+        return _stage_page(data, schema, capacity)
+
+
+def _stage_page(
+    data: Dict[str, object],
+    schema: Dict[str, T.DataType],
+    capacity: Optional[int],
+) -> Page:
     from presto_tpu.connectors.spi import payload_len
 
     names = tuple(schema.keys())
@@ -749,7 +759,8 @@ def prefetch_iter(items, load_fn, depth: int, on_drop=None):
         aborted task must not leave this thread parked forever)."""
         while not stop.is_set():
             try:
-                q.put(entry, timeout=0.1)
+                with tracing.wait("staging.prefetch_put"):
+                    q.put(entry, timeout=0.1)
                 return True
             except queue.Full:
                 continue
@@ -760,7 +771,8 @@ def prefetch_iter(items, load_fn, depth: int, on_drop=None):
             if stop.is_set():
                 return
             try:
-                entry = (load_fn(it), None)
+                with tracing.phase("staging", site="prefetch"):
+                    entry = (load_fn(it), None)
             except BaseException as e:  # re-raised consumer-side
                 _put((None, e))
                 return
@@ -778,7 +790,8 @@ def prefetch_iter(items, load_fn, depth: int, on_drop=None):
     t.start()
     try:
         while True:
-            page, err = q.get()
+            with tracing.wait("staging.prefetch_get"):
+                page, err = q.get()
             if err is not None:
                 raise err
             if page is _END:
@@ -789,7 +802,8 @@ def prefetch_iter(items, load_fn, depth: int, on_drop=None):
         # join before returning: an in-flight load_fn must not touch
         # caller state (e.g. reserve pool bytes) after the driver
         # loop has moved on to its cleanup
-        t.join()
+        with tracing.wait("staging.prefetch_join"):
+            t.join()
         while True:
             try:
                 entry, err = q.get_nowait()

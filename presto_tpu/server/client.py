@@ -25,6 +25,7 @@ import urllib.error
 from typing import Dict, List
 
 from presto_tpu.server import protocol, rpc
+from presto_tpu.utils import tracing
 from presto_tpu.utils.metrics import REGISTRY
 
 
@@ -102,6 +103,18 @@ class PrestoTpuClient:
         self._prepared_header: Optional[str] = None
 
     def execute(self, sql: str) -> ClientResult:
+        """One statement, POST to last page. The client's own work
+        (build the request, decode and collect pages) is ``protocol``
+        time; every round trip and sleep inside is a ``wait``; the
+        whole call is the statement's wall time."""
+        t0 = time.perf_counter_ns()
+        try:
+            with tracing.phase("protocol", site="client"):
+                return self._execute(sql)
+        finally:
+            tracing.add_stmt_wall(time.perf_counter_ns() - t0)
+
+    def _execute(self, sql: str) -> ClientResult:
         first = self._post_statement(sql.encode())
         qid = first["id"]
         columns: List[str] = []
@@ -131,15 +144,15 @@ class PrestoTpuClient:
                 "nextUri"
             ):
                 try:
-                    time.sleep(
-                        min(
-                            float(retry_after),
-                            max(deadline - time.monotonic(), 0.0),
-                            2.0,
-                        )
+                    pause = min(
+                        float(retry_after),
+                        max(deadline - time.monotonic(), 0.0),
+                        2.0,
                     )
                 except ValueError:
-                    pass
+                    continue
+                with tracing.wait("client.retry_after"):
+                    time.sleep(pause)
 
     def _post_statement(self, body: bytes) -> dict:
         """Submit one statement, spraying the coordinator list
@@ -209,7 +222,8 @@ class PrestoTpuClient:
             for target in targets:
                 try:
                     resp = rpc.call(
-                        "GET", target, policy=self.rpc_policy
+                        "GET", target, policy=self.rpc_policy,
+                        wait_site="client.http",
                     )
                     if target != url:
                         REGISTRY.counter("client.retargets").update()
@@ -239,9 +253,10 @@ class PrestoTpuClient:
             ):
                 raise last_exc
             REGISTRY.counter("client.reconnects").update()
-            time.sleep(
-                rpc.compute_backoff(attempt - 1, self.rpc_policy)
-            )
+            with tracing.wait("client.reconnect_backoff"):
+                time.sleep(
+                    rpc.compute_backoff(attempt - 1, self.rpc_policy)
+                )
 
     def _absorb_prepared_headers(self, headers) -> None:
         added = headers.get_all(protocol.ADDED_PREPARE_HEADER)
@@ -303,6 +318,7 @@ class PrestoTpuClient:
             "POST", url, body,
             policy=self.rpc_policy,
             headers=headers,
+            wait_site="client.http_post",
         ).json()
 
     def _get_json(self, url: str) -> dict:

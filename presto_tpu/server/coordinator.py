@@ -44,7 +44,7 @@ from presto_tpu.server.scheduler import (
     stable_workers,
 )
 from presto_tpu.server.spool import ExchangeSpool
-from presto_tpu.utils import faults
+from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY, DistributionStat
 from presto_tpu.utils.tracing import Trace
 
@@ -304,14 +304,18 @@ class MicrobatchQueue:
             # won -> the leader will skip this lane, scalar path here;
             # claim lost -> the leader owns the lane and always
             # delivers (finally below), so wait it out
-            if not member.event.wait(wait_ms / 1000.0 + 60.0):
+            with tracing.wait("coordinator.microbatch_follow"):
+                delivered = member.event.wait(wait_ms / 1000.0 + 60.0)
+            if not delivered:
                 if member.claim():
                     self._note_wait(member)
                     return None
-                member.event.wait()
+                with tracing.wait("coordinator.microbatch_claimed"):
+                    member.event.wait()
             self._note_wait(member)
             return member.result
-        g.full.wait(wait_ms / 1000.0)
+        with tracing.wait("coordinator.microbatch_window"):
+            g.full.wait(wait_ms / 1000.0)
         with self._lock:
             g.closed = True
             if self._groups.get(key) is g:
@@ -1458,7 +1462,8 @@ class CoordinatorServer:
         ):
             if self.arbiter.pressure_subsided():
                 return
-            time.sleep(0.05)
+            with tracing.wait("coordinator.memory_calm"):
+                time.sleep(0.05)
 
     def _fold_memory_stats(self, q: _Query) -> None:
         """Roll the query's cluster-wide memory view (coordinator pool
@@ -1851,8 +1856,12 @@ class CoordinatorServer:
             finally:
                 self.qos.qos_release(q)
         else:
-            with self._admit:
+            with tracing.wait("coordinator.admit"):
+                self._admit.acquire()
+            try:
                 self._admitted_execute(q)
+            finally:
+                self._admit.release()
 
     def _qos_checkpoint(self, q: Optional[_Query]) -> None:
         """Cooperative QoS suspension point (server/qos.py): a
@@ -1872,7 +1881,8 @@ class CoordinatorServer:
             and self.arbiter.admission_held()
         ):
             q._admission_parked = True
-            time.sleep(0.05)
+            with tracing.wait("coordinator.admission_held"):
+                time.sleep(0.05)
         if q.done.is_set():  # killed while queued (memory manager)
             with self._lock:
                 self._pending -= 1
@@ -2030,7 +2040,8 @@ class CoordinatorServer:
     def _run_sql(self, q: _Query) -> None:
         from presto_tpu.sql import ast, parse_statement
 
-        stmt = parse_statement(q.sql)
+        with tracing.phase("plan", site="parse"):
+            stmt = parse_statement(q.sql)
         if isinstance(stmt, (ast.Prepare, ast.Execute, ast.Deallocate)):
             return self._run_prepared_stmt(q, stmt)
         workers = self.active_workers()
@@ -2506,7 +2517,8 @@ class CoordinatorServer:
                     pool.submit(self._run_stage, r.fragment_root, workers, q)
                     for r in remotes
                 ]
-                pages = [f.result() for f in futs]
+                with tracing.wait("coordinator.fragment_futures"):
+                    pages = [f.result() for f in futs]
         with q.trace.span("gather", phase="final-splice"):
             page = self.local._run_with_pages(froot, remotes, pages)
             if host_ops:
@@ -2621,6 +2633,7 @@ class CoordinatorServer:
                 "GET",
                 f"{w.uri}/v1/task/{task_id}/status",
                 traceparent=traceparent,
+                site="coordinator.task_status_final",
             )
             self._record_task_status(q, task_id, st)
         except Exception:
@@ -2644,6 +2657,7 @@ class CoordinatorServer:
                 "DELETE",
                 f"{w.uri}/v1/task/{task_id}",
                 traceparent=traceparent,
+                site="coordinator.task_delete",
             )
         except Exception:
             pass
@@ -3113,6 +3127,7 @@ class CoordinatorServer:
                     "POST", w.uri + "/v1/task", spec.to_json(),
                     policy=df_policy(),
                     traceparent=spec.traceparent,
+                    wait_site="coordinator.dynfilter_post",
                 )
                 posted.append((w, spec))
             for w, spec in posted:
@@ -3124,6 +3139,7 @@ class CoordinatorServer:
                         f"{w.uri}/v1/task/{spec.task_id}/status",
                         policy=df_policy(),
                         traceparent=spec.traceparent,
+                        wait_site="coordinator.dynfilter_status",
                     )
                     state = st.get("state")
                     if state == "FINISHED":
@@ -3137,7 +3153,8 @@ class CoordinatorServer:
                         break
                     if state in ("FAILED", "ABORTED"):
                         return None
-                    time.sleep(0.02)
+                    with tracing.wait("coordinator.dynfilter_poll"):
+                        time.sleep(0.02)
             ok = merged is not None
             return merged
         except Exception:
@@ -4025,9 +4042,10 @@ class CoordinatorServer:
                             (side_stages[1], J.right_keys, 1),
                         )
                     ]
-                    sources: List[tuple] = [
-                        s for f in side_futs for s in f.result()
-                    ]
+                    with tracing.wait("coordinator.producer_futures"):
+                        sources: List[tuple] = [
+                            s for f in side_futs for s in f.result()
+                        ]
 
             join_frag = dataclasses.replace(
                 J,
@@ -4061,6 +4079,7 @@ class CoordinatorServer:
                 self._rpc_json(
                     "POST", w.uri + "/v1/task", spec.to_json(),
                     traceparent=spec.traceparent,
+                    site="coordinator.join_task_post",
                 )
                 return self._pull_task(w, spec)
 
@@ -4068,7 +4087,8 @@ class CoordinatorServer:
                 futs = [
                     pool.submit(run_join_task, i) for i in range(nparts)
                 ]
-                payloads = [p for f in futs for p in f.result()]
+                with tracing.wait("coordinator.join_futures"):
+                    payloads = [p for f in futs for p in f.result()]
             jstage.state = "FINISHED"
         finally:
             for w, tid in created:
@@ -4189,6 +4209,7 @@ class CoordinatorServer:
                         f"{w.uri}/v1/task/{spec.task_id}/sources",
                         body,
                         traceparent=spec.traceparent,
+                        site="coordinator.merge_sources_put",
                     )
                 except Exception:
                     pass
@@ -4229,6 +4250,7 @@ class CoordinatorServer:
                         self._rpc_json(
                             "POST", w.uri + "/v1/task", spec.to_json(),
                             traceparent=spec.traceparent,
+                            site="coordinator.merge_task_post",
                         )
                     except (
                         urllib.error.URLError, ConnectionError, OSError
@@ -4276,6 +4298,7 @@ class CoordinatorServer:
                     self._rpc_json(
                         "POST", w.uri + "/v1/task", spec.to_json(),
                         traceparent=spec.traceparent,
+                        site="coordinator.merge_fallback_post",
                     )
                     return self._pull_task(w, spec)
                 finally:
@@ -4311,7 +4334,10 @@ class CoordinatorServer:
                     futs = [
                         pool.submit(run_merge, i) for i in range(nparts)
                     ]
-                    payloads = [p for f in futs for p in f.result()]
+                    with tracing.wait("coordinator.merge_futures"):
+                        payloads = [
+                            p for f in futs for p in f.result()
+                        ]
         finally:
             for w, spec in merge_specs:
                 self._finish_task(q, w, spec.task_id, spec.traceparent)
@@ -4423,6 +4449,7 @@ class CoordinatorServer:
                             spec.to_json(),
                             policy=self._rpc_policy,
                             traceparent=spec.traceparent,
+                            wait_site="coordinator.producer_task_post",
                         )
                         break
                     except urllib.error.HTTPError as e:
@@ -4455,11 +4482,18 @@ class CoordinatorServer:
             }
 
             def attempt(worker, spec, backup):
+                # its own thread: the scheduling work between the
+                # round trips (spec JSON, page decode, status folds)
+                with tracing.phase("schedule", site="attempt"):
+                    _attempt(worker, spec, backup)
+
+            def _attempt(worker, spec, backup):
                 try:
                     rpc.call_json(
                         "POST", worker.uri + "/v1/task", spec.to_json(),
                         policy=self._rpc_policy,
                         traceparent=spec.traceparent,
+                        wait_site="coordinator.task_post",
                     )
                     out = consume(worker, spec)
                     self._worker_ok(worker)
@@ -4611,11 +4645,12 @@ class CoordinatorServer:
                         and state["fatal"] is None
                         and state["active"] > 0
                     ):
-                        cond.wait(
-                            timeout=0.05
-                            if spec_on and not speculated
-                            else None
-                        )
+                        with tracing.wait("coordinator.range_attempt"):
+                            cond.wait(
+                                timeout=0.05
+                                if spec_on and not speculated
+                                else None
+                            )
             if fatal is not None:
                 # execution failure: tear down every attempt of this
                 # range (an in-flight backup must not leak its task)
@@ -4640,6 +4675,10 @@ class CoordinatorServer:
             range_q.put(r)
 
         def drain_worker(w):
+            with tracing.phase("schedule", site="drain_worker"):
+                return _drain_worker(w)
+
+        def _drain_worker(w):
             out = []
             while True:
                 # QoS preempt-and-resume: a suspended query's stage
@@ -4656,7 +4695,8 @@ class CoordinatorServer:
 
         with ThreadPoolExecutor(max(len(workers), 1)) as pool:
             futs = [pool.submit(drain_worker, w) for w in workers]
-            return [r for f in futs for r in f.result()]
+            with tracing.wait("coordinator.stage_futures"):
+                return [r for f in futs for r in f.result()]
 
     def _wait_task(self, w, spec) -> None:
         """Poll a producer task to completion (its pages stay buffered
@@ -4672,6 +4712,7 @@ class CoordinatorServer:
             st = self._rpc_json(
                 "GET", f"{w.uri}/v1/task/{spec.task_id}/status",
                 traceparent=spec.traceparent,
+                site="coordinator.wait_task_status",
             )
             state = st.get("state")
             if state == "FINISHED":
@@ -4680,7 +4721,8 @@ class CoordinatorServer:
                 raise RuntimeError(
                     f"task on {w.node_id} failed: {st.get('error')}"
                 )
-            time.sleep(0.03)
+            with tracing.wait("coordinator.wait_task_poll"):
+                time.sleep(0.03)
 
     def _pull_task(self, w, spec) -> List[tuple]:
         """Token-acked page pulls until X-Complete (exchange client):
@@ -4698,13 +4740,15 @@ class CoordinatorServer:
 
         def stall():
             st = self._rpc_json(
-                "GET", f"{w.uri}/v1/task/{spec.task_id}/status"
+                "GET", f"{w.uri}/v1/task/{spec.task_id}/status",
+                site="coordinator.task_status_poll",
             )
             if st.get("state") == "FAILED":
                 raise RuntimeError(
                     f"task on {w.node_id} failed: {st.get('error')}"
                 )
-            time.sleep(0.05)
+            with tracing.wait("coordinator.pull_stall"):
+                time.sleep(0.05)
 
         if (
             spec.ici_slice
@@ -4720,7 +4764,8 @@ class CoordinatorServer:
                 # instead of spinning to the deadline
                 try:
                     st = self._rpc_json(
-                        "GET", f"{w.uri}/v1/task/{spec.task_id}/status"
+                        "GET", f"{w.uri}/v1/task/{spec.task_id}/status",
+                        site="coordinator.ici_gather_probe",
                     )
                 except Exception:
                     return False
@@ -4761,13 +4806,15 @@ class CoordinatorServer:
                 traceparent=spec.traceparent,
                 stall=stall,
                 timeout_msg=f"task {spec.task_id} timed out",
+                site="coordinator",
             )
         except urllib.error.HTTPError as e:
             if e.code == 500:
                 # the task FAILED: surface the worker's error text,
                 # not a bare HTTP status
                 st = self._rpc_json(
-                    "GET", f"{w.uri}/v1/task/{spec.task_id}/status"
+                    "GET", f"{w.uri}/v1/task/{spec.task_id}/status",
+                    site="coordinator.task_status_failed",
                 )
                 raise RuntimeError(
                     f"task on {w.node_id} failed: {st.get('error')}"
@@ -4777,14 +4824,18 @@ class CoordinatorServer:
     # ------------------------------------------------------------ helpers
 
     def _rpc_json(
-        self, method: str, url: str, body=None, traceparent: str = ""
+        self, method: str, url: str, body=None, traceparent: str = "",
+        *, site: str,
     ) -> dict:
         """Coordinator->worker JSON RPC under the coordinator's policy
         (config-driven timeout, bounded backoff retries for idempotent
-        calls, trace propagation, fault-plane hooks)."""
+        calls, trace propagation, fault-plane hooks). Every caller is
+        on a statement's path: ``site`` names the round trip's
+        ``wait``."""
         return rpc.call_json(
             method, url, body,
             policy=self._rpc_policy, traceparent=traceparent,
+            wait_site=site,
         )
 
     def _store_result(self, q: _Query, res) -> None:
@@ -4838,43 +4889,8 @@ def _make_handler(coord: CoordinatorServer):
         def do_POST(self):
             parts = [p for p in self.path.split("/") if p]
             if parts == ["v1", "statement"]:
-                from presto_tpu.server import protocol
-
-                # a dying coordinator must not ACK a statement it
-                # cannot journal (the ack promises a resumable query):
-                # 503 = "nothing admitted", which the spray client
-                # re-targets at a peer duplicate-free
-                if coord._shutting_down:
-                    return self._json(
-                        503, {"error": "coordinator shutting down"}
-                    )
-                sql = self._read_body().decode()
-                user = self.headers.get("X-Presto-User", "presto_tpu")
-                # client-owned prepared statements ride per-request
-                # headers (server.protocol): EXECUTE resolves against
-                # this map first
-                prepared = protocol.decode_prepared(
-                    self.headers.get_all(
-                        protocol.PREPARED_STATEMENT_HEADER
-                    )
-                )
-                q = coord.submit(sql, user=user, prepared=prepared)
-                # re-check AFTER submit: a kill that raced past the
-                # gate above may have dropped the journal before the
-                # frame landed — refuse the ACK (the client resubmits
-                # at a peer; a frame that DID land resumes there too,
-                # which is the journal's at-least-once contract)
-                if coord._shutting_down:
-                    return self._json(
-                        503, {"error": "coordinator shutting down"}
-                    )
-                return self._json(
-                    200,
-                    {
-                        "id": q.qid,
-                        "nextUri": f"{coord.uri}/v1/statement/{q.qid}/0",
-                    },
-                )
+                with tracing.phase("protocol", site="coordinator.post"):
+                    return self._post_statement()
             if len(parts) == 3 and parts[:2] == ["v1", "ingest"]:
                 # streaming ingest: POST /v1/ingest/{table} with
                 # {"rows": [{col: val}, ...]} or
@@ -4906,6 +4922,45 @@ def _make_handler(coord: CoordinatorServer):
                         400, {"error": f"{type(e).__name__}: {e}"}
                     )
             self._json(404, {"error": f"no route {self.path}"})
+
+        def _post_statement(self):
+            from presto_tpu.server import protocol
+
+            # a dying coordinator must not ACK a statement it
+            # cannot journal (the ack promises a resumable query):
+            # 503 = "nothing admitted", which the spray client
+            # re-targets at a peer duplicate-free
+            if coord._shutting_down:
+                return self._json(
+                    503, {"error": "coordinator shutting down"}
+                )
+            sql = self._read_body().decode()
+            user = self.headers.get("X-Presto-User", "presto_tpu")
+            # client-owned prepared statements ride per-request
+            # headers (server.protocol): EXECUTE resolves against
+            # this map first
+            prepared = protocol.decode_prepared(
+                self.headers.get_all(
+                    protocol.PREPARED_STATEMENT_HEADER
+                )
+            )
+            q = coord.submit(sql, user=user, prepared=prepared)
+            # re-check AFTER submit: a kill that raced past the
+            # gate above may have dropped the journal before the
+            # frame landed — refuse the ACK (the client resubmits
+            # at a peer; a frame that DID land resumes there too,
+            # which is the journal's at-least-once contract)
+            if coord._shutting_down:
+                return self._json(
+                    503, {"error": "coordinator shutting down"}
+                )
+            return self._json(
+                200,
+                {
+                    "id": q.qid,
+                    "nextUri": f"{coord.uri}/v1/statement/{q.qid}/0",
+                },
+            )
 
         def do_PUT(self):
             parts = [p for p in self.path.split("/") if p]
@@ -4997,114 +5052,118 @@ def _make_handler(coord: CoordinatorServer):
                     return self._json(404, {"error": "no such query"})
                 return self._json(200, coord.query_info(x))
             if len(parts) == 4 and parts[:2] == ["v1", "statement"]:
-                qid, token = parts[2], int(parts[3])
-                q = coord.lookup_query(qid)
-                if q is None:
-                    # multi-coordinator alias lookup: a sprayed (or
-                    # failed-over) client may land here holding a
-                    # statement another live coordinator serves —
-                    # redirect via its lease payload. Loop-free:
-                    # coordinators only advertise qids they can
-                    # resolve locally
-                    peer = coord.locate_peer(qid)
-                    if peer:
-                        return self._json(
-                            200,
-                            {
-                                "id": qid,
-                                "nextUri": (
-                                    f"{peer}/v1/statement/{qid}/{token}"
-                                ),
-                            },
-                        )
-                    return self._json(404, {"error": "no such query"})
-                if q.state == "SUSPENDED" and not q.done.is_set():
-                    # QoS preempt-and-resume: a parked query must not
-                    # hold its client on the long-poll — answer NOW
-                    # with empty data and a retry hint, keeping the
-                    # poll loop alive (and cheap) until resume
-                    return self._json(
-                        200,
-                        {
-                            "id": qid,
-                            "stats": {"state": "SUSPENDED"},
-                            "data": [],
-                            "nextUri": (
-                                f"{coord.uri}/v1/statement/{qid}/"
-                                f"{token}"
-                            ),
-                        },
-                        extra_headers={"Retry-After": "0.5"},
-                    )
-                # long-poll up to 1s for progress (reference: long-poll)
-                q.done.wait(timeout=1.0)
-                # q.error decides failure delivery alongside the state
-                # string: a rare suspension decision racing a kill can
-                # leave a non-FAILED state on a done-with-error query,
-                # and the client must still get the error, never an
-                # empty success page
-                if q.state == "FAILED" or (
-                    q.done.is_set() and q.error is not None
-                ):
-                    q._drained = True  # error delivered: safe to evict
-                    return self._json(
-                        200,
-                        {
-                            "id": qid,
-                            "error": q.error,
-                            "stats": {"state": "FAILED"},
-                        },
-                    )
-                if not q.done.is_set():
-                    return self._json(
-                        200,
-                        {
-                            "id": qid,
-                            "stats": {"state": q.state},
-                            "nextUri": (
-                                f"{coord.uri}/v1/statement/{qid}/{token}"
-                            ),
-                        },
-                    )
-                lo = token * RESULT_PAGE_ROWS
-                hi = min(lo + RESULT_PAGE_ROWS, len(q.rows))
-                out = {
-                    "id": qid,
-                    "columns": q.columns,
-                    "data": q.rows[lo:hi],
-                    "stats": {"state": "FINISHED"},
-                }
-                if hi < len(q.rows):
-                    out["nextUri"] = (
-                        f"{coord.uri}/v1/statement/{qid}/{token + 1}"
-                    )
-                else:
-                    q._drained = True  # last page served
-                # prepared-statement session updates ride the result
-                # response (server.protocol): the client folds them
-                # into the map it replays on future requests
-                extra = {}
-                if q.added_prepare is not None:
-                    from presto_tpu.server import protocol
-
-                    name, text = q.added_prepare
-                    # echo once: only on the FIRST result page, and
-                    # only when the client's replayed map does not
-                    # already carry the identical statement — a client
-                    # that knows the name must not re-absorb (and
-                    # re-serialize) it on every page of every request
-                    if token == 0 and q.prepared.get(name) != text:
-                        extra[protocol.ADDED_PREPARE_HEADER] = (
-                            protocol.encode_prepared(name, text)
-                        )
-                if q.deallocated_prepare is not None:
-                    from presto_tpu.server import protocol
-
-                    extra[protocol.DEALLOCATED_PREPARE_HEADER] = (
-                        q.deallocated_prepare
-                    )
-                return self._json(200, out, extra_headers=extra)
+                with tracing.phase("protocol", site="coordinator.page"):
+                    return self._get_statement(parts[2], int(parts[3]))
             self._json(404, {"error": f"no route {self.path}"})
+
+        def _get_statement(self, qid: str, token: int):
+            q = coord.lookup_query(qid)
+            if q is None:
+                # multi-coordinator alias lookup: a sprayed (or
+                # failed-over) client may land here holding a
+                # statement another live coordinator serves —
+                # redirect via its lease payload. Loop-free:
+                # coordinators only advertise qids they can
+                # resolve locally
+                peer = coord.locate_peer(qid)
+                if peer:
+                    return self._json(
+                        200,
+                        {
+                            "id": qid,
+                            "nextUri": (
+                                f"{peer}/v1/statement/{qid}/{token}"
+                            ),
+                        },
+                    )
+                return self._json(404, {"error": "no such query"})
+            if q.state == "SUSPENDED" and not q.done.is_set():
+                # QoS preempt-and-resume: a parked query must not
+                # hold its client on the long-poll — answer NOW
+                # with empty data and a retry hint, keeping the
+                # poll loop alive (and cheap) until resume
+                return self._json(
+                    200,
+                    {
+                        "id": qid,
+                        "stats": {"state": "SUSPENDED"},
+                        "data": [],
+                        "nextUri": (
+                            f"{coord.uri}/v1/statement/{qid}/"
+                            f"{token}"
+                        ),
+                    },
+                    extra_headers={"Retry-After": "0.5"},
+                )
+            # long-poll up to 1s for progress (reference: long-poll)
+            with tracing.wait("coordinator.long_poll"):
+                q.done.wait(timeout=1.0)
+            # q.error decides failure delivery alongside the state
+            # string: a rare suspension decision racing a kill can
+            # leave a non-FAILED state on a done-with-error query,
+            # and the client must still get the error, never an
+            # empty success page
+            if q.state == "FAILED" or (
+                q.done.is_set() and q.error is not None
+            ):
+                q._drained = True  # error delivered: safe to evict
+                return self._json(
+                    200,
+                    {
+                        "id": qid,
+                        "error": q.error,
+                        "stats": {"state": "FAILED"},
+                    },
+                )
+            if not q.done.is_set():
+                return self._json(
+                    200,
+                    {
+                        "id": qid,
+                        "stats": {"state": q.state},
+                        "nextUri": (
+                            f"{coord.uri}/v1/statement/{qid}/{token}"
+                        ),
+                    },
+                )
+            lo = token * RESULT_PAGE_ROWS
+            hi = min(lo + RESULT_PAGE_ROWS, len(q.rows))
+            out = {
+                "id": qid,
+                "columns": q.columns,
+                "data": q.rows[lo:hi],
+                "stats": {"state": "FINISHED"},
+            }
+            if hi < len(q.rows):
+                out["nextUri"] = (
+                    f"{coord.uri}/v1/statement/{qid}/{token + 1}"
+                )
+            else:
+                q._drained = True  # last page served
+            # prepared-statement session updates ride the result
+            # response (server.protocol): the client folds them
+            # into the map it replays on future requests
+            extra = {}
+            if q.added_prepare is not None:
+                from presto_tpu.server import protocol
+
+                name, text = q.added_prepare
+                # echo once: only on the FIRST result page, and
+                # only when the client's replayed map does not
+                # already carry the identical statement — a client
+                # that knows the name must not re-absorb (and
+                # re-serialize) it on every page of every request
+                if token == 0 and q.prepared.get(name) != text:
+                    extra[protocol.ADDED_PREPARE_HEADER] = (
+                        protocol.encode_prepared(name, text)
+                    )
+            if q.deallocated_prepare is not None:
+                from presto_tpu.server import protocol
+
+                extra[protocol.DEALLOCATED_PREPARE_HEADER] = (
+                    q.deallocated_prepare
+                )
+            return self._json(200, out, extra_headers=extra)
 
     return Handler
 

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from presto_tpu.utils import tracing
 from presto_tpu.utils.metrics import REGISTRY
 from presto_tpu.utils.telemetry import DEVICE
 
@@ -630,7 +631,8 @@ def ici_fetch(
                 if SEGMENT.peek(slice_id, src_task) == "sealed":
                     continue
                 break
-        SEGMENT.wait(0.05)
+        with tracing.wait("exchange_spi.ici_fetch"):
+            SEGMENT.wait(0.05)
     REGISTRY.counter("exchange.ici_fallbacks").update()
     return None
 
@@ -820,7 +822,8 @@ class _CollectiveCache:
                     self._entries[key] = e
                     break
                 if e["state"] == "building":
-                    self._cond.wait(1.0)
+                    with tracing.wait("exchange_spi.collective_build"):
+                        self._cond.wait(1.0)
                     continue
                 return e["entry"]
         built = None
@@ -1148,6 +1151,7 @@ def ici_gather(slice_id: str, spec, deadline: float, probe, fold=None):
                 if SEGMENT.peek(slice_id, src) == "sealed":
                     continue
                 break
-        SEGMENT.wait(0.05)
+        with tracing.wait("exchange_spi.ici_gather"):
+            SEGMENT.wait(0.05)
     REGISTRY.counter("exchange.ici_fallbacks").update()
     return None

@@ -34,6 +34,7 @@ from presto_tpu.connectors.tpch import DictColumn
 from presto_tpu.exec.staging import MaskedColumn
 from presto_tpu.server.protocol import decode as _decode_type
 from presto_tpu.server.protocol import encode as _encode_type
+from presto_tpu.utils import tracing
 
 _MAGIC = b"PTP1"
 
@@ -404,19 +405,21 @@ def page_to_wire_columns(page, fetched_n: Optional[int] = None):
 
     from presto_tpu.exec.staging import ArrayColumn
 
-    n = fetched_n if fetched_n is not None else int(page.num_valid)
-    leaves = []
-    for blk in page.blocks:
-        if blk.offsets is not None:
-            # array block: offsets prefix + FULL flat values (live
-            # extent is data-dependent; serialize trims to offsets[-1])
-            leaves.append(blk.offsets[: n + 1])
-            leaves.append(blk.data)
-        else:
-            leaves.append(blk.data[:n])
-        if blk.valid is not None:
-            leaves.append(blk.valid[:n])
-    fetched = jax.device_get(leaves)
+    with tracing.phase("fetch", site="wire"):
+        n = fetched_n if fetched_n is not None else int(page.num_valid)
+        leaves = []
+        for blk in page.blocks:
+            if blk.offsets is not None:
+                # array block: offsets prefix + FULL flat values (live
+                # extent is data-dependent; serialize trims to
+                # offsets[-1])
+                leaves.append(blk.offsets[: n + 1])
+                leaves.append(blk.data)
+            else:
+                leaves.append(blk.data[:n])
+            if blk.valid is not None:
+                leaves.append(blk.valid[:n])
+        fetched = jax.device_get(leaves)
     cols = []
     i = 0
     for name, blk in zip(page.names, page.blocks):

@@ -69,7 +69,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from presto_tpu.session import NodeConfig
-from presto_tpu.utils import faults
+from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY, DistributionStat
 
 log = logging.getLogger("presto_tpu.qos")
@@ -240,7 +240,10 @@ class QosController:
         if victim is not None:
             REGISTRY.counter("qos.preempt_triggers").update()
             self._apply_suspend_effects(victim)
-        while not entry.event.wait(timeout=0.1):
+        while True:
+            with tracing.wait("qos.admit"):
+                if entry.event.wait(timeout=0.1):
+                    break
             if q.done.is_set() or self.coord._shutting_down:
                 return False
             victim = None
@@ -476,7 +479,10 @@ class QosController:
         if entry is None:
             return
         if not entry.event.is_set():
-            while not entry.event.wait(timeout=0.1):
+            while True:
+                with tracing.wait("qos.checkpoint"):
+                    if entry.event.wait(timeout=0.1):
+                        break
                 if q.done.is_set() or self.coord._shutting_down:
                     return
         if entry.resume_pending:
@@ -508,7 +514,8 @@ class QosController:
         re-dispatch (storm with a free slot) must not journal the
         resume before the suspend frame or un-suspend a state write in
         flight."""
-        entry.effects_done.wait(timeout=10.0)
+        with tracing.wait("qos.resume_effects"):
+            entry.effects_done.wait(timeout=10.0)
         dur = 0.0
         fire = False
         with self._cond:

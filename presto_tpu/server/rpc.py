@@ -33,7 +33,7 @@ import urllib.error
 import urllib.request
 from typing import List, Optional
 
-from presto_tpu.utils import faults
+from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY
 
 #: connection-level failures eligible for retry. ``TimeoutError`` and
@@ -170,6 +170,7 @@ def call(
     headers=None,
     traceparent: str = "",
     idempotent: Optional[bool] = None,
+    wait_site: str = "",
 ) -> RpcResponse:
     """One RPC with bounded retries.
 
@@ -177,7 +178,18 @@ def call(
     POST) and only for connection-level failures — an HTTP error
     status or an application exception propagates immediately. Sleeps
     between attempts follow :func:`compute_backoff`.
+
+    ``wait_site``: callers on a statement's path name the round trip
+    (a ``wait`` of utils/tracing.py, backoff sleeps included);
+    heartbeats and announcers leave it empty and are not timed.
     """
+    if wait_site:
+        with tracing.wait(wait_site):
+            return call(
+                method, url, body, policy=policy, timeout_s=timeout_s,
+                headers=headers, traceparent=traceparent,
+                idempotent=idempotent,
+            )
     if idempotent is None:
         idempotent = method != "POST"
     hdrs = dict(headers or ())
@@ -228,6 +240,7 @@ def pull_pages(
     stall=None,
     timeout_msg: str = "",
     depth: Optional[int] = None,
+    site: str = "rpc",
 ) -> list:
     """The token-acked exchange pull loop (one implementation for the
     coordinator's gather and the worker's shuffle read): GET
@@ -246,7 +259,10 @@ def pull_pages(
 
     ``stall()`` runs when no page is ready yet (default: short sleep);
     callers use it to poll task status and surface failures. The
-    deadline is monotonic."""
+    deadline is monotonic. ``site`` ("coordinator", "worker") names
+    the loop's waits: ``<site>.pull_get`` each GET,
+    ``<site>.pull_result`` the caller blocked on a pipelined GET,
+    ``<site>.pull_idle`` the default sleep."""
     from presto_tpu.server import pages_wire
 
     depth = max(1, policy.pull_depth if depth is None else int(depth))
@@ -260,7 +276,12 @@ def pull_pages(
             policy=policy,
             traceparent=traceparent,
             headers={"X-Ack": str(ack)},
+            wait_site=site + ".pull_get",
         )
+
+    def idle() -> None:
+        with tracing.wait(site + ".pull_idle"):
+            time.sleep(0.02)
 
     def timed_out() -> bool:
         return time.monotonic() > deadline
@@ -288,7 +309,7 @@ def pull_pages(
                 if stall is not None:
                     stall()
                 else:
-                    time.sleep(0.02)
+                    idle()
             token = nxt
         # not reached
 
@@ -302,7 +323,8 @@ def pull_pages(
             for t in range(token, token + depth):
                 if t not in inflight:
                     inflight[t] = executor.submit(fetch, t, token)
-            resp = inflight.pop(token).result()
+            with tracing.wait(site + ".pull_result"):
+                resp = inflight.pop(token).result()
             complete = resp.headers.get("X-Complete") == "true"
             if resp.status == 200:
                 out.append(pages_wire.deserialize_page(resp.body))
@@ -318,7 +340,7 @@ def pull_pages(
             if stall is not None:
                 stall()
             else:
-                time.sleep(0.02)
+                idle()
     finally:
         for f in inflight.values():
             f.cancel()

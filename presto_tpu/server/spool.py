@@ -43,7 +43,7 @@ import time
 import zlib
 from typing import Dict, List, Optional
 
-from presto_tpu.utils import faults
+from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY
 
 _MAGIC = b"SPL1"
@@ -104,7 +104,8 @@ class SpoolDrain:
         references); blocks while the queue is at depth."""
         with self._cond:
             while len(self._queue) >= self.depth and not self._closed:
-                self._cond.wait(0.1)
+                with tracing.wait("spool.drain_submit"):
+                    self._cond.wait(0.1)
             if not self._closed:
                 self._queue.append((task_id, fn))
                 self._pending[task_id] = (
@@ -128,7 +129,8 @@ class SpoolDrain:
                     raise TimeoutError(
                         f"spool drain flush timed out for {task_id}"
                     )
-                self._cond.wait(min(left, 0.1))
+                with tracing.wait("spool.drain_flush"):
+                    self._cond.wait(min(left, 0.1))
             err = self._failed.pop(task_id, None)
         if err is not None:
             raise RuntimeError(

@@ -197,7 +197,8 @@ class _Task:
                     >= MAX_BUFFERED_PAGES
                     and self.state == "RUNNING"
                 ):
-                    self.cond.wait(timeout=0.1)
+                    with tracing.wait("worker.output_buffer"):
+                        self.cond.wait(timeout=0.1)
                 if self.state == "ABORTED":
                     raise RuntimeError("task aborted")
                 self.parts[part].append(page)
@@ -824,6 +825,12 @@ class WorkerServer:
         return spec.task_id
 
     def _run_task(self, task: _Task) -> None:
+        # the task thread's own time is ``exec``; staging, dispatch,
+        # fetch and the waits inside come out of it as children
+        with tracing.phase("exec", site="task"):
+            self._run_task_body(task)
+
+    def _run_task_body(self, task: _Task) -> None:
         task.state = "RUNNING"
         task.stats.state = "RUNNING"
         trace_id = task.trace_ctx[0] if task.trace_ctx else ""
@@ -1056,8 +1063,9 @@ class WorkerServer:
 
         def run_batch(lo: int, hi: int):
             self.runner._qs_local.value = task.stats
-            page, release = stage_batch(lo, hi)
-            return exec_batch(page, release)
+            with tracing.phase("exec", site="batch"):
+                page, release = stage_batch(lo, hi)
+                return exec_batch(page, release)
 
         # dynamic-filter SUMMARY task: batch outputs fold into one
         # per-key summary (exec/dynfilter.py — min/max + NDV-capped
@@ -1157,7 +1165,9 @@ class WorkerServer:
         with ThreadPoolExecutor(spec.task_concurrency) as pool:
             futs = [pool.submit(run_batch, lo, hi) for lo, hi in ranges]
             for f in futs:
-                emit(f.result())
+                with tracing.wait("worker.batch_futures"):
+                    out = f.result()
+                emit(out)
         finish_summary()
 
     def _emit_result(self, task: "_Task", out) -> None:
@@ -1195,6 +1205,7 @@ class WorkerServer:
                 st = rpc.call_json(
                     "GET", f"{uri}/v1/task/{src_task}/status",
                     policy=rpc.RpcPolicy(timeout_s=2.0, retries=0),
+                    wait_site="worker.ici_probe",
                 )
                 return st.get("state") in ("QUEUED", "RUNNING")
             except Exception:
@@ -1418,7 +1429,8 @@ class WorkerServer:
                         raise TimeoutError(
                             "merge task timed out waiting for sources"
                         )
-                    task.cond.wait(timeout=0.1)
+                    with tracing.wait("worker.merge_sources"):
+                        task.cond.wait(timeout=0.1)
                     continue
             for src in pending:
                 uri, src_task = src[0], src[1]
@@ -1671,6 +1683,7 @@ def _pull_partition(
         policy=policy,
         deadline_s=float(session.get("query_max_run_time_s")),
         timeout_msg=f"shuffle pull of {src_task}[{part}] timed out",
+        site="worker",
     )
 
 
@@ -1706,151 +1719,164 @@ def _make_handler(worker: WorkerServer):
                 self.wfile.write(body)
                 return
             if len(parts) == 4 and parts[:2] == ["v1", "task"] and parts[3] == "status":
-                t = worker.tasks.get(parts[2])
-                if t is None:
-                    return self._json(404, {"error": "no such task"})
-                return self._json(
-                    200,
-                    {
-                        "task_id": parts[2],
-                        "state": t.state,
-                        "error": t.error,
-                        "num_pages": len(t.pages),
-                        # durable-copy flag: a FINISHED+spooled task's
-                        # output outlives this worker (drain protocol;
-                        # QoS suspend-progress accounting reads it too)
-                        "spooled": t.spooled,
-                        "stats": t.stats.to_dict(),
-                        "spans": t.spans,
-                        "dynamic_filter": t.dynfilter,
-                    },
-                )
+                with tracing.phase("schedule", site="worker.status"):
+                    return self._task_status(parts[2])
             if (
                 len(parts) == 6
                 and parts[:2] == ["v1", "task"]
                 and parts[3] == "results"
             ):
-                # /v1/task/{id}/results/{buffer}/{token}
-                t = worker.tasks.get(parts[2])
-                if t is None:
-                    return self._json(404, {"error": "no such task"})
-                part = int(parts[4])
-                token = int(parts[5])
-                if t.state == "FAILED":
-                    return self._json(500, {"error": t.error})
-                if not (0 <= part < len(t.parts)):
-                    return self._json(
-                        400, {"error": f"no output buffer {part}"}
+                with tracing.phase("schedule", site="worker.results"):
+                    return self._task_results(
+                        parts[2], int(parts[4]), int(parts[5])
                     )
-                # pulling token N acks pages < N (frees buffer slots and
-                # unblocks the producer — the reference's token-advance
-                # ack). A pipelined client sends an explicit X-Ack floor
-                # instead: its speculative in-flight request for token
-                # N+k must NOT free pages it hasn't consumed yet.
-                ack_hdr = self.headers.get("X-Ack")
-                t.ack_below(
-                    int(ack_hdr) if ack_hdr is not None else token,
-                    part,
+            self._json(404, {"error": f"no route {self.path}"})
+
+        def _task_status(self, task_id: str):
+            t = worker.tasks.get(task_id)
+            if t is None:
+                return self._json(404, {"error": "no such task"})
+            return self._json(
+                200,
+                {
+                    "task_id": task_id,
+                    "state": t.state,
+                    "error": t.error,
+                    "num_pages": len(t.pages),
+                    # durable-copy flag: a FINISHED+spooled task's
+                    # output outlives this worker (drain protocol;
+                    # QoS suspend-progress accounting reads it too)
+                    "spooled": t.spooled,
+                    "stats": t.stats.to_dict(),
+                    "spans": t.spans,
+                    "dynamic_filter": t.dynfilter,
+                },
+            )
+
+        def _task_results(self, task_id: str, part: int, token: int):
+            # /v1/task/{id}/results/{buffer}/{token}
+            t = worker.tasks.get(task_id)
+            if t is None:
+                return self._json(404, {"error": "no such task"})
+            if t.state == "FAILED":
+                return self._json(500, {"error": t.error})
+            if not (0 <= part < len(t.parts)):
+                return self._json(
+                    400, {"error": f"no output buffer {part}"}
                 )
-                # snapshot (page, count, state) ATOMICALLY: reading
-                # len(pages) then state unlocked races the producer's
-                # final append + FINISHED publish — a 204 with
-                # X-Complete=true would silently drop the last page
-                # (pipelined pulls keep a beyond-the-end token in
-                # flight, so the race window is hit on every pull).
-                # Lazy ICI degrade rides the SAME snapshot: a wire
-                # pull of a FINISHED in-slice task (a merge retry
-                # that landed cross-slice) must see the real pages —
-                # an ICI task's serialized buffers are empty until
-                # materialized, and FINISHED + empty would read as a
-                # complete zero-row partition (silent data loss). The
-                # FINISHED decision and the materialize check happen
-                # on the LOCKED state, then the snapshot re-runs: a
-                # producer publishing FINISHED between an unlocked
-                # pre-check and the snapshot can never slip through.
-                while True:
-                    with t.cond:
-                        pages = t.parts[part]
-                        body = (
-                            pages[token] if token < len(pages) else None
-                        )
-                        n_pages = len(pages)
-                        state = t.state
-                        complete = state == "FINISHED" and (
-                            token + (1 if body is not None else 0)
-                            >= n_pages
-                        )
-                        need_mat = (
-                            state == "FINISHED"
-                            and bool(t.spec.ici_slice)
-                            and not t._ici_mat_done
-                        )
-                        if complete and not need_mat:
-                            # drain protocol: this consumer has seen
-                            # the whole stream — the buffer no longer
-                            # pins a draining worker alive
-                            t.complete_served[part] = True
-                    if not need_mat:
-                        break
-                    worker._materialize_ici(t)
-                if body is not None:
-                    self.send_response(200)
-                    self.send_header(
-                        "Content-Type", "application/x-presto-tpu-page"
+            # pulling token N acks pages < N (frees buffer slots and
+            # unblocks the producer — the reference's token-advance
+            # ack). A pipelined client sends an explicit X-Ack floor
+            # instead: its speculative in-flight request for token
+            # N+k must NOT free pages it hasn't consumed yet.
+            ack_hdr = self.headers.get("X-Ack")
+            t.ack_below(
+                int(ack_hdr) if ack_hdr is not None else token,
+                part,
+            )
+            # snapshot (page, count, state) ATOMICALLY: reading
+            # len(pages) then state unlocked races the producer's
+            # final append + FINISHED publish — a 204 with
+            # X-Complete=true would silently drop the last page
+            # (pipelined pulls keep a beyond-the-end token in
+            # flight, so the race window is hit on every pull).
+            # Lazy ICI degrade rides the SAME snapshot: a wire
+            # pull of a FINISHED in-slice task (a merge retry
+            # that landed cross-slice) must see the real pages —
+            # an ICI task's serialized buffers are empty until
+            # materialized, and FINISHED + empty would read as a
+            # complete zero-row partition (silent data loss). The
+            # FINISHED decision and the materialize check happen
+            # on the LOCKED state, then the snapshot re-runs: a
+            # producer publishing FINISHED between an unlocked
+            # pre-check and the snapshot can never slip through.
+            while True:
+                with t.cond:
+                    pages = t.parts[part]
+                    body = (
+                        pages[token] if token < len(pages) else None
                     )
-                    self.send_header("Content-Length", str(len(body)))
-                    self.send_header("X-Next-Token", str(token + 1))
-                    self.send_header(
-                        "X-Complete", "true" if complete else "false"
+                    n_pages = len(pages)
+                    state = t.state
+                    complete = state == "FINISHED" and (
+                        token + (1 if body is not None else 0)
+                        >= n_pages
                     )
-                    self.end_headers()
-                    self.wfile.write(body)
-                    return
-                # no page at this token yet
-                self.send_response(204)
-                self.send_header("Content-Length", "0")
-                self.send_header("X-Next-Token", str(token))
+                    need_mat = (
+                        state == "FINISHED"
+                        and bool(t.spec.ici_slice)
+                        and not t._ici_mat_done
+                    )
+                    if complete and not need_mat:
+                        # drain protocol: this consumer has seen
+                        # the whole stream — the buffer no longer
+                        # pins a draining worker alive
+                        t.complete_served[part] = True
+                if not need_mat:
+                    break
+                worker._materialize_ici(t)
+            if body is not None:
+                self.send_response(200)
+                self.send_header(
+                    "Content-Type", "application/x-presto-tpu-page"
+                )
+                self.send_header("Content-Length", str(len(body)))
+                self.send_header("X-Next-Token", str(token + 1))
                 self.send_header(
                     "X-Complete", "true" if complete else "false"
                 )
                 self.end_headers()
+                self.wfile.write(body)
                 return
-            self._json(404, {"error": f"no route {self.path}"})
+            # no page at this token yet
+            self.send_response(204)
+            self.send_header("Content-Length", "0")
+            self.send_header("X-Next-Token", str(token))
+            self.send_header(
+                "X-Complete", "true" if complete else "false"
+            )
+            self.end_headers()
+            return
 
         def do_POST(self):
             parts = [p for p in self.path.split("/") if p]
             if parts == ["v1", "task"]:
-                if worker._draining or worker._shutting_down:
-                    # reject BEFORE parsing: 503 tells the coordinator
-                    # to reschedule on another worker (no task was
-                    # created here)
-                    return self._json(
-                        503, {"error": "worker is draining"}
-                    )
-                try:
-                    spec = FragmentSpec.from_json(
-                        json.loads(self._read_body().decode())
-                    )
-                    # honor the propagated trace context: a header on
-                    # the POST covers specs from span-unaware clients
-                    hdr = self.headers.get("traceparent", "")
-                    if hdr and not spec.traceparent:
-                        import dataclasses as _dc
-
-                        spec = _dc.replace(spec, traceparent=hdr)
-                    tid = worker.create_task(spec)
-                    return self._json(200, {"task_id": tid})
-                except WorkerDraining as e:
-                    return self._json(503, {"error": str(e)})
-                except Exception as e:
-                    return self._json(400, {"error": str(e)})
+                with tracing.phase("schedule", site="worker.task_post"):
+                    return self._post_task()
             self._json(404, {"error": f"no route {self.path}"})
+
+        def _post_task(self):
+            if worker._draining or worker._shutting_down:
+                # reject BEFORE parsing: 503 tells the coordinator
+                # to reschedule on another worker (no task was
+                # created here)
+                return self._json(
+                    503, {"error": "worker is draining"}
+                )
+            try:
+                spec = FragmentSpec.from_json(
+                    json.loads(self._read_body().decode())
+                )
+                # honor the propagated trace context: a header on
+                # the POST covers specs from span-unaware clients
+                hdr = self.headers.get("traceparent", "")
+                if hdr and not spec.traceparent:
+                    import dataclasses as _dc
+
+                    spec = _dc.replace(spec, traceparent=hdr)
+                tid = worker.create_task(spec)
+                return self._json(200, {"task_id": tid})
+            except WorkerDraining as e:
+                return self._json(503, {"error": str(e)})
+            except Exception as e:
+                return self._json(400, {"error": str(e)})
 
         def do_DELETE(self):
             parts = [p for p in self.path.split("/") if p]
             if len(parts) == 3 and parts[:2] == ["v1", "task"]:
-                worker.delete_task(parts[2])
-                return self._json(200, {"ok": True})
+                with tracing.phase("schedule", site="worker.delete"):
+                    worker.delete_task(parts[2])
+                    return self._json(200, {"ok": True})
             self._json(404, {"error": f"no route {self.path}"})
 
         def do_PUT(self):

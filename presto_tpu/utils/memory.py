@@ -41,7 +41,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from presto_tpu.utils import faults
+from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY
 
 
@@ -247,7 +247,10 @@ class MemoryPool:
                             f"(pool limit {self.limit}B, in use "
                             f"{total}B)"
                         )
-                    self._cond.wait(timeout=min(0.05, deadline - now))
+                    with tracing.wait("memory.reserve_blocked"):
+                        self._cond.wait(
+                            timeout=min(0.05, deadline - now)
+                        )
             finally:
                 self._blocked.pop(token, None)
 

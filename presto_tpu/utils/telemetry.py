@@ -46,7 +46,53 @@ import threading
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+from presto_tpu.utils import tracing
 from presto_tpu.utils.metrics import REGISTRY
+
+# ------------------------------------------------------- true compiles
+
+_XLA_REQUEST = "/jax/core/compile/backend_compile_duration"
+_XLA_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_XLA_CACHE_LOAD_S = "/jax/compilation_cache/cache_retrieval_time_sec"
+_xla_lock = threading.Lock()
+_xla = {"requests": 0, "cache_loads": 0, "compile_s": 0.0}
+_xla_installed = False
+
+
+def _on_xla_event(event: str, **_kw) -> None:
+    if event == _XLA_CACHE_HIT and DEVICE.enabled:
+        with _xla_lock:
+            _xla["cache_loads"] += 1
+
+
+def _on_xla_duration(event: str, duration_secs: float, **_kw) -> None:
+    # backend_compile_duration wraps compile_or_get_cached, so it fires
+    # once a compile REQUEST, cache load or not; a load's retrieval
+    # time is reported apart and comes back out of the compile time
+    if event == _XLA_REQUEST:
+        if DEVICE.enabled:
+            with _xla_lock:
+                _xla["requests"] += 1
+                _xla["compile_s"] += duration_secs
+    elif event == _XLA_CACHE_LOAD_S and DEVICE.enabled:
+        with _xla_lock:
+            _xla["compile_s"] -= duration_secs
+
+
+def install_xla_listeners() -> None:
+    """Count every XLA compile of the process through
+    ``jax.monitoring`` — the engine's fragments, the mesh runner's
+    programs and eager ops alike — and tell a true compile from a
+    load out of the persistent cache. Called once, at import of
+    ``presto_tpu``."""
+    global _xla_installed
+    if _xla_installed:
+        return
+    _xla_installed = True
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_xla_event)
+    monitoring.register_event_duration_secs_listener(_on_xla_duration)
 
 
 class DeviceTelemetry:
@@ -72,6 +118,7 @@ class DeviceTelemetry:
 
     def set_enabled(self, flag: bool) -> None:
         self.enabled = bool(flag)
+        tracing.set_accumulating(self.enabled)
 
     # ---------------------------------------------- choke-point hooks
 
@@ -112,8 +159,18 @@ class DeviceTelemetry:
 
     def snapshot(self) -> Dict[str, float]:
         """Current totals (the bench diffs two of these around each
-        measurement; tests assert zero delta when disabled)."""
-        return {
+        measurement; tests assert zero delta when disabled).
+
+        ``compiles`` / ``compile_ms`` are the engine's own first
+        dispatches; ``xla_compiles`` counts what XLA really compiled
+        process-wide (compile requests that were not loads from the
+        persistent cache), ``xla_cache_loads`` the loads,
+        ``xla_compile_ms`` the time in the compiles. ``span_ms.*``,
+        ``wait_ms.*`` and ``stmt_wall_ms`` are host time per layer
+        (utils/tracing.py)."""
+        with _xla_lock:
+            xla = dict(_xla)
+        out = {
             "dispatches": int(self._dispatches.total),
             "compiles": int(self._compiles.total),
             "compile_ms": float(self._compile_ms.values()["sum"]),
@@ -121,7 +178,12 @@ class DeviceTelemetry:
             "d2h_bytes": int(self._d2h.total),
             "pad_rows": int(self._pad.total),
             "live_rows": int(self._live.total),
+            "xla_compiles": xla["requests"] - xla["cache_loads"],
+            "xla_cache_loads": xla["cache_loads"],
+            "xla_compile_ms": xla["compile_s"] * 1000.0,
         }
+        out.update(tracing.span_snapshot())
+        return out
 
 
 #: process-wide device-plane accounting (the ONE instance; servers

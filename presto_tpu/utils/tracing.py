@@ -13,18 +13,188 @@ in both coordinator and worker logs.
 The tree is servable WHILE the query runs (an open span has
 ``end == 0``), which is what makes "what is query q_7 doing right now"
 answerable from ``/v1/query/{id}``.
+
+Measurement rides the same primitive. :func:`phase` times a piece of
+a statement's path on ``time.perf_counter_ns()`` and, on close, adds
+its SELF time (duration minus the phases that closed inside it on the
+same thread) to a process-wide accumulator keyed by one of eight
+names: seven kinds of *work* (:data:`WORK`, one a layer of PERF.md §3)
+and ``wait`` — every place a statement's thread blocks on another
+thread, a timer or a socket (``site=`` says which).
+``telemetry.device_snapshot()`` serves the accumulator as
+``span_ms.<name>`` / ``wait_ms.<site>`` / ``stmt_wall_ms``, which is
+how the benchmark reads host time per layer. :meth:`Trace.span` is a
+:func:`phase` that also hangs a node on the query's tree. With
+``PRESTO_TPU_PROFILE_SPANS=1`` every phase is also a
+``jax.profiler.TraceAnnotation`` named ``presto:<name>[/<site>]``, so
+a profile holds the engine's spans beside the device's operations
+(``tools/trace_gaps.py`` reads them).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import logging
+import os
 import threading
 import time
 import uuid
 from typing import Dict, List, Optional
 
-log = logging.getLogger("presto_tpu.trace")
+#: the work names: the thread is running the engine's code (for
+#: ``fetch``: blocked on the device). One name a layer boundary;
+#: detail goes in ``site=`` and attributes, never in the name.
+WORK = (
+    "protocol", "plan", "schedule", "staging", "dispatch", "fetch", "exec",
+)
+#: every blocking on another thread, a timer or a socket
+WAIT = "wait"
+#: prefix of the profiler annotations (``presto:<name>[/<site>]``)
+ANNOTATION = "presto:"
+
+#: ``PRESTO_TPU_PROFILE_SPANS=1``: phases are also TraceAnnotations on
+#: the profiler's clock. Read once; off by default because the
+#: benchmark's reduction counts every host event it does not know as
+#: time inside the JAX runtime.
+PROFILE_SPANS = os.environ.get("PRESTO_TPU_PROFILE_SPANS", "") not in (
+    "", "0",
+)
+
+#: the served tree's display names (README "Observability") and the
+#: accumulator name each one's self time belongs to
+_TREE_LAYER = {
+    "query": "exec",
+    "execute": "exec",
+    "gather": "exec",
+    "task": "exec",
+    "execute-local": "exec",
+    "execute-local-fallback": "exec",
+    "fragment": "plan",
+    "dynfilter": "schedule",
+    "recovery": "schedule",
+    "stage:prefetch": "staging",
+}
+_TREE_LAYER.update({n: n for n in WORK})
+
+_tls = threading.local()
+_acc_lock = threading.Lock()
+_self_ns: Dict[str, int] = {n: 0 for n in WORK + (WAIT,)}
+_wait_ns: Dict[str, int] = {}
+_stmt_wall_ns = 0
+_accumulate = True  # follows telemetry.DEVICE.enabled
+
+
+def set_accumulating(flag: bool) -> None:
+    """``telemetry.enabled=false`` freezes the accumulator too."""
+    global _accumulate
+    _accumulate = bool(flag)
+
+
+class _Phase:
+    """Context manager of :func:`phase`; ``span`` is the tree node
+    when :meth:`Trace.span` made it."""
+
+    __slots__ = (
+        "name", "site", "span", "_trace", "_t0", "_child_ns", "_ann",
+    )
+
+    def __init__(self, name, site, trace=None, span=None):
+        self.name = name
+        self.site = site
+        self.span = span
+        self._trace = trace
+        self._child_ns = 0
+        self._ann = None
+
+    def __enter__(self):
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+        stack.append(self)
+        if self._trace is not None:
+            self._trace._push(self.span)
+        if PROFILE_SPANS:
+            import jax
+
+            label = ANNOTATION + self.name
+            if self.site:
+                label += "/" + self.site
+            if self.span is not None:
+                self._ann = jax.profiler.TraceAnnotation(
+                    label, trace_id=self.span.trace_id
+                )
+            else:
+                self._ann = jax.profiler.TraceAnnotation(label)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        stack = _tls.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            stack.remove(self)
+        if stack:
+            stack[-1]._child_ns += dur
+        if self._trace is not None:
+            self._trace._pop(self.span, dur, failed=exc is not None)
+        if _accumulate:
+            with _acc_lock:
+                _self_ns[self.name] += max(dur - self._child_ns, 0)
+                if self.name == WAIT:
+                    _wait_ns[self.site] = (
+                        _wait_ns.get(self.site, 0) + dur
+                    )
+        return False
+
+
+def phase(name: str, site: str = "") -> _Phase:
+    """Time one piece of a statement's path: ``with phase("staging"):``.
+    ``name`` is one of :data:`WORK` or ``"wait"``. Only code running
+    for a statement opens phases — heartbeats, samplers and announcers
+    do not. Never keep one open across a generator's ``yield``: the
+    nesting is per thread."""
+    if name not in _self_ns:
+        raise ValueError(f"unknown phase name {name!r}")
+    return _Phase(name, site)
+
+
+def wait(site: str) -> _Phase:
+    """The phase around a blocking call; ``site`` is
+    ``"<module>.<what>"``, unique per call site."""
+    return _Phase(WAIT, site)
+
+
+def add_stmt_wall(ns: int) -> None:
+    """One statement's whole wall time, client side (a duration, not
+    a self time): what the work names are subtracted from."""
+    global _stmt_wall_ns
+    if _accumulate:
+        with _acc_lock:
+            _stmt_wall_ns += ns
+
+
+def span_snapshot() -> Dict[str, float]:
+    """The accumulator in ms: ``span_ms.<work name>`` self times,
+    ``span_ms.unworked`` = statement wall minus the seven — the time
+    statements spent with no thread working on them (a poller that
+    woke late, a queue, a sleep, code with no phase) —
+    ``stmt_wall_ms``, and ``wait_ms.<site>`` per wait site, raw and
+    overlapping across threads."""
+    with _acc_lock:
+        work = {n: _self_ns[n] / 1e6 for n in WORK}
+        waits = {s: ns / 1e6 for s, ns in _wait_ns.items()}
+        wall = _stmt_wall_ns / 1e6
+    out = {f"span_ms.{n}": v for n, v in work.items()}
+    out["span_ms.unworked"] = wall - sum(work.values())
+    out["stmt_wall_ms"] = wall
+    for s in sorted(waits):
+        out[f"wait_ms.{s}"] = waits[s]
+    return out
 
 #: traceparent version field (only 00 exists; parsed leniently)
 _TP_VERSION = "00"
@@ -68,9 +238,14 @@ class Span:
     start: float
     end: float = 0.0
     attrs: Dict[str, object] = dataclasses.field(default_factory=dict)
+    #: ``time.perf_counter_ns()`` duration, set when a live span
+    #: closes; ``start``/``end`` are wall-clock, for display only
+    dur_ns: int = 0
 
     @property
     def duration_ms(self) -> float:
+        if self.dur_ns:
+            return self.dur_ns / 1e6
         end = self.end or time.time()
         return (end - self.start) * 1000.0
 
@@ -99,22 +274,6 @@ class Span:
         )
 
 
-class _SpanCtx:
-    """Context manager yielded by :meth:`Trace.span`."""
-
-    def __init__(self, trace: "Trace", span: Span):
-        self._trace = trace
-        self.span = span
-
-    def __enter__(self) -> Span:
-        self._trace._push(self.span)
-        return self.span
-
-    def __exit__(self, exc_type, exc, tb):
-        self._trace._pop(self.span, failed=exc is not None)
-        return False
-
-
 class Trace:
     """One query's span tree; thread-safe, servable mid-flight.
 
@@ -129,19 +288,28 @@ class Trace:
         self.trace_id = trace_id or new_trace_id()
         self._lock = threading.Lock()
         self._spans: List[Span] = []
-        self._stack = threading.local()
         self.root: Optional[Span] = None
 
     # ------------------------------------------------------------ spans
 
     def span(self, name: str, parent: Optional[Span] = None, **attrs):
-        """Open a span; use as ``with trace.span("plan"):``."""
+        """Open a span; use as ``with trace.span("plan"):``. ``name``
+        is one of the tree's display names; its self time accumulates
+        under the name's layer (a :func:`phase` with ``site=name``)."""
+        layer = _TREE_LAYER.get(name)
+        if layer is None:
+            raise ValueError(f"unknown span name {name!r}")
         if parent is None:
-            stack = getattr(self._stack, "value", None)
-            if stack:
-                parent = stack[-1]
-            else:
-                parent = self.root
+            # the innermost open span of THIS trace on this thread
+            # (the phase stack is the one nesting record), else root
+            parent = next(
+                (
+                    p.span
+                    for p in reversed(getattr(_tls, "stack", ()))
+                    if p._trace is self
+                ),
+                self.root,
+            )
         s = Span(
             trace_id=self.trace_id,
             span_id=new_span_id(),
@@ -150,34 +318,21 @@ class Trace:
             start=time.time(),
             attrs=dict(attrs),
         )
-        return _SpanCtx(self, s)
+        return _Phase(
+            layer, "" if layer == name else name, trace=self, span=s
+        )
 
     def _push(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
             if self.root is None:
                 self.root = span
-        stack = getattr(self._stack, "value", None)
-        if stack is None:
-            stack = []
-            self._stack.value = stack
-        stack.append(span)
-        log.debug(
-            "trace=%s span=%s start name=%s parent=%s",
-            self.trace_id, span.span_id, span.name, span.parent_id,
-        )
 
-    def _pop(self, span: Span, failed: bool = False) -> None:
+    def _pop(self, span: Span, dur_ns: int, failed: bool = False) -> None:
         span.end = time.time()
+        span.dur_ns = dur_ns
         if failed:
             span.attrs["error"] = True
-        stack = getattr(self._stack, "value", None)
-        if stack and span in stack:
-            stack.remove(span)
-        log.debug(
-            "trace=%s span=%s end name=%s dur_ms=%.1f",
-            self.trace_id, span.span_id, span.name, span.duration_ms,
-        )
 
     def graft(self, span_dicts) -> None:
         """Attach foreign (worker-side) spans to this tree. Spans whose
