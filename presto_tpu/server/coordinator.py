@@ -46,6 +46,7 @@ from presto_tpu.server.scheduler import (
 from presto_tpu.server.spool import ExchangeSpool
 from presto_tpu.utils import faults, tracing
 from presto_tpu.utils.metrics import REGISTRY, DistributionStat
+from presto_tpu.utils.telemetry import DEVICE
 from presto_tpu.utils.tracing import Trace
 
 log = logging.getLogger("presto_tpu.coordinator")
@@ -823,7 +824,6 @@ class CoordinatorServer:
         # itself follows telemetry.enabled so a disabled cluster stays
         # bit-exact pre-telemetry.
         from presto_tpu.utils.telemetry import (
-            DEVICE,
             MetricsFederation,
             MetricsSampler,
         )
@@ -4726,9 +4726,11 @@ class CoordinatorServer:
 
     def _pull_task(self, w, spec) -> List[tuple]:
         """Token-acked page pulls until X-Complete (exchange client):
-        the shared rpc.pull_pages loop, with a stall hook that polls
-        task status so a FAILED task surfaces its worker-side error
-        text. Monotonic-clock deadline (see _wait_task).
+        the shared rpc.pull_pages loop — a long-poll, the worker holds
+        the head request until the page exists — with a stall hook
+        that looks at the task's status each time a max-wait runs out,
+        so a FAILED or lost task surfaces its worker-side error text.
+        Monotonic-clock deadline (see _wait_task).
 
         ICI gather edge: when the pulled task's stage was planned on
         this coordinator's own slice (single-partition root output,
@@ -4739,6 +4741,10 @@ class CoordinatorServer:
         read), and the task is still DELETEd by the caller."""
 
         def stall():
+            # the worker held the results GET for its whole max-wait
+            # and still has no page: no sleep here, only the look at
+            # the task (one that the worker lost answers 404)
+            DEVICE.count_pull_stall()
             st = self._rpc_json(
                 "GET", f"{w.uri}/v1/task/{spec.task_id}/status",
                 site="coordinator.task_status_poll",
@@ -4747,8 +4753,6 @@ class CoordinatorServer:
                 raise RuntimeError(
                     f"task on {w.node_id} failed: {st.get('error')}"
                 )
-            with tracing.wait("coordinator.pull_stall"):
-                time.sleep(0.05)
 
         if (
             spec.ici_slice

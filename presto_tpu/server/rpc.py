@@ -112,13 +112,22 @@ DEFAULT_POLICY = RpcPolicy()
 
 #: shared executor for pipelined page pulls: one process-wide pool
 #: instead of a fresh ThreadPoolExecutor per pull (no thread churn per
-#: task stream). Speculative fetches are plain bounded-timeout GETs —
-#: no inter-future dependencies, so a shared pool cannot deadlock;
-#: abandoned fetches finish within the rpc timeout and their results
-#: are dropped.
+#: task stream). Speculative fetches are plain bounded-timeout GETs
+#: answered at once (only a pull's head request is held by the
+#: producer, and that one runs on the pull's own thread) — no
+#: inter-future dependencies, so a shared pool cannot deadlock or
+#: starve; abandoned fetches finish within the rpc timeout and their
+#: results are dropped.
 _PULL_POOL = None
 _PULL_POOL_LOCK = threading.Lock()
 _PULL_POOL_WORKERS = 32
+
+#: results long-poll: the header a puller sends with the request for
+#: its head token (milliseconds), and the longest a producer holds a
+#: request — well under ``RpcPolicy.timeout_s``, so a held request is
+#: never taken for a dead peer
+MAX_WAIT_HEADER = "X-Max-Wait"
+PULL_MAX_WAIT_S = 1.0
 
 
 def _pull_executor():
@@ -245,102 +254,106 @@ def pull_pages(
     """The token-acked exchange pull loop (one implementation for the
     coordinator's gather and the worker's shuffle read): GET
     ``/v1/task/{id}/results/{buffer}/{token}`` until ``X-Complete``,
-    advancing the token per ``X-Next-Token``. Returns the deserialized
-    pages.
+    one token a page. Returns the deserialized pages.
 
-    Pipelining (``depth``, default ``policy.pull_depth``): up to
-    ``depth`` token requests stay in flight concurrently, so page
-    N+1's network round trip overlaps page N's decompress/deserialize
-    instead of strictly alternating. Every request carries an
-    ``X-Ack`` header with the CONSUMED floor — the producer frees only
-    pages the puller has actually received, so a speculative in-flight
-    request can never free an unconsumed page (with depth 1 the floor
-    equals the requested token, the historical ack-via-URL behavior).
+    Long-poll: the request for the HEAD token (the next page the
+    caller consumes) carries ``X-Max-Wait`` (:data:`MAX_WAIT_HEADER`,
+    milliseconds), and the producer holds it until the page exists,
+    the task is terminal, or the wait ran out — a page reaches the
+    puller when it exists, not at the next tick of a poll. The head
+    request runs on the calling thread (which blocks on its result
+    anyway), so a held request never occupies the shared pool.
 
-    ``stall()`` runs when no page is ready yet (default: short sleep);
-    callers use it to poll task status and surface failures. The
-    deadline is monotonic. ``site`` ("coordinator", "worker") names
-    the loop's waits: ``<site>.pull_get`` each GET,
-    ``<site>.pull_result`` the caller blocked on a pipelined GET,
-    ``<site>.pull_idle`` the default sleep."""
+    Pipelining (``depth``, default ``policy.pull_depth``): tokens
+    ``head+1 .. head+depth-1`` are requested speculatively on the pool,
+    WITHOUT a max-wait (answered at once), so page N+1's round trip
+    overlaps page N's decompress/deserialize when pages are buffered
+    ahead. Every request carries an ``X-Ack`` header with the CONSUMED
+    floor — the producer frees only pages the puller has actually
+    received, so a speculative in-flight request can never free an
+    unconsumed page (with depth 1 the floor equals the requested
+    token, the historical ack-via-URL behavior).
+
+    Stale-204 rule: a speculative answer was requested before the head
+    reached its token. A page (200) or the end of the stream (204 with
+    ``X-Complete``, final once true) is taken; its "no page yet" (204,
+    not complete) is STALE by the time it is read — it is dropped and
+    the token re-requested as the head, never stalled on.
+
+    ``stall()`` runs on a FRESH non-complete 204 of the head request —
+    the max-wait ran out; callers use it to poll task status and
+    surface failures. A peer that answered well before the max-wait
+    (it does not honour the header, or it is draining) is polled
+    instead: a 20 ms sleep between requests. The deadline is
+    monotonic. ``site`` ("coordinator", "worker") names the loop's
+    waits: ``<site>.pull_get`` each GET (the held head request
+    included), ``<site>.pull_result`` the caller blocked on a
+    speculative GET, ``<site>.pull_idle`` the fallback sleep."""
     from presto_tpu.server import pages_wire
 
     depth = max(1, policy.pull_depth if depth is None else int(depth))
     out: list = []
     deadline = time.monotonic() + deadline_s
+    # the held request must answer well inside the request timeout
+    max_wait_s = min(PULL_MAX_WAIT_S, policy.timeout_s / 2.0)
 
-    def fetch(t: int, ack: int) -> RpcResponse:
+    def fetch(t: int, ack: int, wait_ms: int = 0) -> RpcResponse:
+        hdrs = {"X-Ack": str(ack)}
+        if wait_ms > 0:
+            hdrs[MAX_WAIT_HEADER] = str(wait_ms)
         return call(
             "GET",
             f"{uri}/v1/task/{task_id}/results/{buffer}/{t}",
             policy=policy,
             traceparent=traceparent,
-            headers={"X-Ack": str(ack)},
+            headers=hdrs,
             wait_site=site + ".pull_get",
         )
 
-    def idle() -> None:
-        with tracing.wait(site + ".pull_idle"):
-            time.sleep(0.02)
-
-    def timed_out() -> bool:
-        return time.monotonic() > deadline
-
-    def fail_timeout():
-        raise TimeoutError(
-            timeout_msg or f"pull of {task_id}[{buffer}] timed out"
-        )
-
     token = 0
-    if depth == 1:
-        while True:
-            if timed_out():
-                fail_timeout()
-            resp = fetch(token, token)
-            complete = resp.headers.get("X-Complete") == "true"
-            nxt = int(resp.headers.get("X-Next-Token", token))
-            if resp.status == 200:
-                out.append(pages_wire.deserialize_page(resp.body))
-            if complete and nxt == token + (
-                1 if resp.status == 200 else 0
-            ):
-                return out
-            if nxt == token and resp.status != 200:
-                if stall is not None:
-                    stall()
-                else:
-                    idle()
-            token = nxt
-        # not reached
-
     inflight: dict = {}
-    executor = _pull_executor()
+    executor = _pull_executor() if depth > 1 else None
     try:
         while True:
-            if timed_out():
-                fail_timeout()
-            # keep the window full: tokens [consumed, consumed+depth)
-            for t in range(token, token + depth):
+            left_s = deadline - time.monotonic()
+            if left_s < 0:
+                raise TimeoutError(
+                    timeout_msg
+                    or f"pull of {task_id}[{buffer}] timed out"
+                )
+            # keep the speculative window full: (head, head+depth)
+            for t in range(token + 1, token + depth):
                 if t not in inflight:
                     inflight[t] = executor.submit(fetch, t, token)
-            with tracing.wait(site + ".pull_result"):
-                resp = inflight.pop(token).result()
-            complete = resp.headers.get("X-Complete") == "true"
-            if resp.status == 200:
+            resp = None
+            spec = inflight.pop(token, None)
+            if spec is not None:
+                with tracing.wait(site + ".pull_result"):
+                    resp = spec.result()
+                if (
+                    resp.status != 200
+                    and resp.headers.get("X-Complete") != "true"
+                ):
+                    resp = None  # stale "no page yet": ask again
+            if resp is None:
+                wait_s = min(max_wait_s, left_s)
+                t_asked = time.monotonic()
+                resp = fetch(token, token, int(wait_s * 1000))
+                held_s = time.monotonic() - t_asked
+            got_page = resp.status == 200
+            if got_page:
                 out.append(pages_wire.deserialize_page(resp.body))
                 token += 1
-                if complete:
-                    # that was the final page
-                    return out
-                continue
-            # 204: no page at this token (a speculative response may
-            # be stale — re-request rather than trusting it)
-            if complete:
+            if resp.headers.get("X-Complete") == "true":
                 return out
+            if got_page:
+                continue
+            # a fresh 204, not complete: the max-wait ran out
             if stall is not None:
                 stall()
-            else:
-                idle()
+            if held_s < wait_s / 2.0:
+                with tracing.wait(site + ".pull_idle"):
+                    time.sleep(0.02)
     finally:
         for f in inflight.values():
             f.cancel()

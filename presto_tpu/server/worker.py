@@ -15,6 +15,23 @@ loads only the owned range. The whole fragment compiles to one XLA
 program over the local mesh (the in-slice engine); result pages are
 serialized into the task's output buffer, pulled token-acked by the
 coordinator, and freed on DELETE.
+
+The results endpoint (``GET /v1/task/{id}/results/{buffer}/{token}``):
+request headers ``X-Ack`` (the puller's consumed floor: pages below it
+are freed) and ``X-Max-Wait`` (milliseconds, sent by ``rpc.pull_pages``
+with the request for its head token only; clamped here to
+``rpc.PULL_MAX_WAIT_S``). With ``X-Max-Wait`` the handler holds the
+request on the task's condition until a page exists at ``token``, the
+task is FINISHED / FAILED / ABORTED, the worker drains or shuts down,
+or the wait ran out (the reference's results long-poll, its
+``X-Presto-Max-Wait``); without it, or with a value that is not a
+whole number of ms, the answer is immediate. 200 and 204 carry
+``X-Complete`` and ``X-Next-Token`` (the latter informational:
+``pull_pages`` counts its own tokens and does not read it). Answers:
+200 + page, 204 (no page: ``X-Complete: true`` ends the stream,
+``false`` means "not yet" — stale by the time a speculative request's
+answer is read, so pullers drop it), 500 + error for a FAILED task,
+404 for a task this worker does not have.
 """
 
 from __future__ import annotations
@@ -45,6 +62,7 @@ from presto_tpu.server.spool import (
 )
 from presto_tpu.utils import devicediag, faults, tracing
 from presto_tpu.utils.metrics import REGISTRY
+from presto_tpu.utils.telemetry import DEVICE
 
 log = logging.getLogger("presto_tpu.worker")
 
@@ -203,6 +221,8 @@ class _Task:
                     raise RuntimeError("task aborted")
                 self.parts[part].append(page)
                 self.stats.output_bytes += len(page)
+                # a results GET may be held on this page (long-poll)
+                self.cond.notify_all()
         except BaseException:
             # the page never reached the buffer: its reservation must
             # not leak into the task's release-all at teardown
@@ -504,10 +524,21 @@ class WorkerServer:
             ).start()
         return self
 
+    def _wake_results_waiters(self) -> None:
+        """A results GET held on a task's condition (long-poll)
+        re-reads the worker's state: one that is draining or shutting
+        down holds no request."""
+        with self._lock:
+            tasks = list(self.tasks.values())
+        for t in tasks:
+            with t.cond:
+                t.cond.notify_all()
+
     def shutdown(self, graceful: bool = True) -> None:
         """Graceful: stop accepting work, finish running tasks, stop
         (reference: SHUTTING_DOWN protocol, SURVEY.md §5.3)."""
         self._shutting_down = True
+        self._wake_results_waiters()
         if graceful:
             deadline = time.monotonic() + 30
             while time.monotonic() < deadline:
@@ -542,6 +573,7 @@ class WorkerServer:
             if self._draining or self._shutting_down:
                 return
             self._draining = True
+        self._wake_results_waiters()
         REGISTRY.counter("worker.drains").update()
         log.info("node=%s draining", self.node_id)
         # flip discovery NOW instead of waiting out the announce cadence
@@ -791,6 +823,7 @@ class WorkerServer:
         in-flight coordinator RPC sees a dead peer (connection refused)
         — a real crash, not the graceful SHUTTING_DOWN protocol."""
         self._shutting_down = True
+        self._wake_results_waiters()
         try:
             if self._serve_thread.is_alive():
                 self.httpd.shutdown()
@@ -1687,6 +1720,15 @@ def _pull_partition(
     )
 
 
+def _max_wait_ms(value: Optional[str]) -> int:
+    """The ``X-Max-Wait`` of a results GET in ms; absent, malformed or
+    negative reads as 0 (answer at once), never as an error."""
+    try:
+        return max(int(value), 0)
+    except (TypeError, ValueError):
+        return 0
+
+
 def _make_handler(worker: WorkerServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
@@ -1758,8 +1800,6 @@ def _make_handler(worker: WorkerServer):
             t = worker.tasks.get(task_id)
             if t is None:
                 return self._json(404, {"error": "no such task"})
-            if t.state == "FAILED":
-                return self._json(500, {"error": t.error})
             if not (0 <= part < len(t.parts)):
                 return self._json(
                     400, {"error": f"no output buffer {part}"}
@@ -1790,8 +1830,36 @@ def _make_handler(worker: WorkerServer):
             # on the LOCKED state, then the snapshot re-runs: a
             # producer publishing FINISHED between an unlocked
             # pre-check and the snapshot can never slip through.
+            # Long-poll: a puller's request for its head token carries
+            # X-Max-Wait (ms). The handler then holds it on task.cond
+            # until a page exists at ``token``, the task is terminal,
+            # this worker drains or shuts down, or the wait ran out —
+            # and takes the snapshot after the wait, under the same
+            # lock, so all of the above still decides on the locked
+            # state. Without
+            # the header (speculative requests, old peers) it answers
+            # at once. The wait is a named wait: a held handler adds
+            # to no layer's self time.
+            wait_s = min(
+                _max_wait_ms(self.headers.get(rpc.MAX_WAIT_HEADER)) / 1e3,
+                rpc.PULL_MAX_WAIT_S,
+            )
+
+            def answerable() -> bool:
+                pages = t.parts[part]
+                return (
+                    (token < len(pages) and pages[token] is not None)
+                    or t.state not in ("QUEUED", "RUNNING")
+                    or worker._draining
+                    or worker._shutting_down
+                )
+
             while True:
                 with t.cond:
+                    if wait_s > 0 and not answerable():
+                        with tracing.wait("worker.results_wait"):
+                            woken = t.cond.wait_for(answerable, wait_s)
+                        DEVICE.count_results_wait(timed_out=not woken)
                     pages = t.parts[part]
                     body = (
                         pages[token] if token < len(pages) else None
@@ -1815,6 +1883,8 @@ def _make_handler(worker: WorkerServer):
                 if not need_mat:
                     break
                 worker._materialize_ici(t)
+            if state == "FAILED":
+                return self._json(500, {"error": t.error})
             if body is not None:
                 self.send_response(200)
                 self.send_header(

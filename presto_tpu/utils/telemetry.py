@@ -115,6 +115,12 @@ class DeviceTelemetry:
         self._d2h = REGISTRY.counter("device.d2h_bytes")
         self._pad = REGISTRY.counter("device.pad_rows")
         self._live = REGISTRY.counter("device.live_rows")
+        # does the results long-poll engage (server/rpc.pull_pages)
+        self._results_waits = REGISTRY.counter("worker.results_waits")
+        self._results_wait_timeouts = REGISTRY.counter(
+            "worker.results_wait_timeouts"
+        )
+        self._pull_stalls = REGISTRY.counter("coordinator.pull_stalls")
 
     def set_enabled(self, flag: bool) -> None:
         self.enabled = bool(flag)
@@ -155,6 +161,19 @@ class DeviceTelemetry:
             self._pad.update(int(capacity - live))
             self._live.update(int(live))
 
+    def count_results_wait(self, timed_out: bool) -> None:
+        """A results GET that the worker held on the task's condition;
+        ``timed_out`` when its max-wait ran out with nothing to say."""
+        if self.enabled:
+            self._results_waits.update()
+            if timed_out:
+                self._results_wait_timeouts.update()
+
+    def count_pull_stall(self) -> None:
+        """The coordinator's look at a task whose held GET ran out."""
+        if self.enabled:
+            self._pull_stalls.update()
+
     # ------------------------------------------------------ snapshots
 
     def snapshot(self) -> Dict[str, float]:
@@ -165,7 +184,10 @@ class DeviceTelemetry:
         dispatches; ``xla_compiles`` counts what XLA really compiled
         process-wide (compile requests that were not loads from the
         persistent cache), ``xla_cache_loads`` the loads,
-        ``xla_compile_ms`` the time in the compiles. ``span_ms.*``,
+        ``xla_compile_ms`` the time in the compiles.
+        ``worker.results_waits`` / ``worker.results_wait_timeouts`` /
+        ``coordinator.pull_stalls`` say whether the results long-poll
+        engages (waits with no time-outs and no stalls). ``span_ms.*``,
         ``wait_ms.*`` and ``stmt_wall_ms`` are host time per layer
         (utils/tracing.py)."""
         with _xla_lock:
@@ -181,6 +203,11 @@ class DeviceTelemetry:
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,
+            "worker.results_waits": int(self._results_waits.total),
+            "worker.results_wait_timeouts": int(
+                self._results_wait_timeouts.total
+            ),
+            "coordinator.pull_stalls": int(self._pull_stalls.total),
         }
         out.update(tracing.span_snapshot())
         return out

@@ -249,6 +249,58 @@ def test_served_tree_keeps_its_display_names(served):
     assert all(s["duration_ms"] >= 0 for s in spans)
 
 
+def test_held_results_get_is_a_wait_not_schedule_work():
+    """A results GET held 100 ms while the task's thread works: the
+    handler's ``schedule`` span keeps its few ms of self time, and the
+    statement has no unworked time — the task worked throughout."""
+    from presto_tpu import types as T
+    from presto_tpu.server import pages_wire, rpc
+    from presto_tpu.server import worker as worker_mod
+    from presto_tpu.server.protocol import FragmentSpec
+    import numpy as np
+
+    w = worker_mod.WorkerServer().start()
+    try:
+        spec = FragmentSpec(
+            task_id="adhoc.span.0", query_id="adhoc", fragment=None,
+            partition_scan=0, split_start=0, split_end=0,
+        )
+        t = worker_mod._Task(spec, pool=w.memory_pool, node_id=w.node_id)
+        t.state = "RUNNING"
+        w.tasks[spec.task_id] = t
+        page = pages_wire.serialize_page(
+            [("x", np.arange(4, dtype=np.int64), None, T.BIGINT, None)], 4
+        )
+        url = f"{w.uri}/v1/task/{spec.task_id}/results/0/0"
+        rpc.call("GET", url)  # the handler's first call, outside the delta
+
+        def task_thread():
+            with tracing.phase("exec", site="task"):
+                time.sleep(0.1)
+                t.offer_page(page)
+
+        def body():
+            t0 = time.perf_counter_ns()
+            th = threading.Thread(target=task_thread)
+            th.start()
+            resp = rpc.call(
+                "GET", url, headers={rpc.MAX_WAIT_HEADER: "1000"},
+                wait_site="test.held_get",
+            )
+            th.join()
+            tracing.add_stmt_wall(time.perf_counter_ns() - t0)
+            assert resp.status == 200
+
+        d = _delta(body)
+    finally:
+        w.shutdown(graceful=False)
+    assert d["worker.results_waits"] == 1
+    assert d["wait_ms.worker.results_wait"] >= 90
+    assert d["span_ms.exec"] >= 100
+    assert d["span_ms.schedule"] < 5
+    assert d["span_ms.unworked"] < 10
+
+
 # -------------------------------------------------------------- trace_gaps
 
 
@@ -348,6 +400,54 @@ def _wait_sites():
                         if isinstance(kw.value, ast.Constant):
                             sites.append((kw.value.value, path, node.lineno))
     return sites
+
+
+def _blocking_calls(path):
+    """``(line, receiver.method, named)`` of every ``.wait(``,
+    ``.wait_for(`` and ``time.sleep(`` in a file; ``named`` = lexically inside a
+    ``with tracing.wait(...)``."""
+    with open(path) as fh:
+        tree = ast.parse(fh.read())
+    parent = {}
+    for node in ast.walk(tree):
+        for child in ast.iter_child_nodes(node):
+            parent[child] = node
+    out = []
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)):
+            continue
+        recv = ast.unparse(node.func.value)
+        if (node.func.attr, recv) != ("sleep", "time") and (
+            node.func.attr not in ("wait", "wait_for") or recv == "tracing"
+        ):
+            continue
+        named, p = False, node
+        while p in parent and not named:
+            p = parent[p]
+            named = isinstance(p, ast.With) and any(
+                ast.unparse(i.context_expr).startswith("tracing.wait(")
+                for i in p.items
+            )
+        out.append((node.lineno, f"{recv}.{node.func.attr}", named))
+    return out
+
+
+def test_every_condition_wait_of_the_exchange_is_a_named_wait():
+    """The exchange's two ends: every ``cond.wait`` / ``event.wait``
+    of ``server/worker.py`` and every blocking call of ``pull_pages``
+    sits inside a ``tracing.wait`` — a thread held there adds to no
+    layer's self time."""
+    server = os.path.join(ROOT, "presto_tpu", "server")
+    waits = [c for c in _blocking_calls(os.path.join(server, "worker.py"))
+             if c[1].endswith((".wait", ".wait_for"))]
+    assert len(waits) >= 3
+    assert all(named for _, _, named in waits), waits
+    assert "worker.results_wait" in {s for s, _, _ in _wait_sites()}
+    # rpc.py: the one bare sleep is call()'s backoff, inside the
+    # caller's wait_site; the pull loop's fallback sleep is named
+    rpc_calls = _blocking_calls(os.path.join(server, "rpc.py"))
+    assert [c[1] for c in rpc_calls if not c[2]] == ["time.sleep"]
+    assert sum(1 for c in rpc_calls if c[2]) == 1
 
 
 def test_every_wait_site_is_named_once():
