@@ -414,9 +414,11 @@ def _run_served(smoke: _Smoke) -> None:
                 q1_want.update(_numpy_q1(catalogs, smoke.schema))
             err = _check_q1(rows, q1_want)
             if warm and err is None:
-                cold = state.get("q1_cold") or {}
-                moved = info["device"]["h2d_bytes"] + info["device"]["d2h_bytes"]
-                was = cold.get("h2d_bytes", 0) + cold.get("d2h_bytes", 0)
+                # host->device alone: the result fetch is the same both
+                # times, and the cold run stages only the columns Q6,
+                # which ran before it, left out (residency by column)
+                moved = info["device"]["h2d_bytes"]
+                was = (state.get("q1_cold") or {}).get("h2d_bytes", 0)
                 if info["device"]["compile_ms"] != 0:
                     err = (
                         "warm q1 compiled again: compile_ms "
@@ -424,8 +426,8 @@ def _run_served(smoke: _Smoke) -> None:
                     )
                 elif was and moved * 4 > was:
                     err = (
-                        f"warm q1 moved {moved} bytes, cold moved {was}: "
-                        "the staged table was not reused"
+                        f"warm q1 staged {moved} bytes, cold staged {was}: "
+                        "the resident columns were not reused"
                     )
             elif not warm:
                 state["q1_cold"] = info["device"]

@@ -369,7 +369,7 @@ class SystemConnector(Connector):
     def _cache_rows(self):
         """Live occupancy of the engine caches (reference: the jmx
         cache-stats beans): the device-resident split cache (staged
-        pages, LRU byte budget) and the compiled-program cache."""
+        columns and pages, LRU byte budget) and the compiled-program cache."""
         if self._runner is None:
             return []
         from presto_tpu.utils.metrics import REGISTRY

@@ -37,8 +37,11 @@ from presto_tpu.exec.staging import (
     DEFAULT_CACHE_BYTES,
     CatalogManager,
     SplitCache,
+    block_nbytes,
     bucket_capacity,
+    columns_of_page,
     page_nbytes,
+    page_of_columns,
     stage_page,
 )
 from presto_tpu.ops import (
@@ -72,10 +75,6 @@ from presto_tpu.utils.telemetry import DEVICE
 
 class ExecutionError(RuntimeError):
     pass
-
-
-def _noop() -> None:
-    """No-op release handle (stage_split callers without an owner)."""
 
 
 class QueryResult:
@@ -2291,102 +2290,133 @@ class LocalQueryRunner:
         page_source=None,
     ) -> Tuple[Page, object]:
         """Stage ONE split batch [lo, hi) of a scan to device at a
-        fixed capacity, through the device-resident split cache when
-        ``stream_split_cache`` is on — repeated passes over the same
-        splits skip the connector read AND the host->device transfer
+        fixed capacity, column by column through the device-resident
+        split cache when ``stream_split_cache`` is on: each of the
+        scan's columns is looked up on its own, only the columns that
+        missed are read from the connector and staged, and the batch's
+        ``Page`` is assembled from the parts. Statements with different
+        column sets over one table share what overlaps, and a repeated
+        pass skips the connector read AND the host->device transfer
         (SURVEY.md §5.7: the table cache at split granularity).
 
         Returns ``(page, release)``: the caller invokes ``release()``
         once the batch's device execution is done. With an ``owner``,
-        a cache-served (or freshly cached) page is PINNED for that
-        window — eviction must not drop its pool accounting while the
-        page is live on device — and release unpins it; an uncached
-        page reserves its bytes under ``owner`` and release returns
-        them. Without an owner, release is a no-op.
+        every cache-served (or freshly cached) column is PINNED for
+        that window — eviction must not drop its pool accounting while
+        it is live on device — and release unpins them; columns that
+        were not admitted reserve their bytes under ``owner`` and
+        release returns them. Without an owner nothing is held per
+        batch and release does nothing.
 
         The pushed constraint is deliberately NOT part of the identity:
         split page sources read raw split ranges (constraints act at
         enumeration/filter time), so the staged batch is
         constraint-independent.
 
-        ``page_source()`` overrides the connector read on a cache miss
-        (the worker routes it through its ``_load_range`` hook)."""
+        ``page_source(columns)`` overrides the connector read of the
+        missing columns (the worker routes it through its
+        ``_load_range`` hook)."""
         from presto_tpu.connectors.spi import ConnectorSplit
-        from presto_tpu.exec.staging import stage_page
+        from presto_tpu.utils.metrics import REGISTRY
 
-        cache_on = bool(self.session.get("stream_split_cache"))
         conn = self.catalogs.get(scan.handle.catalog)
-        key = (
-            scan.handle,
-            scan.columns,
-            lo,
-            hi,
-            capacity,
-            self.session.get("tpu_offload"),
-        )
+        cache_on = bool(
+            self.session.get("stream_split_cache")
+        ) and conn.cacheable()
+        schema = dict(scan.schema)
+        offload = self.session.get("tpu_offload")
+        keys = {
+            c: (scan.handle, c, lo, hi, capacity, offload) for c in schema
+        }
         # owner callers (worker drivers) release per batch; without an
         # owner, an active query still pins — released wholesale at
         # query end (release_pins) — so pressure eviction never
-        # un-accounts a page some plan is executing over
+        # un-accounts a column some plan is executing over
         per_batch = owner is not None
         pin = per_batch or self._active_qs is not None
-        unpin = (
-            (lambda: self.split_cache.unpin(key))
-            if per_batch
-            else _noop
-        )
-        if cache_on and conn.cacheable():
-            page = self.split_cache.get(key, pin=pin)
-            if page is not None:
-                self._note_cache_hit()
-                if pin and not per_batch:
-                    self._note_pinned_key(key)
-                return page, unpin
-        t0 = time.perf_counter()
-        with tracing.phase("staging", site="stage_split"):
-            payload = (
-                page_source()
-                if page_source is not None
-                else conn.create_page_source(
-                    ConnectorSplit(scan.handle, lo, hi),
-                    list(scan.columns),
-                )
-            )
-            with self._device_scope():
-                page = stage_page(
-                    payload, dict(scan.schema), capacity=capacity
-                )
-        from presto_tpu.utils.metrics import REGISTRY
+        parts, pinned = {}, []
 
-        nbytes = _page_nbytes(page)
-        REGISTRY.distribution("staging.bytes").add(nbytes)
-        # per-query h2d attribution of the split transfer (cache hits
-        # returned above without touching the device)
-        self._fold_device_stat(device_h2d_bytes=nbytes)
-        if self._active_qs is not None:
-            # locked: concurrent task drivers / the prefetch thread
-            # share one TaskStats sink (+= would drop updates)
-            with self._qs_mu:
-                self._active_qs.staging_ms += (
-                    time.perf_counter() - t0
-                ) * 1000.0
-        if cache_on and conn.cacheable() and self.split_cache.put(
-            key, page, nbytes, pin=pin
-        ):
-            # cache-owned: put() reserved the bytes under the shared
-            # owner via try_reserve (the staged page still serves THIS
-            # batch either way; a full pool just means the split isn't
-            # cached — a cache fill never kills a query to make room)
-            if pin and not per_batch:
-                self._note_pinned_key(key)
-            return page, unpin
-        if owner is not None and self.memory_pool is not None:
-            # live (uncached) batch residency accounts to the query
-            self.memory_pool.reserve(owner, nbytes)
-            return page, (
-                lambda: self.memory_pool.release(owner, nbytes)
-            )
-        return page, _noop
+        def unpin():
+            for key in pinned:
+                self.split_cache.unpin(key)
+
+        def resident(c, part):
+            parts[c] = part
+            if per_batch:
+                pinned.append(keys[c])
+            elif pin:
+                self._note_pinned_key(keys[c])
+
+        try:
+            if cache_on:
+                for c, key in keys.items():
+                    got = self.split_cache.get(key, pin=pin)
+                    if got is not None:
+                        resident(c, got)
+                DEVICE.count_stage_columns(
+                    len(parts), len(keys) - len(parts)
+                )
+            missing = [c for c in schema if c not in parts]
+            uncached = 0
+            if missing:
+                t0 = time.perf_counter()
+                with tracing.phase("staging", site="stage_column"):
+                    payload = (
+                        page_source(missing)
+                        if page_source is not None
+                        else conn.create_page_source(
+                            ConnectorSplit(scan.handle, lo, hi), missing
+                        )
+                    )
+                    with self._device_scope():
+                        staged = columns_of_page(stage_page(
+                            payload,
+                            {c: schema[c] for c in missing},
+                            capacity=capacity,
+                        ))
+                staged_bytes = 0
+                for c, part in staged.items():
+                    nbytes = block_nbytes(part.block)
+                    staged_bytes += nbytes
+                    # a cache-owned column is reserved under the shared
+                    # owner via try_reserve (the staged column serves
+                    # THIS batch either way; a full pool just means it
+                    # isn't cached — a cache fill never kills a query)
+                    if cache_on and self.split_cache.put(
+                        keys[c], part, nbytes, pin=pin
+                    ):
+                        resident(c, part)
+                    else:
+                        parts[c] = part
+                        uncached += nbytes
+                REGISTRY.distribution("staging.bytes").add(staged_bytes)
+                # per-query h2d attribution of the split transfer
+                # (resident columns moved nothing)
+                self._fold_device_stat(device_h2d_bytes=staged_bytes)
+                if self._active_qs is not None:
+                    # locked: concurrent task drivers / the prefetch
+                    # thread share one TaskStats sink
+                    with self._qs_mu:
+                        self._active_qs.staging_ms += (
+                            time.perf_counter() - t0
+                        ) * 1000.0
+            else:
+                self._note_cache_hit()
+            if not per_batch or self.memory_pool is None:
+                uncached = 0
+            if uncached:
+                # live (uncached) residency accounts to the query
+                self.memory_pool.reserve(owner, uncached)
+        except BaseException:
+            unpin()
+            raise
+
+        def release():
+            unpin()
+            if uncached:
+                self.memory_pool.release(owner, uncached)
+
+        return page_of_columns(tuple(schema), parts), release
 
     def _load_merged_payload(self, scan: N.TableScanNode) -> Dict:
         """Fetch all splits of a scan and merge their column payloads.

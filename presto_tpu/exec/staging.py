@@ -21,7 +21,7 @@ import collections
 import dataclasses
 import queue
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -35,12 +35,12 @@ from presto_tpu.utils.telemetry import DEVICE
 MIN_BUCKET = 1 << 10
 
 #: default device-resident split-cache budget (tier-1 key
-#: ``staging.cache-bytes`` overrides). 4GB: big enough that the SF10
-#: bench working sets (~2.4GB of pruned columns) stay resident across
-#: iterations instead of re-staging every pass (staging cost not
-#: measured on the chip), while staying well under v5e HBM (16GB) and
-#: the 8GB default
-#: memory pool, so cache fills never crowd out running queries
+#: ``staging.cache-bytes`` overrides). 4GB: big enough that the
+#: columns TPC-H Q1 and Q6 scan of SF10's ``lineitem`` (their union,
+#: held once by column) stay resident across statements instead of
+#: re-staging every pass, while staying well under v5e HBM (16GB) and
+#: the 8GB default memory pool, so cache fills never crowd out running
+#: queries (sizes measured on the chip: PERF.md §4)
 DEFAULT_CACHE_BYTES = 4 << 30
 
 
@@ -227,57 +227,92 @@ def merge_column_chunks(parts: List[object], dtype=None):
     return merged["c"]
 
 
-def page_to_host(page: Page):
-    """Pull a staged page's device buffers back to host RAM (the spill
-    write of the host-spill lane). Pages are pytrees, so the transfer
-    is one generic device_get over data/validity/offsets/children —
-    static aux (dtype, dictionary, names) rides along untouched."""
+def page_to_host(staged, nbytes: int):
+    """Pull a cache entry's device buffers back to host RAM (the spill
+    write of the host-spill lane). Entries are pytrees (a whole-table
+    ``Page`` or one :class:`StagedColumn`), so the transfer is one
+    generic device_get over data/validity/offsets/children — static
+    aux (dtype, dictionary, names) rides along untouched."""
     import jax
 
-    if DEVICE.enabled:
-        DEVICE.count_d2h(page_nbytes(page))
-    return jax.device_get(page)
+    DEVICE.count_d2h(nbytes)
+    return jax.device_get(staged)
 
 
-def host_to_page(host) -> Page:
+def host_to_page(host, nbytes: int):
     """Restage a spilled host pytree back onto the device (the staged
     twin of :func:`page_to_host`; lives HERE so every host->device
     transfer stays in this module — tools/check_device_puts.py)."""
     import jax
 
-    page = jax.tree_util.tree_map(jnp.asarray, host)
-    if DEVICE.enabled:
-        DEVICE.count_h2d(page_nbytes(page))
-    return page
+    staged = jax.tree_util.tree_map(jnp.asarray, host)
+    DEVICE.count_h2d(nbytes)
+    return staged
+
+
+def block_nbytes(b: Block) -> int:
+    """Device bytes one staged column holds (data/validity/offsets
+    buffers, recursing into array/map/row children)."""
+    n = int(b.data.nbytes)
+    if b.valid is not None:
+        n += int(b.valid.nbytes)
+    if b.offsets is not None:
+        n += int(b.offsets.nbytes)
+    for child in b.children or ():
+        n += block_nbytes(child)
+    return n
 
 
 def page_nbytes(page: Page) -> int:
-    """Device bytes a staged page holds (data/validity/offsets buffers,
-    recursing into array/map/row children) — the accounting unit for
-    the split cache and the memory pool."""
-
-    def block_nbytes(b) -> int:
-        n = int(b.data.nbytes)
-        if b.valid is not None:
-            n += int(b.valid.nbytes)
-        if b.offsets is not None:
-            n += int(b.offsets.nbytes)
-        for child in b.children or ():
-            n += block_nbytes(child)
-        return n
-
+    """Device bytes a staged page holds — the accounting unit for the
+    split cache and the memory pool."""
     return sum(block_nbytes(b) for b in page.blocks)
 
 
+class StagedColumn(NamedTuple):
+    """One column of one split range on the device: the split cache's
+    entry for streamed scans. Statements that scan different column
+    sets of a table share what they have in common, so what stays
+    resident is the union of the columns, not a page per statement
+    shape. ``num_valid`` is the row count of the range as it was
+    staged (a device scalar, so a page built from resident columns
+    moves nothing host->device)."""
+
+    block: Block
+    num_valid: jnp.ndarray
+
+
+def columns_of_page(page: Page) -> Dict[str, StagedColumn]:
+    """A freshly staged page of one split range as cache entries."""
+    return {
+        name: StagedColumn(block, page.num_valid)
+        for name, block in zip(page.names, page.blocks)
+    }
+
+
+def page_of_columns(names, parts: Dict[str, StagedColumn]) -> Page:
+    """The ``Page`` a whole staging of ``names`` would have built,
+    assembled from per-column entries of the same split range."""
+    return Page(
+        blocks=tuple(parts[n].block for n in names),
+        num_valid=parts[names[0]].num_valid,
+        names=tuple(names),
+    )
+
+
 class SplitCache:
-    """Device-resident staged-``Page`` cache with an LRU byte budget.
+    """Device-resident cache of staged columns with an LRU byte budget.
 
     Reference parity: the split-level half of the reference's
     fragment-result / raw-data caching tier (Alluxio-style local cache
     on the native worker, SURVEY.md §7 host->device staging as the
-    TPU-native analogue of disk I/O). Entries are whole staged pytrees
-    keyed by ``(table handle, columns, lo, hi, capacity bucket, ...)``;
-    a hit skips BOTH the connector read and the host->device transfer.
+    TPU-native analogue of disk I/O). An entry of a streamed scan is
+    ONE COLUMN of one split range (a :class:`StagedColumn` keyed by
+    ``(table handle, column, lo, hi, capacity bucket, tpu_offload)``,
+    ``LocalQueryRunner.stage_split``), so scans with different column
+    sets share what overlaps; a table under ``max_device_rows`` is one
+    whole staged ``Page`` (``_load_table``). A hit skips BOTH the
+    connector read and the host->device transfer.
 
     Budget discipline: entries charge the byte budget (LRU eviction at
     the boundary) AND reserve against the node :class:`MemoryPool`
@@ -346,6 +381,7 @@ class SplitCache:
             # before the kill-largest policy fires — droppable cache
             # must never cost a live query its reservation
             pool.add_pressure_hook(self.evict_bytes)
+        DEVICE.track_cache(self)
 
     def set_spill_budget(self, nbytes: int) -> None:
         """(Re)size the host-spill budget (the worker wires the tier-1
@@ -414,7 +450,7 @@ class SplitCache:
         from presto_tpu.utils.metrics import REGISTRY
 
         host, nbytes = got
-        page = host_to_page(host)  # DMA, no lock held
+        page = host_to_page(host, nbytes)  # DMA, no lock held
         if not self.put(key, page, nbytes, pin=pin, expect_epoch=epoch):
             with self._lock:
                 if self._epoch != epoch:
@@ -615,7 +651,7 @@ class SplitCache:
         for key, page, nbytes in dropped:
             if self.spill_budget <= 0 or nbytes > self.spill_budget:
                 continue
-            host = page_to_host(page)  # DMA, no lock held
+            host = page_to_host(page, nbytes)  # DMA, no lock held
             with self._lock:
                 if self._epoch != epoch:
                     return  # invalidated mid-copy: drop, don't re-admit

@@ -47,6 +47,7 @@ from typing import Dict, List, Optional
 
 from presto_tpu.connectors.spi import ConnectorSplit
 from presto_tpu.exec.staging import (
+    block_nbytes,
     bucket_capacity,
     page_nbytes,
     stage_page,
@@ -1042,17 +1043,18 @@ class WorkerServer:
             """Stage the partitioned scan's [lo, hi) batch through the
             device-resident split cache (LocalQueryRunner.stage_split:
             one fixed capacity bucket per batch size, so every full
-            batch reuses one compiled program; uncached batches
-            reserve their live residency under the query, cached ones
-            are pinned against eviction until released)."""
+            batch reuses one compiled program; resident columns are
+            pinned against eviction until released, the missing ones
+            are read here, and those the cache does not admit reserve
+            their live residency under the query)."""
             # staging may run on a prefetch/pool thread: point it at
             # the task's stats sink (thread-local on the runner)
             self.runner._qs_local.value = task.stats
             fetched = []
 
-            def read_range():
-                fetched.append(True)
-                return self._load_range(part_scan, lo, hi)
+            def read_range(columns):
+                fetched.extend(columns)
+                return self._load_range(part_scan, lo, hi, columns)
 
             page, release = self.runner.stage_split(
                 part_scan, lo, hi, bucket_capacity(hi - lo),
@@ -1069,10 +1071,10 @@ class WorkerServer:
                 task.stats.input_rows += hi - lo
                 task.stats.input_bytes += staged_bytes
             if fetched:
-                # only REAL staging traffic counts — a cache hit moved
-                # zero bytes host->device
+                # only REAL staging traffic counts — a resident column
+                # moved zero bytes host->device
                 REGISTRY.distribution("worker.staging_bytes").add(
-                    staged_bytes
+                    sum(block_nbytes(page.block(c)) for c in fetched)
                 )
             return page, release
 
@@ -1369,10 +1371,14 @@ class WorkerServer:
         )
         return pages
 
-    def _load_range(self, scan: N.TableScanNode, lo: int, hi: int):
+    def _load_range(
+        self, scan: N.TableScanNode, lo: int, hi: int, columns: List[str]
+    ):
+        """Read ``columns`` of the scan's rows [lo, hi): the columns
+        the staging cache does not hold (all of them on a cold pass)."""
         conn = self.runner.catalogs.get(scan.handle.catalog)
         split = ConnectorSplit(scan.handle, lo, hi)
-        return conn.create_page_source(split, list(scan.columns))
+        return conn.create_page_source(split, list(columns))
 
     def _materialize_ici(self, task: "_Task") -> None:
         """Degrade one task's ICI edges to HTTP, exactly once: the

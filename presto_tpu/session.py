@@ -174,13 +174,16 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
         ),
         PropertyMetadata(
             "stream_split_cache",
-            "Keep staged split-batch pages device-resident across "
-            "queries (cacheable connectors only), so repeated streamed "
-            "passes over the same splits skip the host->device "
-            "re-staging transfer (the table cache at split "
-            "granularity — SURVEY.md §5.7). Off by default: caching "
-            "every split defeats larger-than-HBM discipline when the "
-            "working set genuinely exceeds device memory",
+            "Keep the staged columns of split batches device-resident "
+            "across queries (cacheable connectors only), one cache "
+            "entry a column and split range, so repeated streamed "
+            "passes over the same splits — by statements of any "
+            "column set — skip the connector read and the host->device "
+            "transfer of every column already held (the table cache at "
+            "split granularity — SURVEY.md §5.7). Off by default in a "
+            "bare session; a WorkerServer turns it ON at boot whenever "
+            "staging.cache-bytes > 0 (default 4 GiB, LRU), so the "
+            "served path always runs with it",
             bool,
             False,
         ),

@@ -44,6 +44,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from presto_tpu.utils import tracing
@@ -121,6 +122,12 @@ class DeviceTelemetry:
             "worker.results_wait_timeouts"
         )
         self._pull_stalls = REGISTRY.counter("coordinator.pull_stalls")
+        # residency by column of the staging cache (exec/staging.py)
+        self._stage_col_hits = REGISTRY.counter("staging.col_hit")
+        self._stage_col_misses = REGISTRY.counter("staging.col_miss")
+        self._stage_evictions = REGISTRY.counter("staging.cache_evict")
+        self._caches: "weakref.WeakSet" = weakref.WeakSet()
+        self._caches_lock = threading.Lock()
 
     def set_enabled(self, flag: bool) -> None:
         self.enabled = bool(flag)
@@ -174,6 +181,19 @@ class DeviceTelemetry:
         if self.enabled:
             self._pull_stalls.update()
 
+    def count_stage_columns(self, hits: int, misses: int) -> None:
+        """One split batch looked up in the staging cache: the columns
+        it found resident and those it had to read and stage."""
+        if self.enabled:
+            self._stage_col_hits.update(hits)
+            self._stage_col_misses.update(misses)
+
+    def track_cache(self, cache) -> None:
+        """A live ``SplitCache`` whose ``used_bytes()`` the snapshot
+        sums as ``stage_resident_bytes`` (held weakly)."""
+        with self._caches_lock:
+            self._caches.add(cache)
+
     # ------------------------------------------------------ snapshots
 
     def snapshot(self) -> Dict[str, float]:
@@ -187,11 +207,19 @@ class DeviceTelemetry:
         ``xla_compile_ms`` the time in the compiles.
         ``worker.results_waits`` / ``worker.results_wait_timeouts`` /
         ``coordinator.pull_stalls`` say whether the results long-poll
-        engages (waits with no time-outs and no stalls). ``span_ms.*``,
+        engages (waits with no time-outs and no stalls).
+        ``stage_col_hits`` / ``stage_col_misses`` count the columns a
+        streamed split batch found resident in the staging cache or had
+        to stage, ``stage_evictions`` the entries it dropped for room
+        (the registry's ``staging.cache_evict``),
+        ``stage_resident_bytes`` is what the process's staging caches
+        hold now (a level, not a total). ``span_ms.*``,
         ``wait_ms.*`` and ``stmt_wall_ms`` are host time per layer
         (utils/tracing.py)."""
         with _xla_lock:
             xla = dict(_xla)
+        with self._caches_lock:
+            caches = list(self._caches) if self.enabled else ()
         out = {
             "dispatches": int(self._dispatches.total),
             "compiles": int(self._compiles.total),
@@ -208,6 +236,10 @@ class DeviceTelemetry:
                 self._results_wait_timeouts.total
             ),
             "coordinator.pull_stalls": int(self._pull_stalls.total),
+            "stage_col_hits": int(self._stage_col_hits.total),
+            "stage_col_misses": int(self._stage_col_misses.total),
+            "stage_evictions": int(self._stage_evictions.total),
+            "stage_resident_bytes": sum(c.used_bytes() for c in caches),
         }
         out.update(tracing.span_snapshot())
         return out

@@ -112,10 +112,10 @@ class _SlowWorker(_CountingWorker):
         super().__init__(*a, **kw)
         self.spans = []
 
-    def _load_range(self, scan, lo, hi):
+    def _load_range(self, scan, lo, hi, columns):
         t0 = time.time()
         time.sleep(self.DELAY_S)
-        out = super()._load_range(scan, lo, hi)
+        out = super()._load_range(scan, lo, hi, columns)
         self.spans.append((t0, time.time()))
         return out
 

@@ -159,7 +159,9 @@ def test_disabled_plane_freezes_spans_and_compiles():
 def test_snapshot_always_has_the_keys_the_benchmark_reads():
     snap = device_snapshot()
     for k in WORK_KEYS + ["span_ms.unworked", "stmt_wall_ms", "xla_compiles",
-                          "xla_cache_loads", "xla_compile_ms"]:
+                          "xla_cache_loads", "xla_compile_ms",
+                          "stage_col_hits", "stage_col_misses",
+                          "stage_evictions", "stage_resident_bytes"]:
         assert isinstance(snap[k], (int, float)), k
 
 

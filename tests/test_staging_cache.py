@@ -9,6 +9,7 @@ pipelined exchange pulls (``rpc.pull-depth``), and adaptive
 exchange compression.
 """
 
+import gc
 import os
 import time
 
@@ -26,6 +27,7 @@ from presto_tpu.exec.staging import (
 )
 from presto_tpu.session import NodeConfig, Session
 from presto_tpu.utils.memory import MemoryPool
+from presto_tpu.utils.telemetry import device_snapshot
 
 
 def _page(n=1024, fill=1):
@@ -229,6 +231,289 @@ def test_memory_connector_write_invalidates_cache():
     assert r.execute(q).rows() == [(1,), (3,)]
 
 
+# ------------------------------------------- residency by column
+
+
+Q6_COLS = ("l_extendedprice", "l_discount", "l_quantity", "l_shipdate")
+Q1_COLS = Q6_COLS + ("l_returnflag", "l_linestatus", "l_tax")
+CAP = 4096
+
+
+def _lineitem_scan(runner, columns):
+    from presto_tpu.plan import nodes as N
+
+    handle = _h("lineitem")
+    types = runner.catalogs.get("tpch").metadata().get_table_schema(handle)
+    return N.TableScanNode(
+        handle, tuple(columns), tuple((c, types[c]) for c in columns)
+    )
+
+
+class _ColumnSpy:
+    """Records the column lists asked of the connector's page source."""
+
+    def __init__(self, runner):
+        self.conn = runner.catalogs.get("tpch")
+        self.orig = self.conn.create_page_source
+        self.reads = []
+
+    def __enter__(self):
+        def spy(split, columns):
+            self.reads.append(tuple(columns))
+            return self.orig(split, columns)
+
+        self.conn.create_page_source = spy
+        return self
+
+    def __exit__(self, *exc):
+        self.conn.create_page_source = self.orig
+
+
+def _column_runner(budget=1 << 30, pool=None):
+    return LocalQueryRunner(
+        memory_pool=pool,
+        staging_cache_bytes=budget,
+        session=Session(properties={"stream_split_cache": True}),
+    )
+
+
+def _col_bytes(page, names):
+    from presto_tpu.exec.staging import block_nbytes
+
+    return sum(block_nbytes(page.block(c)) for c in names)
+
+
+@pytest.mark.parametrize(
+    "first,second",
+    [(Q6_COLS, Q1_COLS), (Q1_COLS, Q6_COLS), (Q1_COLS, Q1_COLS)],
+    ids=["q6-then-q1", "q1-then-q6", "q1-twice"],
+)
+def test_overlapping_scans_stage_each_shared_column_once(first, second):
+    """Two scans of one split range whose column sets overlap: the
+    second reads and stages only what the first did not, the cache
+    holds the union once, and the assembled page equals a fresh
+    staging of the same columns."""
+    r = _column_runner()
+    gc.collect()  # caches of earlier tests die now, not mid-test
+    snap0 = device_snapshot()
+    with _ColumnSpy(r) as spy:
+        a, _ = r.stage_split(_lineitem_scan(r, first), 0, CAP, CAP)
+        b, _ = r.stage_split(_lineitem_scan(r, second), 0, CAP, CAP)
+    new = tuple(c for c in second if c not in first)
+    assert spy.reads == [tuple(first)] + ([new] if new else [])
+    union = dict.fromkeys(first + second)
+    st = r.split_cache.stats()
+    assert st["entries"] == len(union)
+    assert st["misses"] == len(union)
+    assert st["hits"] == len(second) - len(new)
+    assert st["bytes"] == _col_bytes(a, first) + _col_bytes(b, new)
+    d = {k: v - snap0[k] for k, v in device_snapshot().items()}
+    assert d["stage_col_misses"] == len(union)
+    assert d["stage_col_hits"] == len(second) - len(new)
+    assert d["h2d_bytes"] == st["bytes"]
+    assert d["stage_resident_bytes"] == st["bytes"]
+    assert d["stage_evictions"] == 0
+    # the shared columns are the SAME device arrays, not copies
+    for c in set(first) & set(second):
+        assert b.block(c).data is a.block(c).data
+    fresh = LocalQueryRunner().stage_split(
+        _lineitem_scan(r, second), 0, CAP, CAP
+    )[0]
+    assert b.names == fresh.names == tuple(second)
+    assert b.to_pylist() == fresh.to_pylist()
+
+
+def test_eviction_and_pins_are_per_column():
+    """The LRU budget drops single columns, oldest first, and a pinned
+    column stays while its neighbours of the same split range go."""
+    probe = _column_runner()
+    page, _ = probe.stage_split(_lineitem_scan(probe, Q6_COLS), 0, CAP, CAP)
+    one = _col_bytes(page, ("l_quantity",))  # three int64 columns
+    budget = _col_bytes(page, Q6_COLS)
+    pool = MemoryPool(1 << 30)
+    r = _column_runner(budget=budget, pool=pool)
+    scan = _lineitem_scan(r, Q6_COLS)
+    _, release = r.stage_split(scan, 0, CAP, CAP, owner="q")
+    assert r.split_cache.stats()["entries"] == 4
+    assert pool.used_bytes(SplitCache.OWNER) == budget
+    release()
+    # a second range of one column: evicts exactly the oldest column
+    # of the first range, not the whole page
+    _, release = r.stage_split(
+        _lineitem_scan(r, ("l_quantity",)), CAP, 2 * CAP, CAP, owner="q"
+    )
+    release()
+    st = r.split_cache.stats()
+    assert (st["evictions"], st["entries"]) == (1, 4)
+    assert st["bytes"] <= budget
+    assert pool.used_bytes(SplitCache.OWNER) == st["bytes"]
+    with _ColumnSpy(r) as spy:
+        _, release = r.stage_split(scan, 0, CAP, CAP, owner="q")
+    assert spy.reads == [("l_extendedprice",)]  # only the evicted one
+    # all four columns of range 0 are pinned now: a further column
+    # cannot be admitted over them and accounts to the query instead
+    before = r.split_cache.stats()
+    _, release2 = r.stage_split(
+        _lineitem_scan(r, ("l_tax",)), 2 * CAP, 3 * CAP, CAP, owner="q"
+    )
+    pinned = {k[1] for k in r.split_cache._entries if k[2] == 0}
+    assert pinned == set(Q6_COLS)
+    assert pool.used_bytes("q") == one
+    release2()
+    assert pool.used_bytes("q") == 0
+    release()
+    assert not r.split_cache._pins
+    assert r.split_cache.stats()["evictions"] >= before["evictions"]
+
+
+def test_invalidate_drops_every_column_of_the_table():
+    r = _column_runner()
+    r.stage_split(_lineitem_scan(r, Q1_COLS), 0, CAP, CAP)
+    r.stage_split(_lineitem_scan(r, Q6_COLS), CAP, 2 * CAP, CAP)
+    r.execute("select count(*) from tpch.tiny.region")  # another table
+    before = r.split_cache.stats()["entries"]
+    assert r.split_cache.invalidate(_h("lineitem")) == 11
+    st = r.split_cache.stats()
+    assert st["entries"] == before - 11
+    assert not any(k[0] == _h("lineitem") for k in r.split_cache._entries)
+    with _ColumnSpy(r) as spy:
+        r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+    assert spy.reads == [Q6_COLS]
+
+
+def test_runtime_caches_row_counts_columns():
+    """``system.runtime.caches`` follows the entries: one a column and
+    split range, with the cache's own hit and miss counts."""
+    r = _column_runner()
+    r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+    r.stage_split(_lineitem_scan(r, Q1_COLS), 0, CAP, CAP)
+    st = r.split_cache.stats()
+    row = r.execute(
+        "select entries, bytes, hits, misses, evictions "
+        "from system.runtime.caches where cache = 'staging.split_cache'"
+    ).rows()
+    assert row == [(7, st["bytes"], 4, 7, 0)]
+
+
+def test_partial_miss_is_a_staging_span_and_a_hit_is_none(monkeypatch):
+    """The read + stage of the missing columns runs under
+    ``phase("staging", site="stage_column")``, so it lands in
+    ``span_ms.staging``; a batch served from resident columns opens no
+    span at all."""
+    from presto_tpu.utils import tracing
+
+    sites = []
+    phase = tracing.phase
+
+    def spy(name, site=""):
+        sites.append((name, site))
+        return phase(name, site)
+
+    monkeypatch.setattr(tracing, "phase", spy)
+    r = _column_runner()
+    r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+    del sites[:]
+    s0 = device_snapshot()["span_ms.staging"]
+    r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+    assert sites == [] and device_snapshot()["span_ms.staging"] == s0
+    r.stage_split(_lineitem_scan(r, Q1_COLS), 0, CAP, CAP)
+    assert sites[0] == ("staging", "stage_column")
+    assert device_snapshot()["span_ms.staging"] > s0
+
+
+def test_concurrent_overlapping_scans_keep_the_accounts_straight():
+    """Eight drivers stage overlapping column sets of four split ranges
+    through a cache half the size of what they touch: whatever the
+    interleaving of hits, fills, evictions and duplicate stagings,
+    every page holds its range's rows, no pin and no query reservation
+    survives, and the pool's cache owner equals the cache's bytes."""
+    import sys
+    import threading
+
+    probe = _column_runner()
+    sums = {}
+    for i in range(4):
+        page = probe.stage_split(
+            _lineitem_scan(probe, Q1_COLS), i * CAP, (i + 1) * CAP, CAP
+        )[0]
+        sums[i] = {c: int(page.block(c).data.sum()) for c in Q1_COLS}
+    budget = probe.split_cache.used_bytes() // 2
+    pool = MemoryPool(1 << 30)
+    r = _column_runner(budget=budget, pool=pool)
+    errors = []
+
+    def driver(t):
+        try:
+            for k in range(12):
+                cols = Q6_COLS if (t + k) % 2 else Q1_COLS
+                i = (t + k) % 4
+                page, release = r.stage_split(
+                    _lineitem_scan(r, cols), i * CAP, (i + 1) * CAP, CAP,
+                    owner=f"q{t}",
+                )
+                try:
+                    got = {c: int(page.block(c).data.sum()) for c in cols}
+                    assert got == {c: sums[i][c] for c in cols}, (t, k)
+                    assert r.split_cache.used_bytes() <= budget
+                finally:
+                    release()
+        except BaseException as e:
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=driver, args=(t,)) for t in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads) and not errors, errors
+    assert not r.split_cache._pins
+    assert pool.used_bytes() == pool.used_bytes(SplitCache.OWNER)
+    assert pool.used_bytes(SplitCache.OWNER) == r.split_cache.used_bytes()
+    assert r.split_cache.stats()["evictions"] > 0
+
+
+def test_failed_read_leaves_no_pin_behind():
+    """A page source that raises on a partial miss must not leave the
+    columns that did hit pinned for ever."""
+    r = _column_runner()
+    r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+
+    def boom(columns):
+        raise OSError("disk on fire")
+
+    with pytest.raises(OSError):
+        r.stage_split(
+            _lineitem_scan(r, Q1_COLS), 0, CAP, CAP, owner="q",
+            page_source=boom,
+        )
+    assert not r.split_cache._pins
+
+
+def test_spilled_columns_restage_one_by_one():
+    """The host-spill lane moves single columns: after pool pressure
+    every column is in host RAM, and a narrower scan restages only its
+    own."""
+    r = _column_runner(pool=MemoryPool(1 << 30))
+    r.split_cache.set_spill_budget(64 << 20)
+    scan = _lineitem_scan(r, Q1_COLS)
+    want = r.stage_split(scan, 0, CAP, CAP)[0].to_pylist()
+    assert r.split_cache.evict_bytes(1 << 30) > 0
+    st = r.split_cache.stats()
+    assert (st["bytes"], st["spill_entries"]) == (0, 7)
+    with _ColumnSpy(r) as spy:
+        r.stage_split(_lineitem_scan(r, Q6_COLS), 0, CAP, CAP)
+        st = r.split_cache.stats()
+        assert (st["restages"], st["spill_entries"]) == (4, 3)
+        got = r.stage_split(scan, 0, CAP, CAP)[0].to_pylist()
+    assert spy.reads == []
+    assert got == want
+
+
 # -------------------------------------------------- prefetch pipeline
 
 
@@ -330,6 +615,78 @@ def test_worker_warm_task_reports_cache_hits():
         )
         assert hits > 0, "warm task must serve splits from the cache"
         assert info.get("staging_cache_hits", 0) > 0  # query rollup
+    finally:
+        w.shutdown(graceful=False)
+        coord.shutdown()
+
+
+def test_served_mix_stages_only_columns_not_yet_resident():
+    """Q6, Q1, Q6 through client -> coordinator -> worker with lineitem
+    streamed in batches: every result equals the benchmark's numpy
+    reference, Q1 adds host->device bytes only for the three columns
+    Q6 did not scan, and the third statement adds none."""
+    import zlib
+
+    from benchmark import discovery
+    from benchmark.data import HostData
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.server import CoordinatorServer, WorkerServer
+    from presto_tpu.server.client import PrestoTpuClient
+
+    here = os.path.dirname(os.path.abspath(discovery.__file__))
+    data = HostData(TpchConnector(), "tpch", "tiny")
+    coord = CoordinatorServer(
+        session=Session(
+            properties={"max_device_rows": 16_384, "page_capacity": 4_096}
+        )
+    ).start()
+    w = WorkerServer(coordinator_uri=coord.uri).start()
+    reads = []
+    load_range = w._load_range
+
+    def spy(scan, lo, hi, columns):
+        reads.append((scan.handle.table, lo, tuple(columns)))
+        return load_range(scan, lo, hi, columns)
+
+    w._load_range = spy
+    try:
+        _wait_workers(coord, 1)
+        client = PrestoTpuClient(coord.uri, timeout_s=120)
+        cache = w.runner.split_cache
+        seen = []
+        for name in ("q6", "q1", "q6"):
+            mod = discovery.load_module(
+                os.path.join(here, "statements", name + ".py")
+            )
+            rng = np.random.default_rng([5, zlib.crc32(name.encode())])
+            p = mod.params(rng, data)
+            before = cache.stats()
+            del reads[:]
+            rows = client.execute(mod.sql("tpch.tiny", p, "t")).rows()
+            assert mod.compare(
+                [tuple(r) for r in rows], mod.reference(data, p)
+            ) is None, (name, p)
+            after = cache.stats()
+            seen.append({
+                "batches": len({lo for _t, lo, _c in reads}),
+                "columns": {c for _t, _lo, cols in reads for c in cols},
+                "bytes": after["bytes"] - before["bytes"],
+                "misses": after["misses"] - before["misses"],
+                "hits": after["hits"] - before["hits"],
+            })
+        q6, q1, again = seen
+        assert q6["batches"] >= 10  # lineitem really streams
+        assert q6["columns"] == set(Q6_COLS)
+        assert q6["misses"] == 4 * q6["batches"] and q6["hits"] == 0
+        assert q1["columns"] == set(Q1_COLS) - set(Q6_COLS)
+        assert q1["misses"] == 3 * q6["batches"]
+        assert q1["hits"] == 4 * q6["batches"]
+        # two int32 dictionary columns and one int64 against three
+        # int64 and one int32: Q1 adds 16 bytes a row to Q6's 28
+        assert q1["bytes"] * 28 == q6["bytes"] * 16
+        assert again["columns"] == set() and again["bytes"] == 0
+        assert (again["misses"], again["hits"]) == (0, 4 * q6["batches"])
+        assert cache.stats()["evictions"] == 0 and not cache._pins
     finally:
         w.shutdown(graceful=False)
         coord.shutdown()
