@@ -1734,6 +1734,7 @@ class LocalQueryRunner:
                 int(getattr(leaf, "nbytes", 0)) for leaf in fetched
             )
             DEVICE.count_dispatch()
+            DEVICE.count_program_out(_static_page_nbytes(page))
             DEVICE.count_d2h(batch_d2h)
             if fresh:
                 DEVICE.count_compile((t_disped - t_disp) * 1000.0)
@@ -1977,6 +1978,7 @@ class LocalQueryRunner:
                     (t_disped - t_disp) * 1000.0 if fresh else 0.0
                 )
                 DEVICE.count_dispatch()
+                DEVICE.count_program_out(_static_page_nbytes(page))
                 DEVICE.count_d2h(d2h)
                 if fresh:
                     DEVICE.count_compile(compile_ms)

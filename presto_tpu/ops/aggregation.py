@@ -19,10 +19,17 @@ but cost minutes of compile; one-hot reduction and cumsum are ~10ms):
   keys together; every accumulator is then a *scan*, not a scatter:
   sums/counts are inclusive-cumsum differences at group boundaries,
   min/max are segmented associative scans read at group ends.
-- Shapes stay static: the planner supplies ``max_groups`` (the output
-  capacity bucket); kernels report overflow instead of reallocating, and
-  the host re-runs at a bigger bucket on overflow (SURVEY.md §7 "Hard
-  parts: dynamic shapes").
+- Shapes stay static, and each path sizes its output from what it
+  knows. The planner supplies ``max_groups``, a bucket of its ROW
+  estimate (half the source's rows: 2^24 for Q1 at SF10, which has four
+  groups). The sorted path has nothing better — unbounded keys carry no
+  proof — so its output is ``max_groups`` long; kernels report overflow
+  instead of reallocating, and the host re-runs at a bigger bucket on
+  overflow (SURVEY.md §7 "Hard parts: dynamic shapes"). The one-hot
+  path holds a proof of its key domain (``nseg`` <= 256 segments), so it
+  builds its output at that domain's bucket,
+  ``min(max_groups, bucket_capacity(nseg))``, whatever the planner
+  estimated. The global path emits one row.
 
 Aggregate functions: count(*), count(x), sum, min, max, avg. Null
 semantics match SQL: aggregates skip nulls; count(*) counts rows;
@@ -236,8 +243,24 @@ def _onehot_aggregate(
     ascending segment order is lexicographic in the keys (dict ids are
     order-preserving); a key's NULL slot is its largest id (nulls group
     last, matching the sorted path's NULLS LAST grouping order).
+
+    The output page is ``min(max_groups, bucket_capacity(nseg))`` long:
+    there are at most ``nseg`` groups by construction (dead rows match no
+    column), so nothing here is allocated, scanned, scattered or gathered
+    at ``max_groups``. Sized by the planner's bucket instead, Q1's
+    partial stage at SF10 built 2^24-slot pages for four groups: 17.6 ms
+    of ``reduce-window`` (the compaction's cumsum over the slots) and
+    13 ms of copies a 2^20-row batch, 1.73 GB of output of which 105 KB
+    was fetched, 2.1 s of device time a statement where 41 ms do
+    (PERF.md §6, PR 30). The capacity stays a ``bucket_capacity``
+    bucket, the one ``materialize_page`` re-pads a fetched prefix to.
+    ``overflow`` can only be true where a caller passes ``max_groups``
+    under ``nseg``; the first ``max_groups`` groups are kept then.
     """
+    from presto_tpu.exec.staging import bucket_capacity
+
     cap = page.capacity
+    out_cap = min(max_groups, bucket_capacity(nseg))
 
     strides = []
     s = 1
@@ -262,7 +285,7 @@ def _onehot_aggregate(
     overflow = num_groups > max_groups
 
     # occupied segments compacted to the front, ascending (lexicographic)
-    sel = nonzero_1d(occupied, max_groups, nseg)
+    sel = nonzero_1d(occupied, out_cap, nseg)
     safe_sel = jnp.minimum(sel, nseg - 1).astype(jnp.int32)
 
     names: List[str] = []

@@ -116,6 +116,7 @@ class DeviceTelemetry:
         self._d2h = REGISTRY.counter("device.d2h_bytes")
         self._pad = REGISTRY.counter("device.pad_rows")
         self._live = REGISTRY.counter("device.live_rows")
+        self._program_out = REGISTRY.counter("device.program_out_bytes")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -139,6 +140,14 @@ class DeviceTelemetry:
         """One compiled-program execution launched on the device."""
         if self.enabled:
             self._dispatches.update(n)
+
+    def count_program_out(self, nbytes: int) -> None:
+        """Static byte size of the page a dispatched program returns:
+        what it allocates and writes on the device whether or not
+        anyone fetches it (shapes only; nothing read from the device).
+        """
+        if self.enabled:
+            self._program_out.update(int(nbytes))
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -208,6 +217,9 @@ class DeviceTelemetry:
         ``worker.results_waits`` / ``worker.results_wait_timeouts`` /
         ``coordinator.pull_stalls`` say whether the results long-poll
         engages (waits with no time-outs and no stalls).
+        ``program_out_bytes`` sums the static size of every dispatched
+        fragment program's output page (beside ``d2h_bytes``, what of
+        it was fetched).
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -228,6 +240,7 @@ class DeviceTelemetry:
             "d2h_bytes": int(self._d2h.total),
             "pad_rows": int(self._pad.total),
             "live_rows": int(self._live.total),
+            "program_out_bytes": int(self._program_out.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

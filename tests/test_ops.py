@@ -2,11 +2,15 @@
 window (SURVEY.md §7 step 3), in the reference's hand-built-page style
 (SURVEY.md §4.1)."""
 
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from presto_tpu import types as T
-from presto_tpu.expr import ColumnRef, Literal, arith
+from presto_tpu.expr import ColumnRef, ExprLowerer, Literal, arith
 from presto_tpu.ops import (
     AggCall,
     SortKey,
@@ -18,6 +22,7 @@ from presto_tpu.ops import (
     order_by,
     window,
 )
+from presto_tpu.ops.aggregation import _sorted_aggregate
 from presto_tpu.page import Page
 
 
@@ -418,3 +423,130 @@ def test_sorted_sum_overflow_trap():
     assert not any(bool(flag) for _, flag in errors3)
     rows = {r["k"]: r["s"] for r in out3.to_pylist()}
     assert rows == {1: 40, 2: 60}
+
+
+# ---------------------------------------- one-hot path: output capacity
+
+
+def _onehot_case(shape):
+    """A Q1-shaped page: two dictionary keys (3 x 2 values), a decimal
+    and a nullable bigint. ``plain`` has five of the six groups,
+    ``nullable`` adds NULLs in the second key (seven groups), ``empty``
+    filters every row out."""
+    k1 = ["A", "N", "R", "A", "N", "R", "A", "N", "N", "A", "R", "N"]
+    k2 = ["F", "O", "F", "F", "O", "F", "O", "F", "O", "F", "F", "O"]
+    if shape == "nullable":
+        k2 = [None if i in (2, 6, 9) else v for i, v in enumerate(k2)]
+    d = [1.25, 2.50, 3.75, 10.00, 0.05, 7.10, 8.20, 9.30, 4.40, 5.55,
+         6.65, 0.95]
+    x = [3, None, 5, 7, 11, None, 13, 17, 19, 23, None, 29]
+    p = make_page(
+        capacity=16,
+        k1=(k1, T.VARCHAR), k2=(k2, T.VARCHAR),
+        d=(d, T.decimal(12, 2)), x=(x, T.BIGINT),
+    )
+    rows = list(zip(k1, k2, d, x))
+    if shape == "empty":
+        p = dataclasses.replace(
+            p, live=jnp.zeros((16,), jnp.bool_),
+            num_valid=jnp.asarray(0, jnp.int32),
+        )
+        rows = []
+    return p, rows
+
+
+def _onehot_reference(rows):
+    """The same group-by in numpy, in the kernel's order: ascending
+    keys, a key's NULL last."""
+    groups = {}
+    for k1, k2, d, x in rows:
+        groups.setdefault((k1, k2), []).append((d, x))
+    out = []
+    for key in sorted(groups, key=lambda k: (k[0], k[1] is None, k[1] or "")):
+        ds = np.array([round(d * 100) for d, _ in groups[key]], np.int64)
+        xs = [x for _, x in groups[key] if x is not None]
+        out.append({
+            "k1": key[0], "k2": key[1],
+            "sd": int(ds.sum()) / 100, "ad": float(ds.sum()) / 100 / len(ds),
+            "cx": len(xs), "n": len(ds),
+            "mn": min(xs) if xs else None, "mx": max(xs) if xs else None,
+        })
+    return out
+
+
+def _onehot_aggs(p):
+    return [
+        AggCall("sum", col(p, "d"), "sd"),
+        AggCall("avg", col(p, "d"), "ad"),
+        AggCall("count", col(p, "x"), "cx"),
+        AggCall("count_star", None, "n"),
+        AggCall("min", col(p, "x"), "mn"),
+        AggCall("max", col(p, "x"), "mx"),
+    ]
+
+
+def _same_rows(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.keys() == w.keys()
+        for name in w:
+            if isinstance(w[name], float):
+                assert abs(g[name] - w[name]) < 1e-9, (name, g, w)
+            else:
+                assert g[name] == w[name], (name, g, w)
+
+
+@pytest.mark.parametrize("shape", ["plain", "nullable", "empty"])
+@pytest.mark.parametrize("max_groups", [4, 1024, 1 << 20, 1 << 24])
+def test_onehot_output_sized_by_proved_domain(max_groups, shape):
+    """The one-hot path's page is ``min(max_groups, 1024)`` long whatever
+    bucket the planner estimated, holds the rows of a plain group-by,
+    and overflows exactly where ``max_groups`` is under the groups."""
+    p, rows = _onehot_case(shape)
+    keys = [("k1", col(p, "k1")), ("k2", col(p, "k2"))]
+    out, overflow = jax.jit(
+        lambda pg: hash_aggregate(pg, keys, _onehot_aggs(p), max_groups)
+    )(p)
+    want = _onehot_reference(rows)
+    assert out.capacity == min(max_groups, 1024)
+    assert all(b.capacity == out.capacity for b in out.blocks)
+    assert bool(overflow) == (len(want) > max_groups)
+    assert int(out.num_valid) == min(len(want), max_groups)
+    _same_rows(out.to_pylist(), want[:max_groups])
+    if max_groups == 1024:
+        lowerer = ExprLowerer(p)
+        evald = [(n, *lowerer.eval(e), e) for n, e in keys]
+        srt, s_over = _sorted_aggregate(
+            p, evald, _onehot_aggs(p), max_groups, p.row_mask(), lowerer
+        )
+        assert not bool(s_over) and srt.capacity == out.capacity
+        _same_rows(out.to_pylist(), srt.to_pylist())
+
+
+def test_onehot_program_holds_nothing_max_groups_long():
+    """Trace only: at a 2^16-row batch and the planner's 2^24 bucket no
+    value of the program is longer than the one-hot matrix itself."""
+    p, _ = _onehot_case("nullable")
+    cap, nseg = 1 << 16, 3 * (2 + 1)
+    big = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((cap,) + a.shape[1:], a.dtype)
+        if getattr(a, "ndim", 0) else a,
+        p,
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda pg: hash_aggregate(
+            pg, [("k1", col(p, "k1")), ("k2", col(p, "k2"))],
+            _onehot_aggs(p), 1 << 24,
+        )
+    )(big)
+
+    def sizes(jp):
+        for eqn in jp.eqns:
+            for v in eqn.outvars:
+                yield int(np.prod(v.aval.shape, dtype=np.int64)), eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sizes(sub)
+
+    worst = max(sizes(jaxpr.jaxpr))
+    assert worst[0] <= cap * nseg, worst
+    assert jaxpr.out_avals[0].shape == (1024,)

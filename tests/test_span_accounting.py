@@ -161,7 +161,8 @@ def test_snapshot_always_has_the_keys_the_benchmark_reads():
     for k in WORK_KEYS + ["span_ms.unworked", "stmt_wall_ms", "xla_compiles",
                           "xla_cache_loads", "xla_compile_ms",
                           "stage_col_hits", "stage_col_misses",
-                          "stage_evictions", "stage_resident_bytes"]:
+                          "stage_evictions", "stage_resident_bytes",
+                          "program_out_bytes"]:
         assert isinstance(snap[k], (int, float)), k
 
 

@@ -692,6 +692,76 @@ def test_served_mix_stages_only_columns_not_yet_resident():
         coord.shutdown()
 
 
+def test_served_q1_partial_pages_sized_by_key_domain(monkeypatch):
+    """Q1 through client -> coordinator -> worker with batches smaller
+    than the plan's ``max_groups``: every partial program returns a
+    1,024-slot page (the bucket of the proved key domain, not the
+    planner's row bucket), the rows equal the numpy reference, and
+    ``program_out_bytes`` grows by the static size of each dispatched
+    program's output page."""
+    import jax
+
+    from benchmark import discovery
+    from benchmark.data import HostData
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import local_runner
+    from presto_tpu.plan import nodes as N
+    from presto_tpu.server import CoordinatorServer, WorkerServer
+    from presto_tpu.server.client import PrestoTpuClient
+
+    here = os.path.dirname(os.path.abspath(discovery.__file__))
+    mod = discovery.load_module(os.path.join(here, "statements", "q1.py"))
+    data = HostData(TpchConnector(), "tpch", "tiny")
+    coord = CoordinatorServer(
+        session=Session(
+            properties={"max_device_rows": 16_384, "page_capacity": 4_096}
+        )
+    ).start()
+    w = WorkerServer(coordinator_uri=coord.uri).start()
+
+    outputs = []  # (capacity, static bytes) of every dispatched program
+    static_nbytes = local_runner._static_page_nbytes
+
+    def spy_nbytes(page):
+        n = static_nbytes(page)
+        if not isinstance(page.blocks[0].data, jax.core.Tracer):
+            outputs.append((page.capacity, n))
+        return n
+
+    monkeypatch.setattr(local_runner, "_static_page_nbytes", spy_nbytes)
+    partials = []  # (plan's max_groups, batch capacity) per worker batch
+    run_with_pages = w.runner._run_with_pages
+
+    def spy_run(root, scans, pages, *a, **kw):
+        aggs = [n for n in N.walk(root) if isinstance(n, N.AggregationNode)]
+        partials.append((aggs[0].max_groups, pages[0].capacity))
+        return run_with_pages(root, scans, pages, *a, **kw)
+
+    w.runner._run_with_pages = spy_run
+    try:
+        _wait_workers(coord, 1)
+        client = PrestoTpuClient(coord.uri, timeout_s=120)
+        p = mod.params(np.random.default_rng(30), data)
+        before = device_snapshot()
+        rows = client.execute(mod.sql("tpch.tiny", p, "t")).rows()
+        after = device_snapshot()
+        assert mod.compare(
+            [tuple(r) for r in rows], mod.reference(data, p)
+        ) is None
+        assert len(partials) >= 10  # lineitem really streams
+        assert all(cap == 4_096 < mg for mg, cap in partials), partials
+        assert after["dispatches"] - before["dispatches"] == len(outputs)
+        assert len(outputs) >= len(partials)
+        assert {cap for cap, _ in outputs} == {1_024}, outputs
+        grew = after["program_out_bytes"] - before["program_out_bytes"]
+        assert grew == sum(n for _, n in outputs) > 0
+        # thirteen blocks a row, none of them max_groups long
+        assert max(n for _, n in outputs) < 1_024 * 13 * 9
+    finally:
+        w.shutdown(graceful=False)
+        coord.shutdown()
+
+
 def test_worker_cache_disabled_by_zero_budget():
     from presto_tpu.server import WorkerServer
 
