@@ -1766,14 +1766,9 @@ def main() -> None:
         ("tpch_q18_sf10_rows_per_sec", _Q18, "sf10", "lineitem", 100,
          {"max_device_rows": str(1 << 27)}, 2),
         # budget 2M: lineitem (6M) streams while orders (1.5M) still
-        # fits as the replicated build side of the semi-join.
-        # stream_split_cache: stage each split ONCE across the
-        # warmup+2-iteration protocol — re-staging 6 batches per pass
-        # is protocol arithmetic, not engine speed (staging cost not
-        # measured on the chip)
+        # fits as the replicated build side of the semi-join
         ("tpch_q18_sf1_streamed_rows_per_sec", _Q18, "sf1", "lineitem",
-         100, {"max_device_rows": str(1 << 21),
-               "stream_split_cache": "true"}, 2),
+         100, {"max_device_rows": str(1 << 21)}, 2),
         ("tpch_window_orders_sf1_rows_per_sec", _WINDOW, "sf1",
          "orders", None, None, None),
         ("tpcds_q95_tiny_rows_per_sec", queries_tpcds.Q95, None,

@@ -212,10 +212,10 @@ class LocalQueryRunner:
         self._bound_local = threading.local()
         self._prepared: Dict[str, object] = {}
         #: device-resident staged-page cache (exec.staging.SplitCache):
-        #: whole-table entries always (cacheable connectors), split-
-        #: batch entries when stream_split_cache is on — one LRU byte
-        #: budget (staging.cache-bytes) enforced through the memory
-        #: pool's shared "table-cache" owner
+        #: whole-table entries and the columns of split batches
+        #: (cacheable connectors), under one LRU byte budget
+        #: (staging.cache-bytes; 0 keeps nothing) enforced through the
+        #: memory pool's shared "table-cache" owner
         self.split_cache = SplitCache(
             DEFAULT_CACHE_BYTES
             if staging_cache_bytes is None
@@ -244,9 +244,9 @@ class LocalQueryRunner:
         # restore-to-None between another's is-not-None check and its
         # attribute writes)
         self._qs_local = threading.local()
-        # guards read-modify-write (+=) on a SHARED stats sink: a
-        # worker task with task_concurrency > 1 points every batch
-        # driver's thread-local at the same TaskStats
+        # guards read-modify-write (+=) on a SHARED stats sink: the
+        # prefetch thread of a streamed scan stages into the same
+        # TaskStats / QueryStats the thread running the batches writes
         self._qs_mu = threading.Lock()
 
     @property
@@ -2055,9 +2055,9 @@ class LocalQueryRunner:
 
     def _fold_dyn_stat(self, attr: str, n: int) -> None:
         """Add ``n`` to the active sink's dynamic-filter counter under
-        the right lock(s): ``_qs_mu`` serializes concurrent task
-        drivers, and a QueryStats sink ALSO folds worker-task deltas
-        into the same fields under its ``_roll_lock`` (stats.roll_up)
+        the right lock(s): ``_qs_mu`` serializes the threads that
+        share one sink, and a QueryStats sink ALSO folds worker-task
+        deltas into the same fields under its ``_roll_lock`` (stats.roll_up)
         — both writers must serialize on it or an increment silently
         vanishes. The ONE implementation for every runner-side
         dynamic-filter stat write."""
@@ -2274,14 +2274,6 @@ class LocalQueryRunner:
             )
         return page
 
-    def _load_split(
-        self, scan: N.TableScanNode, lo: int, hi: int, capacity: int
-    ) -> Page:
-        """Stage ONE split batch (see :meth:`stage_split`), dropping
-        the residency bookkeeping callers without per-batch pool
-        accounting don't need."""
-        return self.stage_split(scan, lo, hi, capacity)[0]
-
     def stage_split(
         self,
         scan: N.TableScanNode,
@@ -2293,13 +2285,18 @@ class LocalQueryRunner:
     ) -> Tuple[Page, object]:
         """Stage ONE split batch [lo, hi) of a scan to device at a
         fixed capacity, column by column through the device-resident
-        split cache when ``stream_split_cache`` is on: each of the
-        scan's columns is looked up on its own, only the columns that
-        missed are read from the connector and staged, and the batch's
-        ``Page`` is assembled from the parts. Statements with different
-        column sets over one table share what overlaps, and a repeated
-        pass skips the connector read AND the host->device transfer
-        (SURVEY.md §5.7: the table cache at split granularity).
+        split cache: each of the scan's columns is looked up on its
+        own, only the columns that missed are read from the connector
+        and staged, every staged column is offered to the cache, and
+        the batch's ``Page`` is assembled from the parts. Statements
+        with different column sets over one table share what overlaps,
+        and a repeated pass skips the connector read AND the
+        host->device transfer (SURVEY.md §5.7: the table cache at split
+        granularity). Whether a column stays resident is the cache's
+        answer (its byte budget, 0 keeps nothing; a full, all-pinned
+        cache admits nothing); a connector whose data may change under
+        the cache (``cacheable()`` false) is never looked up or kept,
+        the rule :meth:`_load_table` applies.
 
         Returns ``(page, release)``: the caller invokes ``release()``
         once the batch's device execution is done. With an ``owner``,
@@ -2322,9 +2319,7 @@ class LocalQueryRunner:
         from presto_tpu.utils.metrics import REGISTRY
 
         conn = self.catalogs.get(scan.handle.catalog)
-        cache_on = bool(
-            self.session.get("stream_split_cache")
-        ) and conn.cacheable()
+        cacheable = conn.cacheable()
         schema = dict(scan.schema)
         offload = self.session.get("tpu_offload")
         keys = {
@@ -2350,7 +2345,7 @@ class LocalQueryRunner:
                 self._note_pinned_key(keys[c])
 
         try:
-            if cache_on:
+            if cacheable:
                 for c, key in keys.items():
                     got = self.split_cache.get(key, pin=pin)
                     if got is not None:
@@ -2384,7 +2379,7 @@ class LocalQueryRunner:
                     # owner via try_reserve (the staged column serves
                     # THIS batch either way; a full pool just means it
                     # isn't cached — a cache fill never kills a query)
-                    if cache_on and self.split_cache.put(
+                    if cacheable and self.split_cache.put(
                         keys[c], part, nbytes, pin=pin
                     ):
                         resident(c, part)
@@ -2396,8 +2391,8 @@ class LocalQueryRunner:
                 # (resident columns moved nothing)
                 self._fold_device_stat(device_h2d_bytes=staged_bytes)
                 if self._active_qs is not None:
-                    # locked: concurrent task drivers / the prefetch
-                    # thread share one TaskStats sink
+                    # locked: the prefetch thread and the thread
+                    # running the batches share one stats sink
                     with self._qs_mu:
                         self._active_qs.staging_ms += (
                             time.perf_counter() - t0

@@ -43,6 +43,10 @@ MIN_BUCKET = 1 << 10
 #: queries (sizes measured on the chip: PERF.md §4)
 DEFAULT_CACHE_BYTES = 4 << 30
 
+#: split batches the streamed scan loops stage ahead of the batch the
+#: device is executing (:func:`prefetch_iter`'s ``depth``)
+PREFETCH_DEPTH = 2
+
 
 @dataclasses.dataclass
 class ArrayColumn:

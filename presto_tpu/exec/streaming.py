@@ -32,7 +32,12 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from presto_tpu.connectors.tpch import DictColumn
-from presto_tpu.exec.staging import MaskedColumn, prefetch_iter, stage_page
+from presto_tpu.exec.staging import (
+    PREFETCH_DEPTH,
+    MaskedColumn,
+    prefetch_iter,
+    stage_page,
+)
 from presto_tpu.plan import nodes as N
 from presto_tpu.parallel.fragmenter import insert_gathers
 from presto_tpu.server import pages_wire
@@ -53,9 +58,7 @@ def _prefetch_splits(runner, scan, ranges, capacity):
     thread stages batch N+1 while the caller's device program runs
     batch N. Each prefetch-staged batch opens a ``stage:prefetch``
     span on the query's trace, so EXPLAIN ANALYZE shows the staging
-    window overlapping the open ``execute`` span. Depth 0
-    (staging_prefetch_depth) degenerates to the exact serial loop."""
-    depth = int(runner.session.get("staging_prefetch_depth"))
+    window overlapping the open ``execute`` span."""
     qs = runner._active_qs
     trace = getattr(qs, "trace", None) if qs is not None else None
 
@@ -63,17 +66,16 @@ def _prefetch_splits(runner, scan, ranges, capacity):
         # prefetch thread: inherit the caller's stats sink (runner
         # thread-locals don't cross threads)
         runner._qs_local.value = qs
-        if trace is not None and depth > 0:
-            with trace.span(
-                "stage:prefetch", parent=trace.root,
-                lo=rng[0], hi=rng[1],
-            ):
-                return runner._load_split(
-                    scan, rng[0], rng[1], capacity
-                )
-        return runner._load_split(scan, rng[0], rng[1], capacity)
+        # no owner: the columns stay pinned until the query ends
+        # (runner.release_pins), so the release is not needed here
+        if trace is None:
+            return runner.stage_split(scan, rng[0], rng[1], capacity)[0]
+        with trace.span(
+            "stage:prefetch", parent=trace.root, lo=rng[0], hi=rng[1],
+        ):
+            return runner.stage_split(scan, rng[0], rng[1], capacity)[0]
 
-    return prefetch_iter(ranges, load, depth)
+    return prefetch_iter(ranges, load, PREFETCH_DEPTH)
 
 
 def _scan_rows(catalogs, scan: N.TableScanNode) -> int:

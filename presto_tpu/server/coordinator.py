@@ -394,10 +394,9 @@ class CoordinatorServer:
             limit, kill_largest=self._kill_largest_query
         )
         self.memory_pool.node_id = "coordinator"
-        # gather-side staging knobs: the coordinator's embedded runner
-        # stages gathered pages and coordinator-local scans through the
-        # same device-resident split cache / prefetch pipeline the
-        # workers use (tier-1: staging.cache-bytes, staging.prefetch-depth)
+        # the coordinator's embedded runner stages gathered pages and
+        # coordinator-local scans through the same device-resident
+        # split cache the workers use (tier-1: staging.cache-bytes)
         from presto_tpu.exec.staging import DEFAULT_CACHE_BYTES
 
         cache_raw = (
@@ -421,13 +420,6 @@ class CoordinatorServer:
                 config.get("history.max-entries", 256) if config else 256
             ),
         )
-        prefetch = (
-            config.get("staging.prefetch-depth") if config else None
-        )
-        if prefetch is not None:
-            self.local.session.set(
-                "staging_prefetch_depth", int(prefetch)
-            )
         # distributed dynamic filtering (exec/dynfilter.py): tier-1
         # keys seed the session defaults, like the staging knobs
         df_wait = (
@@ -3593,12 +3585,6 @@ class CoordinatorServer:
                 split_batch_rows=int(
                     self.local.session.get("page_capacity")
                 ),
-                task_concurrency=int(
-                    self.local.session.get("task_concurrency")
-                ),
-                prefetch_depth=int(
-                    self.local.session.get("staging_prefetch_depth")
-                ),
                 traceparent=q.trace.traceparent(),
             ))
 
@@ -3998,12 +3984,6 @@ class CoordinatorServer:
                     split_batch_rows=int(
                         self.local.session.get("page_capacity")
                     ),
-                    task_concurrency=int(
-                        self.local.session.get("task_concurrency")
-                    ),
-                    prefetch_depth=int(
-                        self.local.session.get("staging_prefetch_depth")
-                    ),
                     n_partitions=nparts,
                     partition_keys=tuple(keys),
                     spool=self._spooling(),
@@ -4161,12 +4141,6 @@ class CoordinatorServer:
                 split_end=hi,
                 split_batch_rows=int(
                     self.local.session.get("page_capacity")
-                ),
-                task_concurrency=int(
-                    self.local.session.get("task_concurrency")
-                ),
-                prefetch_depth=int(
-                    self.local.session.get("staging_prefetch_depth")
                 ),
                 n_partitions=nparts,
                 partition_keys=tuple(key_names),

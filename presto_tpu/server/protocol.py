@@ -191,13 +191,6 @@ class FragmentSpec:
     #: Safe because the coordinator's FINAL step merges partial states,
     #: so per-batch partials concatenate like per-worker partials.
     split_batch_rows: int = 0
-    #: concurrent split-batch drivers per task (session
-    #: ``task_concurrency``; reference: task.concurrency driver count)
-    task_concurrency: int = 1
-    #: split batches prefetch-staged ahead of device execution
-    #: (session ``staging_prefetch_depth``; -1 = unset — the worker
-    #: falls back to its own session/config default)
-    prefetch_depth: int = -1
     #: partitioned output (reference: PartitionedOutputOperator +
     #: PartitionedOutputBuffer): producers hash-partition output rows by
     #: ``partition_keys`` into ``n_partitions`` buffers; downstream
@@ -251,8 +244,6 @@ class FragmentSpec:
             "split_start": self.split_start,
             "split_end": self.split_end,
             "split_batch_rows": self.split_batch_rows,
-            "task_concurrency": self.task_concurrency,
-            "prefetch_depth": self.prefetch_depth,
             "n_partitions": self.n_partitions,
             "partition_keys": list(self.partition_keys),
             "sources": [list(s) for s in self.sources],
@@ -274,8 +265,6 @@ class FragmentSpec:
             split_start=d["split_start"],
             split_end=d["split_end"],
             split_batch_rows=d.get("split_batch_rows", 0),
-            task_concurrency=d.get("task_concurrency", 1),
-            prefetch_depth=d.get("prefetch_depth", -1),
             n_partitions=d.get("n_partitions", 1),
             partition_keys=tuple(d.get("partition_keys", ())),
             sources=tuple(

@@ -47,9 +47,11 @@ from typing import Dict, List, Optional
 
 from presto_tpu.connectors.spi import ConnectorSplit
 from presto_tpu.exec.staging import (
+    PREFETCH_DEPTH,
     block_nbytes,
     bucket_capacity,
     page_nbytes,
+    prefetch_iter,
     stage_page,
 )
 from presto_tpu.exec.stats import TaskStats
@@ -321,8 +323,8 @@ class WorkerServer:
                 config.get("memory.reserve-block-max-s", 30.0)
             )
         # device-resident split cache (tier-1: staging.cache-bytes,
-        # 0 disables): the LRU byte budget + try_reserve discipline
-        # make always-on safe on the worker hot path — repeated
+        # 0 keeps nothing): the LRU byte budget + try_reserve
+        # discipline make it safe on the worker hot path — repeated
         # queries over the same split ranges skip the connector read
         # and the host->device transfer entirely
         cache_raw = (
@@ -338,8 +340,6 @@ class WorkerServer:
             memory_pool=self.memory_pool,
             staging_cache_bytes=cache_bytes,
         )
-        if cache_bytes > 0:
-            self.runner.session.set("stream_split_cache", True)
         # host-spill lane (degrade before you kill): under HBM
         # pressure, evicted split-cache pages offload to a host-RAM
         # pool of this budget and restage on demand — gated with the
@@ -352,13 +352,6 @@ class WorkerServer:
                 self.runner.split_cache.set_spill_budget(
                     parse_bytes(spill_raw)
                 )
-        prefetch = (
-            config.get("staging.prefetch-depth") if config else None
-        )
-        if prefetch is not None:
-            self.runner.session.set(
-                "staging_prefetch_depth", int(prefetch)
-            )
         # parameterized plan cache (plan/canonical.py): the worker's
         # share is fragment CANONICALIZATION — literal-variant fragments
         # of one shape hit this runner's compile cache — gated by the
@@ -996,8 +989,9 @@ class WorkerServer:
         outputs are partial states the coordinator's FINAL step merges,
         so batching is semantics-preserving; it also bounds device
         residency to one batch (the grouped-execution memory shape).
-        ``task_concurrency`` drivers overlap host staging with device
-        execution."""
+        A background host thread stages up to ``staging.PREFETCH_DEPTH``
+        batches ahead while the jitted fragment for the current one
+        runs on the device, so transfer and compute overlap."""
         # chaos hook: an armed fault plane may delay this task, fail it
         # (kill_task), or crash the whole worker (kill_worker) here —
         # mid-execute from the coordinator's point of view, since the
@@ -1039,7 +1033,7 @@ class WorkerServer:
             for lo in range(spec.split_start, spec.split_end, batch)
         ] or [(spec.split_start, spec.split_end)]
 
-        def stage_batch(lo: int, hi: int):
+        def stage_batch(rng):
             """Stage the partitioned scan's [lo, hi) batch through the
             device-resident split cache (LocalQueryRunner.stage_split:
             one fixed capacity bucket per batch size, so every full
@@ -1047,8 +1041,10 @@ class WorkerServer:
             pinned against eviction until released, the missing ones
             are read here, and those the cache does not admit reserve
             their live residency under the query)."""
-            # staging may run on a prefetch/pool thread: point it at
-            # the task's stats sink (thread-local on the runner)
+            lo, hi = rng
+            t0 = time.perf_counter()
+            # staging runs on the prefetch thread: point it at the
+            # task's stats sink (thread-local on the runner)
             self.runner._qs_local.value = task.stats
             fetched = []
 
@@ -1064,18 +1060,20 @@ class WorkerServer:
             # one accounting unit (data + validity + offsets), same as
             # the pool reservation stage_split made
             staged_bytes = page_nbytes(page)
-            # task.cond guards the stats accumulators: with
-            # task_concurrency > 1 concurrent drivers race the
-            # read-modify-write (+=) and would drop updates
-            with task.cond:
-                task.stats.input_rows += hi - lo
-                task.stats.input_bytes += staged_bytes
+            # one writer: the loop below stages every batch on one
+            # thread, and _load_table's fold of the replicated scans
+            # ran before it started
+            task.stats.input_rows += hi - lo
+            task.stats.input_bytes += staged_bytes
             if fetched:
                 # only REAL staging traffic counts — a resident column
                 # moved zero bytes host->device
                 REGISTRY.distribution("worker.staging_bytes").add(
                     sum(block_nbytes(page.block(c)) for c in fetched)
                 )
+            task.stats.prefetch_ms += (
+                time.perf_counter() - t0
+            ) * 1000.0
             return page, release
 
         def exec_batch(split_page, release):
@@ -1090,17 +1088,10 @@ class WorkerServer:
                     out = apply_host_ops(out, pushed_ops)
                 return out
             finally:
-                with task.cond:
-                    task.stats.execute_ms += (
-                        time.perf_counter() - t_exec
-                    ) * 1000.0
+                task.stats.execute_ms += (
+                    time.perf_counter() - t_exec
+                ) * 1000.0
                 release()
-
-        def run_batch(lo: int, hi: int):
-            self.runner._qs_local.value = task.stats
-            with tracing.phase("exec", site="batch"):
-                page, release = stage_batch(lo, hi)
-                return exec_batch(page, release)
 
         # dynamic-filter SUMMARY task: batch outputs fold into one
         # per-key summary (exec/dynfilter.py — min/max + NDV-capped
@@ -1119,8 +1110,7 @@ class WorkerServer:
                     ndv_limit=spec.dynfilter_ndv
                     or dynfilter.DEFAULT_NDV_LIMIT,
                 )
-                with task.cond:
-                    summary_cell.append(s)
+                summary_cell.append(s)
                 return
             if spec.n_partitions > 1:
                 # partitioned output rides the unified exchange SPI:
@@ -1149,60 +1139,23 @@ class WorkerServer:
                 merged = dynfilter.empty_summary(spec.dynfilter_keys)
             task.dynfilter = merged.to_json()
 
-        if spec.task_concurrency <= 1 or len(ranges) <= 1:
-            # pipelined prefetch staging (staging_prefetch_depth /
-            # tier-1 staging.prefetch-depth): a background host thread
-            # stages split N+1 while the jitted fragment for split N
-            # runs on device — compute and transfer overlap instead of
-            # alternating. Depth 0 is the exact serial path. The
-            # coordinator ships the client session's value on the spec
-            # (like page_capacity / task_concurrency); -1 = unset
-            depth = (
-                spec.prefetch_depth
-                if spec.prefetch_depth >= 0
-                else int(
-                    self.runner.session.get("staging_prefetch_depth")
-                )
-            )
-            from presto_tpu.exec.staging import prefetch_iter
+        def drop_staged(entry):
+            # a prefetched-but-never-executed batch surrenders its
+            # residency (pool reservation or cache pin) — the task
+            # is failing/aborting and the task-end release-all has
+            # not run yet (prefetch_iter's abandonment contract)
+            entry[1]()
 
-            def staged_ahead(rng):
-                t0 = time.perf_counter()
-                page, release = stage_batch(*rng)
-                if depth > 0:
-                    with task.cond:
-                        task.stats.prefetch_ms += (
-                            time.perf_counter() - t0
-                        ) * 1000.0
-                return page, release
-
-            def drop_staged(entry):
-                # a prefetched-but-never-executed batch surrenders its
-                # residency (pool reservation or cache pin) — the task
-                # is failing/aborting and the task-end release-all has
-                # not run yet (prefetch_iter's abandonment contract)
-                entry[1]()
-
-            batches = prefetch_iter(
-                ranges, staged_ahead, depth, on_drop=drop_staged
-            )
-            try:
-                for page, release in batches:
-                    emit(exec_batch(page, release))
-            finally:
-                # deterministic close: joins the prefetch thread and
-                # drops queued batches BEFORE _run_task's release-all
-                batches.close()
-            finish_summary()
-            return
-        from concurrent.futures import ThreadPoolExecutor
-
-        with ThreadPoolExecutor(spec.task_concurrency) as pool:
-            futs = [pool.submit(run_batch, lo, hi) for lo, hi in ranges]
-            for f in futs:
-                with tracing.wait("worker.batch_futures"):
-                    out = f.result()
-                emit(out)
+        batches = prefetch_iter(
+            ranges, stage_batch, PREFETCH_DEPTH, on_drop=drop_staged
+        )
+        try:
+            for page, release in batches:
+                emit(exec_batch(page, release))
+        finally:
+            # deterministic close: joins the prefetch thread and
+            # drops queued batches BEFORE _run_task's release-all
+            batches.close()
         finish_summary()
 
     def _emit_result(self, task: "_Task", out) -> None:

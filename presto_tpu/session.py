@@ -79,13 +79,6 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             True,
         ),
         PropertyMetadata(
-            "task_concurrency",
-            "Local drivers per task (device lanes for vmapped fragments)",
-            int,
-            1,
-            _positive("task_concurrency"),
-        ),
-        PropertyMetadata(
             "speculative_result_rows",
             "Result-prefix rows piggybacked on the control fetch: "
             "results this small materialize in ONE device round trip "
@@ -171,33 +164,6 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             int,
             1 << 24,
             _positive("max_device_rows"),
-        ),
-        PropertyMetadata(
-            "stream_split_cache",
-            "Keep the staged columns of split batches device-resident "
-            "across queries (cacheable connectors only), one cache "
-            "entry a column and split range, so repeated streamed "
-            "passes over the same splits — by statements of any "
-            "column set — skip the connector read and the host->device "
-            "transfer of every column already held (the table cache at "
-            "split granularity — SURVEY.md §5.7). Off by default in a "
-            "bare session; a WorkerServer turns it ON at boot whenever "
-            "staging.cache-bytes > 0 (default 4 GiB, LRU), so the "
-            "served path always runs with it",
-            bool,
-            False,
-        ),
-        PropertyMetadata(
-            "staging_prefetch_depth",
-            "Split batches staged ahead on a background host thread "
-            "while the device executes the current batch (pipelined "
-            "prefetch staging: compute/transfer overlap on the worker "
-            "hot path). 0 disables — the serial stage->run->stage "
-            "path, bit-identical results. Tier-1 twin: "
-            "staging.prefetch-depth",
-            int,
-            2,
-            _non_negative("staging_prefetch_depth"),
         ),
         PropertyMetadata(
             "max_fragment_weight",
@@ -543,11 +509,9 @@ class NodeConfig:
         # exchange pull pipelining: token-acked page-pull requests kept
         # in flight per pull loop (1 = strict request->ack->request)
         "rpc.pull-depth": int,
-        # device-resident split cache: LRU byte budget for staged pages
-        # kept across queries (0 disables), and the number of split
-        # batches prefetch-staged ahead of device execution
+        # device-resident split cache: LRU byte budget for staged
+        # columns kept across queries (0 keeps nothing)
         "staging.cache-bytes": str,
-        "staging.prefetch-depth": int,
         # worker->coordinator announce cadence (healthy interval; the
         # failure backoff grows from it) and per-announce timeout
         "announcement.interval-s": float,

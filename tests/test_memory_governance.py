@@ -344,7 +344,6 @@ def test_spilled_vs_unspilled_results_bit_identical():
         memory_pool=pool, staging_cache_bytes=1 << 20
     )
     r.split_cache.set_spill_budget(64 << 20)
-    r.session.set("stream_split_cache", True)
     r.session.set("max_device_rows", 4096)  # force split streaming
     first = r.execute(sql).rows()
     # HBM pressure: the pool's pressure-hook path reclaims every

@@ -9,7 +9,7 @@ import pytest
 
 from presto_tpu.exec.local_runner import LocalQueryRunner
 from presto_tpu.parallel import DistributedQueryRunner
-from presto_tpu.session import Session
+from presto_tpu.session import NodeConfig, Session
 from presto_tpu.verifier import SqliteOracle, verify_offload, verify_query
 
 Q_AGG = (
@@ -71,9 +71,9 @@ def test_hash_partition_count_sets_mesh_width():
     assert r8.n == len(jax.devices())
 
 
-def test_task_concurrency_and_split_batches_over_http(oracle_mod):
-    """Small split batches + concurrent drivers stream many partial
-    pages per task; results stay oracle-exact."""
+def test_split_batches_over_http(oracle_mod):
+    """Small split batches stream many partial pages per task; results
+    stay oracle-exact."""
     from presto_tpu.server import (
         CoordinatorServer,
         PrestoTpuClient,
@@ -82,7 +82,6 @@ def test_task_concurrency_and_split_batches_over_http(oracle_mod):
 
     coord = CoordinatorServer().start()
     coord.local.session.set("page_capacity", 1 << 12)  # 4096-row batches
-    coord.local.session.set("task_concurrency", 2)
     w = WorkerServer(coordinator_uri=coord.uri).start()
     try:
         deadline = time.time() + 10
@@ -94,6 +93,28 @@ def test_task_concurrency_and_split_batches_over_http(oracle_mod):
     finally:
         w.shutdown(graceful=False)
         coord.shutdown()
+
+
+@pytest.mark.parametrize(
+    "name,tier1",
+    [
+        ("stream_split_cache", None),
+        ("task_concurrency", None),
+        ("staging_prefetch_depth", "staging.prefetch-depth"),
+    ],
+)
+def test_removed_scan_options_are_unknown_names(name, tier1):
+    """A client or config file still naming an option the scan task
+    no longer has fails loudly instead of being silently ignored."""
+    with pytest.raises(KeyError, match=name):
+        Session().set(name, 1)
+    with pytest.raises(KeyError, match=name):
+        Session(properties={name: 1})
+    with pytest.raises(KeyError, match=name):
+        LocalQueryRunner().execute(f"set session {name} = 1")
+    for key in filter(None, (name, tier1)):
+        with pytest.raises(KeyError, match=key):
+            NodeConfig({key: "1"})
 
 
 @pytest.fixture(scope="module")

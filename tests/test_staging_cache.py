@@ -271,9 +271,7 @@ class _ColumnSpy:
 
 def _column_runner(budget=1 << 30, pool=None):
     return LocalQueryRunner(
-        memory_pool=pool,
-        staging_cache_bytes=budget,
-        session=Session(properties={"stream_split_cache": True}),
+        memory_pool=pool, staging_cache_bytes=budget
     )
 
 
@@ -537,13 +535,12 @@ def test_prefetch_iter_propagates_errors():
     assert got == [0, 1, 2]
 
 
-def _streamed_runner(depth):
+def _streamed_runner():
     return LocalQueryRunner(
         session=Session(
             properties={
                 "max_device_rows": 16_384,
                 "page_capacity": 4_096,
-                "staging_prefetch_depth": depth,
             }
         )
     )
@@ -556,16 +553,28 @@ STREAMED_Q = (
 
 
 def test_prefetch_depth_zero_bit_identical():
-    rows0 = _streamed_runner(0).execute(STREAMED_Q).rows()
-    rows2 = _streamed_runner(2).execute(STREAMED_Q).rows()
-    assert rows0 == rows2
+    """Staging ahead changes when a batch is staged, not what: the
+    serial loop (depth 0) and depth 2 yield the same staged batches of
+    the same split ranges in the same order."""
+    # budget 0: both passes really read and stage every batch
+    r = LocalQueryRunner(staging_cache_bytes=0)
+    scan = _lineitem_scan(r, Q1_COLS)
+    ranges = [(lo, lo + CAP) for lo in range(0, 5 * CAP, CAP)]
+
+    def load(rng):
+        return r.stage_split(scan, rng[0], rng[1], CAP)[0].to_pylist()
+
+    serial = list(prefetch_iter(ranges, load, 0))
+    ahead = list(prefetch_iter(ranges, load, 2))
+    assert len(serial) == len(ranges) and serial == ahead
+    assert r.split_cache.stats()["entries"] == 0
 
 
 def test_prefetch_spans_overlap_execute():
     """The trace of a multi-split scan shows stage:prefetch spans
     overlapping the open execute span (the compute/transfer overlap
     EXPLAIN ANALYZE is supposed to make visible)."""
-    r = _streamed_runner(2)
+    r = _streamed_runner()
     r.execute(STREAMED_Q)
     qs = r.history.snapshot()[-1]
     spans = qs.trace.spans()
@@ -599,7 +608,7 @@ def test_worker_warm_task_reports_cache_hits():
     coord = CoordinatorServer().start()
     w = WorkerServer(coordinator_uri=coord.uri).start()
     try:
-        assert w.runner.session.get("stream_split_cache") is True
+        assert w.runner.split_cache.budget > 0
         _wait_workers(coord, 1)
         client = PrestoTpuClient(coord.uri, timeout_s=60)
         q = "select count(*) as c from tpch.tiny.orders"
@@ -763,16 +772,39 @@ def test_served_q1_partial_pages_sized_by_key_domain(monkeypatch):
 
 
 def test_worker_cache_disabled_by_zero_budget():
-    from presto_tpu.server import WorkerServer
+    """A zero budget is the whole off switch: the worker looks its
+    columns up, is told "not resident", and reads the connector for
+    the same statement again."""
+    from presto_tpu.server import CoordinatorServer, WorkerServer
+    from presto_tpu.server.client import PrestoTpuClient
 
+    coord = CoordinatorServer().start()
     w = WorkerServer(
-        config=NodeConfig({"staging.cache-bytes": "0"})
-    )
+        coordinator_uri=coord.uri,
+        config=NodeConfig({"staging.cache-bytes": "0"}),
+    ).start()
+    reads = []
+    load_range = w._load_range
+
+    def spy(scan, lo, hi, columns):
+        reads.append((lo, hi, tuple(columns)))
+        return load_range(scan, lo, hi, columns)
+
+    w._load_range = spy
     try:
-        assert w.runner.session.get("stream_split_cache") is False
         assert w.runner.split_cache.budget == 0
+        _wait_workers(coord, 1)
+        client = PrestoTpuClient(coord.uri, timeout_s=60)
+        q = "select sum(o_totalprice) as s from tpch.tiny.orders"
+        first = client.execute(q).rows()
+        cold = list(reads)
+        assert cold and all(cols for _, _, cols in cold)
+        assert client.execute(q).rows() == first
+        assert sorted(reads[len(cold):]) == sorted(cold)
+        assert w.runner.split_cache.stats()["entries"] == 0
     finally:
         w.shutdown(graceful=False)
+        coord.shutdown()
 
 
 # ----------------------------------------- pipelined exchange pulls

@@ -211,16 +211,14 @@ def test_join_build_spill_left_payload(tight_runner, oracle):
 
 
 def test_split_cache_skips_restaging(oracle):
-    """With stream_split_cache on, the SECOND streamed pass over the
-    same scan must not touch the connector for split batches (the
-    table cache at split granularity — SURVEY.md §5.7; the bench's
-    q18_sf1_streamed protocol fix)."""
+    """The SECOND streamed pass over the same scan must not touch the
+    connector for split batches the cache's budget holds (the table
+    cache at split granularity — SURVEY.md §5.7)."""
     r = LocalQueryRunner(
         session=Session(
             properties={
                 "max_device_rows": MAX_DEVICE_ROWS,
                 "page_capacity": BATCH_ROWS,
-                "stream_split_cache": True,
             }
         )
     )
@@ -252,16 +250,18 @@ def test_split_cache_skips_restaging(oracle):
     assert sorted(first.rows()) == sorted(second.rows())
 
 
-def test_split_cache_off_by_default(oracle):
-    """Default sessions must re-stage (caching every split defeats
-    larger-than-HBM discipline when the set genuinely exceeds HBM)."""
+def test_split_cache_zero_budget_restages(oracle):
+    """A runner built with a zero cache budget keeps no streamed batch:
+    every pass reads and stages every split again (the embedder's
+    setting when the scanned set genuinely exceeds HBM)."""
     r = LocalQueryRunner(
         session=Session(
             properties={
                 "max_device_rows": MAX_DEVICE_ROWS,
                 "page_capacity": BATCH_ROWS,
             }
-        )
+        ),
+        staging_cache_bytes=0,
     )
     conn = r.catalogs.get("tpch")
     calls = []
