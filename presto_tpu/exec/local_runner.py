@@ -43,6 +43,7 @@ from presto_tpu.exec.staging import (
     page_nbytes,
     page_of_columns,
     stage_page,
+    stage_params,
 )
 from presto_tpu.ops import (
     filter_project,
@@ -1723,6 +1724,7 @@ class LocalQueryRunner:
         t_disped = time.perf_counter()
         with tracing.phase("fetch", site="microbatch"):
             fetched = jax.device_get(leaves)
+        DEVICE.count_sync()
         t_fetched = time.perf_counter()
         # device-plane accounting: the batch is ONE real dispatch +
         # one fetch on the process counters; per-lane attribution
@@ -1816,7 +1818,10 @@ class LocalQueryRunner:
         fetch_result: bool = True,
     ) -> Page:
         """Run the compiled whole-plan program, retrying on capacity
-        overflow. With ``stats_out``, per-node row counters are traced as
+        overflow: :meth:`_resolve`, :meth:`_dispatch`, :meth:`_collect`
+        in a row (a task that runs many batches through one fragment
+        resolves once and repeats the other two).
+        With ``stats_out``, per-node row counters are traced as
         extra outputs (EXPLAIN ANALYZE); stats_out receives
         (walk_id, label, rows, capacity) records.
 
@@ -1824,8 +1829,35 @@ class LocalQueryRunner:
         stays ON DEVICE — only the control flags + live count are
         fetched (one round trip) — and the return value is
         ``(device_page_rebucketed, n)`` instead of a host page."""
+        resolved = self._resolve(
+            root, scans, analyzed=stats_out is not None
+        )
+        return self._collect(
+            self._dispatch(resolved, pages),
+            stats_out=stats_out,
+            fetch_result=fetch_result,
+        )
+
+    def _resolve(
+        self,
+        root: N.PlanNode,
+        scans: List[N.PlanNode],
+        analyzed: bool = False,
+        prog: Optional[N.PlanNode] = None,
+        batches: int = 1,
+    ) -> "_Resolved":
+        """What is the same for every batch a task runs through
+        ``root``: the canonical root and its fingerprint, the parameter
+        vector, the page indices re-mapped onto the canonical leaves
+        and the compiled entry. ``prog`` is the program-instance token
+        of an overflow retry (the unscaled root). A caller that will
+        dispatch several ``batches`` gets the parameter vector on the
+        device: handed over from the host, each of its scalars is a
+        transfer of its own at every call (0.8 ms of a 2.3 ms Q1 call
+        on the chip; the one put costs about as much: PERF.md §7)."""
+        from presto_tpu.plan import canonical
+
         scan_ids = {id(s): i for i, s in enumerate(scans)}
-        analyzed = stats_out is not None
         # per-operator observability (exec/stats.OperatorStats): trace
         # the per-node row counters on EVERY run, not just EXPLAIN
         # ANALYZE — the history store and QueryInfo read them. Part of
@@ -1834,214 +1866,212 @@ class LocalQueryRunner:
         counted = analyzed or bool(
             self.session.get("enable_operator_stats")
         )
-        from presto_tpu.plan import canonical
-
-        # program-instance token for operator-stats folding: streamed
-        # batches re-enter with the SAME root object (their folds sum),
-        # while distinct programs of one query — scalar-subquery
-        # pre-passes, sibling fragments — are different objects even
-        # when their shapes (and walk positions) coincide
-        prog_root = root
-        tries = 0
-        while True:
-            # key by structural fingerprint, not object identity: every
-            # execute_plan rebuilds the tree (prune/bind), and a retrace
-            # per call would redo XLA cache lookups costing seconds.
-            # The fingerprint is taken over the CANONICAL root —
-            # literals hoisted into RuntimeParam slots whose values ride
-            # in as the program's parameter vector — so literal-variant
-            # plans of one shape share ONE compiled program
-            # (plan/canonical.py; enable_plan_cache=false keeps the
-            # pre-cache literal fingerprints bit-for-bit).
-            offload = self.session.get("tpu_offload")
-            from presto_tpu.utils.metrics import REGISTRY
-
-            bound = getattr(self._bound_local, "value", None)
-            # analyzed (EXPLAIN ANALYZE) keeps literals in place: node
-            # labels print the predicate exprs, and those must show the
-            # query's actual values
-            hoist = (
-                bool(self.session.get("enable_plan_cache"))
-                and not analyzed
-            )
-            croot, params = canonical.hoist_params(
-                root, bound=bound, hoist_literals=hoist
-            )
-            # fingerprint() is a full-tree repr: compute it ONCE per
-            # iteration (it keys the compile cache, the no-hoist check,
-            # and the failure handler below)
+        # key by structural fingerprint, not object identity: every
+        # execute_plan rebuilds the tree (prune/bind), and a retrace
+        # per call would redo XLA cache lookups costing seconds.
+        # The fingerprint is taken over the CANONICAL root —
+        # literals hoisted into RuntimeParam slots whose values ride
+        # in as the program's parameter vector — so literal-variant
+        # plans of one shape share ONE compiled program
+        # (plan/canonical.py; enable_plan_cache=false keeps the
+        # pre-cache literal fingerprints bit-for-bit).
+        offload = self.session.get("tpu_offload")
+        bound = getattr(self._bound_local, "value", None)
+        # analyzed (EXPLAIN ANALYZE) keeps literals in place: node
+        # labels print the predicate exprs, and those must show the
+        # query's actual values
+        hoist = (
+            bool(self.session.get("enable_plan_cache"))
+            and not analyzed
+        )
+        croot, params = canonical.hoist_params(
+            root, bound=bound, hoist_literals=hoist
+        )
+        # fingerprint() is a full-tree repr: computed ONCE here (it
+        # keys the compile cache, the no-hoist check, and the failure
+        # handler of _dispatch)
+        cfp = croot.fingerprint()
+        if croot is not root and cfp in self._no_hoist:
+            # this shape's parameterized form failed to trace once:
+            # permanent classic literal-form lane
+            croot, params = canonical.bind_literal_root(
+                root, bound
+            ), ()
             cfp = croot.fingerprint()
-            if croot is not root and cfp in self._no_hoist:
-                # this shape's parameterized form failed to trace once:
-                # permanent classic literal-form lane
-                croot, params = canonical.bind_literal_root(
-                    root, bound
-                ), ()
-                cfp = croot.fingerprint()
-            if croot is root:
-                cscan_ids = scan_ids
-            else:
-                # the canonical tree is a rebuilt copy: its leaves are
-                # NEW objects wherever an ancestor/field changed, but
-                # the rewrite preserves tree shape, so leaves correspond
-                # 1:1 by walk position — remap the identity-keyed page
-                # indices onto the canonical leaves
-                leaf_types = (N.TableScanNode, N.RemoteSourceNode)
-                orig_leaves = [
-                    n for n in N.walk(root) if isinstance(n, leaf_types)
-                ]
-                new_leaves = [
-                    n
-                    for n in N.walk(croot)
-                    if isinstance(n, leaf_types)
-                ]
-                cscan_ids = dict(scan_ids)
-                for o, nn in zip(orig_leaves, new_leaves):
-                    if id(o) in scan_ids:
-                        cscan_ids[id(nn)] = scan_ids[id(o)]
-            key = (cfp, analyzed, counted, offload)
-            with self._compile_mu:
-                entry = self._compiled.get(key)
-                fresh = entry is None
-                if fresh:
-                    trace, msgs_cell, nodes_cell = self._make_trace(
-                        croot, cscan_ids, counted, analyzed
-                    )
-                    trace.__name__ = _program_name(croot, cfp)
-                    entry = (jax.jit(trace), msgs_cell, nodes_cell)
-                    self._compiled[key] = entry
+        if croot is root:
+            cscan_ids = scan_ids
+        else:
+            # the canonical tree is a rebuilt copy: its leaves are
+            # NEW objects wherever an ancestor/field changed, but
+            # the rewrite preserves tree shape, so leaves correspond
+            # 1:1 by walk position — remap the identity-keyed page
+            # indices onto the canonical leaves
+            leaf_types = (N.TableScanNode, N.RemoteSourceNode)
+            orig_leaves = [
+                n for n in N.walk(root) if isinstance(n, leaf_types)
+            ]
+            new_leaves = [
+                n
+                for n in N.walk(croot)
+                if isinstance(n, leaf_types)
+            ]
+            cscan_ids = dict(scan_ids)
+            for o, nn in zip(orig_leaves, new_leaves):
+                if id(o) in scan_ids:
+                    cscan_ids[id(nn)] = scan_ids[id(o)]
+        key = (cfp, analyzed, counted, offload)
+        with self._compile_mu:
+            entry = self._compiled.get(key)
+            fresh = entry is None
+            if fresh:
+                trace, msgs_cell, nodes_cell = self._make_trace(
+                    croot, cscan_ids, counted, analyzed
+                )
+                trace.__name__ = _program_name(croot, cfp)
+                entry = (jax.jit(trace), msgs_cell, nodes_cell)
+                self._compiled[key] = entry
+        fn, msgs_cell, nodes_cell = entry
+        if batches > 1 and params:
+            with self._device_scope():
+                params = stage_params(params)
+        return _Resolved(
+            root=root,
+            scans=scans,
+            # program-instance token for operator-stats folding:
+            # streamed batches re-enter with the SAME root object
+            # (their folds sum), while distinct programs of one query —
+            # scalar-subquery pre-passes, sibling fragments — are
+            # different objects even when their shapes (and walk
+            # positions) coincide
+            prog=root if prog is None else prog,
+            analyzed=analyzed,
+            counted=counted,
+            key=key,
+            params=params,
+            fn=fn,
+            msgs_cell=msgs_cell,
+            nodes_cell=nodes_cell,
+            fresh=fresh,
+        )
+
+    def _dispatch(
+        self, resolved: "_Resolved", pages: List[Page]
+    ) -> "_Pending":
+        """Launch the resolved program over one batch's pages and
+        return its outputs on the device, unread: the call does not
+        wait for the program (:meth:`_collect` does)."""
+        from presto_tpu.utils.metrics import REGISTRY
+
+        while True:
             # compile-amortization counters (bench.py runs read these):
-            # a miss pays trace + XLA compile; steady state is all hits
+            # a miss pays trace + XLA compile; steady state is all
+            # hits. jit compiles lazily, at the entry's FIRST call
+            fresh, resolved.fresh = resolved.fresh, False
             REGISTRY.counter(
                 "compile.cache_miss" if fresh else "compile.cache_hit"
             ).update()
             if fresh and self._active_qs is not None:
                 self._active_qs.compile_cache_hit = False
-            fn, msgs_cell, nodes_cell = entry
             t_disp = time.perf_counter()
             try:
                 with self._device_scope(), tracing.phase("dispatch"):
-                    page, flags_arr, err_arr, cnt_arr, dyn_arr = fn(
-                        pages, params
+                    page, flags_arr, err_arr, cnt_arr, dyn_arr = (
+                        resolved.fn(pages, resolved.params)
                     )
+                break
             except Exception:
-                if params:
-                    # the canonical form failed (usually a hoisted
-                    # literal feeding a structure-demanding kernel at
-                    # trace time): retire it and recompile this shape
-                    # in literal form — a query the literal path can
-                    # run must never fail because of hoisting. Guarded
-                    # on params alone (not _no_hoist membership): a
-                    # CONCURRENT thread that fetched the same entry
-                    # before the first failure retired it must also
-                    # fall back, not re-raise. The literal lane always
-                    # has params=(), so this cannot loop.
-                    self._no_hoist.add(key[0])
-                    with self._compile_mu:
-                        self._compiled.pop(key, None)
-                    continue
-                raise
-            # Round-trip discipline (every separate fetch pays a host<->
-            # device sync; not measured on the chip): ONE device_get for
-            # all control
-            # outputs + the result row count + a SPECULATIVE prefix of
-            # every result block. When the result fits the speculative
-            # window (the common aggregate / top-N shape) the query is
-            # ONE round trip total; otherwise materialize_page below
-            # fetches the full live prefix as before (the wasted
-            # speculative bytes cost ~1ms/MB vs the 65ms RTT saved).
-            spec = min(
+                if not resolved.params:
+                    raise
+                # the canonical form failed (usually a hoisted
+                # literal feeding a structure-demanding kernel at
+                # trace time): retire it and recompile this shape
+                # in literal form — a query the literal path can
+                # run must never fail because of hoisting. Guarded
+                # on params alone (not _no_hoist membership): a
+                # CONCURRENT thread that fetched the same entry
+                # before the first failure retired it must also
+                # fall back, not re-raise. The literal lane always
+                # has params=(), so this cannot loop. The task's
+                # later batches take it too: ``resolved`` is theirs.
+                self._no_hoist.add(resolved.key[0])
+                with self._compile_mu:
+                    self._compiled.pop(resolved.key, None)
+                literal = self._resolve(
+                    resolved.root, resolved.scans,
+                    analyzed=resolved.analyzed, prog=resolved.prog,
+                )
+                resolved.__dict__.update(literal.__dict__)
+        t_disped = time.perf_counter()
+        # device-plane accounting (utils/telemetry.py): one real
+        # dispatch; a fresh entry's dispatch window carries trace +
+        # XLA compile (documented approximation). Counted for runs
+        # that overflow too: they still dispatched.
+        if DEVICE.enabled:
+            compile_ms = (t_disped - t_disp) * 1000.0 if fresh else 0.0
+            DEVICE.count_dispatch()
+            DEVICE.count_program_out(_static_page_nbytes(page))
+            if fresh:
+                DEVICE.count_compile(compile_ms)
+            self._fold_device_stat(
+                device_dispatches=1,
+                device_compiles=1 if fresh else 0,
+                device_compile_ms=compile_ms,
+            )
+        return _Pending(
+            resolved=resolved,
+            pages=pages,
+            page=page,
+            control=(flags_arr, err_arr, cnt_arr, dyn_arr),
+            t_disp=t_disp,
+        )
+
+    def _collect(
+        self,
+        pending: "_Pending",
+        stats_out: Optional[List] = None,
+        fetch_result: bool = True,
+        tries: int = 0,
+    ):
+        """Read a dispatched batch. Round-trip discipline: ONE
+        ``jax.device_get`` for all control outputs + the result row
+        count + a SPECULATIVE prefix of every result block. When the
+        result fits the speculative window (the common aggregate /
+        top-N shape) the batch is ONE round trip total; otherwise
+        materialize_page fetches the full live prefix. What a read
+        costs on the chip is its leaves, not its being a round trip
+        (my chip runs, PR 32: 76 us of host time to issue a leaf's
+        copy; fifteen reads of 25 leaves 33.2 ms, one read of 375
+        leaves 31.0 ms — PERF.md §7). An error flag raises; a capacity
+        overflow runs the batch again at four times the capacities."""
+        page = pending.page
+        res = pending.resolved
+        spec = (
+            min(
                 int(self.session.get("speculative_result_rows")),
                 page.capacity,
             )
-            if not fetch_result:
-                spec = 0
-            leaves: List = [
-                flags_arr, err_arr, cnt_arr, dyn_arr, page.num_valid,
-            ]
-            if spec > 0:
-                leaves.extend(page.prefix_leaves(spec))
-            t_disped = time.perf_counter()
-            with tracing.phase("fetch", site="control"):
-                fetched = jax.device_get(leaves)
-            t_fetched = time.perf_counter()
-            # device-plane accounting (utils/telemetry.py): one real
-            # dispatch + its fetch bytes; a fresh entry's dispatch
-            # window carries trace + XLA compile (jit compiles lazily
-            # at first call — documented approximation). Counted on
-            # retry iterations too: an overflowed run still dispatched.
-            if DEVICE.enabled:
-                d2h = sum(
-                    int(getattr(leaf, "nbytes", 0)) for leaf in fetched
-                )
-                compile_ms = (
-                    (t_disped - t_disp) * 1000.0 if fresh else 0.0
-                )
-                DEVICE.count_dispatch()
-                DEVICE.count_program_out(_static_page_nbytes(page))
-                DEVICE.count_d2h(d2h)
-                if fresh:
-                    DEVICE.count_compile(compile_ms)
-                self._fold_device_stat(
-                    device_dispatches=1,
-                    device_d2h_bytes=d2h,
-                    device_compiles=1 if fresh else 0,
-                    device_compile_ms=compile_ms,
-                )
-            flags_np, err_np, cnt_np, dyn_np, n_out = fetched[:5]
-            for msg, flag in zip(msgs_cell, err_np):
-                if bool(flag):
-                    raise ExecutionError(msg)
-            if not flags_np.any():
-                if analyzed:
-                    stats_out.clear()
-                    stats_out.extend(
-                        (walk_id, label, int(c), cap)
-                        for (
-                            walk_id, label, cap, _nb, _dp, _fp, _ch
-                        ), c in zip(nodes_cell, cnt_np)
-                    )
-                if counted and nodes_cell:
-                    # fold per-operator actuals into the active stats
-                    # sink (TaskStats on workers, QueryStats locally);
-                    # only the SUCCESSFUL run counts — overflow retries
-                    # re-execute the same rows
-                    self._fold_operator_stats(
-                        nodes_cell,
-                        cnt_np,
-                        wall_ms=(t_fetched - t_disp) * 1000.0,
-                        device_ms=(t_fetched - t_disped) * 1000.0,
-                        prog=prog_root,
-                    )
-                if dyn_np.size:
-                    # attribute only on the SUCCESSFUL run: overflow
-                    # retries re-execute the filter over the same rows
-                    pruned = int(dyn_np.sum())
-                    if pruned:
-                        from presto_tpu.utils.metrics import REGISTRY
-
-                        REGISTRY.counter(
-                            "dynamic_filter.rows_pruned"
-                        ).update(pruned)
-                        self._fold_dyn_stat(
-                            "dynamic_filter_rows_pruned", pruned
-                        )
-                n = int(n_out)
-                # output capacity-bucket padding waste: the rows this
-                # program computed over vs the rows anyone will read
-                if DEVICE.enabled:
-                    DEVICE.count_padding(n, page.capacity)
-                    self._fold_device_stat(
-                        device_pad_rows=page.capacity - n,
-                        device_live_rows=n,
-                    )
-                if not fetch_result:
-                    from presto_tpu.page import pad_capacity
-
-                    return pad_capacity(page, bucket_capacity(n)), n
-                if 0 < spec and n <= spec:
-                    return _page_from_prefix(page, fetched[5:], n)
-                return materialize_page(page, n)
+            if fetch_result
+            else 0
+        )
+        leaves: List = [*pending.control, page.num_valid]
+        if spec > 0:
+            leaves.extend(page.prefix_leaves(spec))
+        t_wait = time.perf_counter()
+        with tracing.phase("fetch", site="control"):
+            fetched = jax.device_get(leaves)
+        t_fetched = time.perf_counter()
+        if DEVICE.enabled:
+            d2h = sum(
+                int(getattr(leaf, "nbytes", 0)) for leaf in fetched
+            )
+            DEVICE.count_sync()
+            DEVICE.count_d2h(d2h)
+            self._fold_device_stat(device_d2h_bytes=d2h)
+        flags_np, err_np, cnt_np, dyn_np, n_out = fetched[:5]
+        for msg, flag in zip(res.msgs_cell, err_np):
+            if bool(flag):
+                raise ExecutionError(msg)
+        if flags_np.any():
             tries += 1
             if tries >= self.MAX_RETRIES:
                 raise ExecutionError(
@@ -2051,7 +2081,65 @@ class LocalQueryRunner:
             if self._active_qs is not None:
                 with self._qs_mu:
                     self._active_qs.retries += 1
-            root = _scale_capacities(root, 4)
+            scaled = self._resolve(
+                _scale_capacities(res.root, 4), res.scans,
+                analyzed=res.analyzed, prog=res.prog,
+            )
+            return self._collect(
+                self._dispatch(scaled, pending.pages),
+                stats_out=stats_out,
+                fetch_result=fetch_result,
+                tries=tries,
+            )
+        if res.analyzed:
+            stats_out.clear()
+            stats_out.extend(
+                (walk_id, label, int(c), cap)
+                for (
+                    walk_id, label, cap, _nb, _dp, _fp, _ch
+                ), c in zip(res.nodes_cell, cnt_np)
+            )
+        if res.counted and res.nodes_cell:
+            # fold per-operator actuals into the active stats
+            # sink (TaskStats on workers, QueryStats locally);
+            # only the SUCCESSFUL run counts — overflow retries
+            # re-execute the same rows
+            self._fold_operator_stats(
+                res.nodes_cell,
+                cnt_np,
+                wall_ms=(t_fetched - pending.t_disp) * 1000.0,
+                device_ms=(t_fetched - t_wait) * 1000.0,
+                prog=res.prog,
+            )
+        if dyn_np.size:
+            # attribute only on the SUCCESSFUL run: overflow
+            # retries re-execute the filter over the same rows
+            pruned = int(dyn_np.sum())
+            if pruned:
+                from presto_tpu.utils.metrics import REGISTRY
+
+                REGISTRY.counter(
+                    "dynamic_filter.rows_pruned"
+                ).update(pruned)
+                self._fold_dyn_stat(
+                    "dynamic_filter_rows_pruned", pruned
+                )
+        n = int(n_out)
+        # output capacity-bucket padding waste: the rows this
+        # program computed over vs the rows anyone will read
+        if DEVICE.enabled:
+            DEVICE.count_padding(n, page.capacity)
+            self._fold_device_stat(
+                device_pad_rows=page.capacity - n,
+                device_live_rows=n,
+            )
+        if not fetch_result:
+            from presto_tpu.page import pad_capacity
+
+            return pad_capacity(page, bucket_capacity(n)), n
+        if 0 < spec and n <= spec:
+            return _page_from_prefix(page, fetched[5:], n)
+        return materialize_page(page, n)
 
     def _fold_dyn_stat(self, attr: str, n: int) -> None:
         """Add ``n`` to the active sink's dynamic-filter counter under
@@ -2605,6 +2693,7 @@ def materialize_page(page: Page, n: int) -> Page:
         return page
     with tracing.phase("fetch", site="materialize"):
         leaves = jax.device_get(page.prefix_leaves(n))
+    DEVICE.count_sync()
     return _page_from_prefix(page, leaves, n)
 
 
@@ -2670,6 +2759,40 @@ def _node_depths(root: N.PlanNode) -> Dict[int, int]:
 
     rec(root, 0)
     return out
+
+
+@dataclasses.dataclass
+class _Resolved:
+    """A fragment root resolved to its compiled program
+    (``LocalQueryRunner._resolve``): what every batch of one task
+    shares. ``fresh`` is true until the entry's first dispatch."""
+
+    root: N.PlanNode
+    scans: List[N.PlanNode]
+    prog: N.PlanNode
+    analyzed: bool
+    counted: bool
+    key: tuple
+    params: tuple
+    fn: object
+    msgs_cell: list
+    nodes_cell: list
+    fresh: bool
+
+
+@dataclasses.dataclass
+class _Pending:
+    """One dispatched batch whose outputs are still on the device
+    (``LocalQueryRunner._dispatch`` -> ``_collect``): the result
+    ``page`` and the four ``control`` outputs (overflow flags, error
+    flags, operator row counters, dynamic-filter counts). ``pages``
+    are its inputs, kept for an overflow retry."""
+
+    resolved: _Resolved
+    pages: List[Page]
+    page: Page
+    control: tuple
+    t_disp: float
 
 
 def _static_page_nbytes(page: Page) -> int:

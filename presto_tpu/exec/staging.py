@@ -254,6 +254,20 @@ def host_to_page(host, nbytes: int):
     return staged
 
 
+def stage_params(params: tuple) -> tuple:
+    """A fragment program's hoisted parameter vector on the device,
+    for a task that runs many batches through one program: handed
+    over from the host, every scalar of it is a transfer of its own
+    at every call (local_runner._dispatch)."""
+    import jax
+
+    staged = jax.device_put(params)
+    DEVICE.count_h2d(
+        sum(int(getattr(p, "nbytes", 0)) for p in params)
+    )
+    return staged
+
+
 def block_nbytes(b: Block) -> int:
     """Device bytes one staged column holds (data/validity/offsets
     buffers, recursing into array/map/row children)."""

@@ -421,27 +421,33 @@ class Page:
         materialization path fetches (round-trip discipline). Array
         blocks fetch offsets[:k+1] plus the FULL flat values array
         (their live extent is data-dependent; the padded fetch trades
-        bytes for the round trip)."""
+        bytes for the round trip). A leaf that ``k`` covers is returned
+        as it is: slicing a device array is Python work even when it
+        cuts nothing off."""
+
+        def head(x, n):
+            return x if n >= x.shape[0] else x[:n]
+
         leaves = []
         for blk in self.blocks:
             if blk.dtype.is_map:
-                leaves.append(blk.offsets[: k + 1])
+                leaves.append(head(blk.offsets, k + 1))
                 for ch in blk.children:
                     leaves.append(ch.data)
                     if ch.valid is not None:
                         leaves.append(ch.valid)
             elif blk.dtype.is_row:
                 for ch in blk.children:
-                    leaves.append(ch.data[:k])
+                    leaves.append(head(ch.data, k))
                     if ch.valid is not None:
-                        leaves.append(ch.valid[:k])
+                        leaves.append(head(ch.valid, k))
             elif blk.offsets is not None:
-                leaves.append(blk.offsets[: k + 1])
+                leaves.append(head(blk.offsets, k + 1))
                 leaves.append(blk.data)
             else:
-                leaves.append(blk.data[:k])
+                leaves.append(head(blk.data, k))
             if blk.valid is not None:
-                leaves.append(blk.valid[:k])
+                leaves.append(head(blk.valid, k))
         return leaves
 
     def with_blocks(self, names: Sequence[str], blocks: Sequence[Block]) -> "Page":

@@ -991,7 +991,14 @@ class WorkerServer:
         residency to one batch (the grouped-execution memory shape).
         A background host thread stages up to ``staging.PREFETCH_DEPTH``
         batches ahead while the jitted fragment for the current one
-        runs on the device, so transfer and compute overlap."""
+        runs on the device, so transfer and compute overlap.
+
+        The fragment is resolved to its compiled program once, for
+        all of the task's batches (LocalQueryRunner._resolve). Each
+        batch is read back before the next is dispatched, so the pages
+        leave at the pace they are made: reading a task's batches in
+        one go, or keeping several in flight, was slower on the chip
+        (PERF.md §6, PR 32)."""
         # chaos hook: an armed fault plane may delay this task, fail it
         # (kill_task), or crash the whole worker (kill_worker) here —
         # mid-execute from the coordinator's point of view, since the
@@ -1076,6 +1083,8 @@ class WorkerServer:
             ) * 1000.0
             return page, release
 
+        resolved = self.runner._resolve(root, scans, batches=len(ranges))
+
         def exec_batch(split_page, release):
             pages = [
                 split_page if s is part_scan else repl_pages[id(s)]
@@ -1083,7 +1092,9 @@ class WorkerServer:
             ]
             t_exec = time.perf_counter()
             try:
-                out = self.runner._run_with_pages(root, scans, pages)
+                out = self.runner._collect(
+                    self.runner._dispatch(resolved, pages)
+                )
                 if pushed_ops:
                     out = apply_host_ops(out, pushed_ops)
                 return out

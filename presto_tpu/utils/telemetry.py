@@ -117,6 +117,7 @@ class DeviceTelemetry:
         self._pad = REGISTRY.counter("device.pad_rows")
         self._live = REGISTRY.counter("device.live_rows")
         self._program_out = REGISTRY.counter("device.program_out_bytes")
+        self._syncs = REGISTRY.counter("device.syncs")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -148,6 +149,14 @@ class DeviceTelemetry:
         """
         if self.enabled:
             self._program_out.update(int(nbytes))
+
+    def count_sync(self) -> None:
+        """One blocking ``jax.device_get`` of program outputs: the
+        host waited for the device and the transfers (one a dispatched
+        batch; a result longer than its speculative prefix costs a
+        second)."""
+        if self.enabled:
+            self._syncs.update()
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -219,7 +228,8 @@ class DeviceTelemetry:
         engages (waits with no time-outs and no stalls).
         ``program_out_bytes`` sums the static size of every dispatched
         fragment program's output page (beside ``d2h_bytes``, what of
-        it was fetched).
+        it was fetched), ``device_syncs`` the blocking fetches that
+        brought it (local_runner._collect, materialize_page).
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -241,6 +251,7 @@ class DeviceTelemetry:
             "pad_rows": int(self._pad.total),
             "live_rows": int(self._live.total),
             "program_out_bytes": int(self._program_out.total),
+            "device_syncs": int(self._syncs.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

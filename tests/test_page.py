@@ -117,3 +117,25 @@ def test_block_null_mask_static_none():
     b2 = Block.from_pylist([1, None, 3], T.BIGINT)
     assert b2.valid is not None
     assert list(np.asarray(b2.valid)) == [True, False, True]
+
+
+@pytest.mark.parametrize("k", [8, 9, 1 << 20])
+def test_prefix_leaves_returns_a_covered_leaf_unsliced(k):
+    """A prefix that covers the page cuts nothing: the leaves are the
+    page's own arrays, not slices of them (a slice of a device array
+    is Python work even when it is the whole array)."""
+    page = Page.from_pydict(
+        {"k": [1, 2, None], "name": ["a", None, "b"]},
+        {"k": T.BIGINT, "name": T.VARCHAR},
+        capacity=8,
+    )
+    own = []
+    for b in page.blocks:
+        own.append(b.data)
+        if b.valid is not None:
+            own.append(b.valid)
+    leaves = page.prefix_leaves(k)
+    assert len(leaves) == len(own)
+    assert all(a is b for a, b in zip(leaves, own))
+    # a shorter prefix still cuts
+    assert [x.shape[0] for x in page.prefix_leaves(3)] == [3] * len(own)

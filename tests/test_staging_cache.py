@@ -739,14 +739,17 @@ def test_served_q1_partial_pages_sized_by_key_domain(monkeypatch):
 
     monkeypatch.setattr(local_runner, "_static_page_nbytes", spy_nbytes)
     partials = []  # (plan's max_groups, batch capacity) per worker batch
-    run_with_pages = w.runner._run_with_pages
+    dispatch = w.runner._dispatch
 
-    def spy_run(root, scans, pages, *a, **kw):
-        aggs = [n for n in N.walk(root) if isinstance(n, N.AggregationNode)]
+    def spy_dispatch(resolved, pages):
+        aggs = [
+            n for n in N.walk(resolved.root)
+            if isinstance(n, N.AggregationNode)
+        ]
         partials.append((aggs[0].max_groups, pages[0].capacity))
-        return run_with_pages(root, scans, pages, *a, **kw)
+        return dispatch(resolved, pages)
 
-    w.runner._run_with_pages = spy_run
+    w.runner._dispatch = spy_dispatch
     try:
         _wait_workers(coord, 1)
         client = PrestoTpuClient(coord.uri, timeout_s=120)
