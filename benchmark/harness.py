@@ -317,10 +317,18 @@ def run(cell: discovery.Cell, args, t0: float) -> int:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
-    line = json.dumps(result)
-    if not on_chip:
-        # no accelerator: no result on standard output, and a non-zero exit
-        print(line, file=sys.stderr, flush=True)
-        return 1
-    print(line, flush=True)
+    # each number the comparison held, beside its limit: the result's last
+    # key and the last lines of standard error. Every answer of the run is
+    # compared exactly (``_check``), so the numbers are counts of answers.
+    wrong = sum(1 for s in ctx.samples if (s.error or "").startswith("mismatch: "))
+    result["compared"] = {
+        "answers": {"value": len(ctx.samples), "limit": 1, "holds": "at least"},
+        "wrong": {"value": wrong, "limit": 0, "holds": "at most"},
+        "unanswered": {"value": result["failed"] - wrong, "limit": 0, "holds": "at most"},
+    }
+    # no accelerator: no result on standard output, and (never correct) a non-zero exit
+    print(json.dumps(result), file=sys.stdout if on_chip else sys.stderr, flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} ({c['holds']} {c['limit']})",
+              file=sys.stderr, flush=True)
     return 0 if result["correct"] else 1

@@ -101,7 +101,13 @@ def test_the_dropped_in_cell_runs(copy, trace):
     assert got.returncode == 1, got.stderr[-2000:]  # a CPU run is never correct
     for line in got.stdout.splitlines():  # and prints no result on standard output
         assert "metrics" not in json.loads(line)
-    line = json.loads(got.stderr.strip().splitlines()[-1])
+    # standard error ends with the result and, last, each compared number beside its limit
+    *_, last, answers, wrong, unanswered = got.stderr.strip().splitlines()
+    assert (answers.split(":")[0], wrong, unanswered) == (
+        "compared answers", "compared wrong: 0 (at most 0)",
+        "compared unanswered: 0 (at most 0)")
+    line = json.loads(last)
+    assert list(line)[-1] == "compared"
     assert line["correct"] is False and line["failed"] == 0 and line["attempted"] > 1
     assert line["device"]["platform"] == "cpu"
     if trace:

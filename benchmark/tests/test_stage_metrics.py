@@ -1,6 +1,7 @@
-"""The cell ``sf10_q1_resident`` resolves by name, and the two layer
-metrics of the staging cache's residency by column read the counters
-they name — or are left out where there is nothing to read."""
+"""Every cell of ``BENCHMARK.json`` resolves by name, with every metric
+that lists it, and the two layer metrics of the staging cache's
+residency by column read the counters they name — or are left out
+where there is nothing to read."""
 
 import dataclasses
 import json
@@ -15,6 +16,16 @@ CELL = "sf10_q1_resident"
 NEW = ["stage_hit_share.pass", "stage_evictions_per_stmt.pass"]
 
 
+def _bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _file(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
 @pytest.fixture(scope="module")
 def cell():
     return discovery.load_cell(ROOT, CELL)
@@ -24,23 +35,42 @@ def _obs(**counters):
     return {"counters": counters, "stmts": 4}
 
 
-def test_the_cell_its_configuration_and_traffic_load(cell):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entry = next(c for c in bench["configs"] if c["name"] == cell.config_name)
-    assert cell.config_name == "tpch_sf10_1chip_resident" and cell.chips == 1
-    assert (cell.config["catalog"], cell.config["schema"]) == ("tpch", "sf10")
-    assert entry["reduced"] == cell.config["reduced"] == ["scale"]
-    assert set(cell.config["reduced_how"]) == {"scale"}
-    assert cell.traffic_name == "power_q1"
-    assert cell.traffic == dict(cell.traffic, loop="closed_pass", clients=1,
-                                statements=["q1"], param_sets=4, warm_passes=2)
-    assert list(cell.statement_paths) == ["q1"]
-    assert [m["name"] for m in cell.end_to_end] == ["setup_s", "pass_s.p50"]
-    names = [m.name for m in cell.per_layer]
-    assert names[-2:] == NEW and len(names) == 19
-    # every metric of a whole pass lists the cell
-    assert all(CELL in m["workloads"] for m in bench["per_layer"])
+@pytest.mark.parametrize("name", [w["name"] for w in _bench()["workloads"]])
+def test_a_cell_its_configuration_traffic_loop_and_statements_load(name):
+    """Every expectation comes from the cell's own entry and files, so a
+    PR that adds a cell adds a case here and edits nothing."""
+    bench = _bench()
+    entry = next(w for w in bench["workloads"] if w["name"] == name)
+    config = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    cell = discovery.load_cell(ROOT, name)
+    assert (cell.config_name, cell.chips) == (entry["config"], entry["chips"])
+    assert cell.config == _file(config["file"])
+    assert config["reduced"] == cell.config["reduced"]
+    assert set(cell.config.get("reduced_how", {})) == set(config["reduced"])
+    assert cell.traffic_name == entry["traffic"]
+    assert cell.traffic == _file(bench["paths"][0], "traffic", entry["traffic"] + ".json")
+    # the loop and every statement of a pass are files that load
+    statements = cell.traffic["statements"]
+    assert list(cell.statement_paths) == statements and statements
+    assert set(cell.statements()) == set(statements)
+    assert all(hasattr(m, "sql") and hasattr(m, "params") for m in cell.statements().values())
+    loop = cell.traffic["loop"]
+    assert os.path.basename(cell.loop_path) == loop + ".py" and cell.loop().VARIANT
+    # set-up warms at least one whole pass, and the pools hold a set or more
+    assert cell.traffic.get("warm_passes", 1) >= 1 and cell.traffic["param_sets"] >= 1
+    # the cell reports setup_s and another end-to-end metric
+    e2e = [m["name"] for m in bench["end_to_end"]
+           if "workloads" not in m or name in m["workloads"]]
+    assert [m["name"] for m in cell.end_to_end] == e2e
+    assert "setup_s" in e2e and len(e2e) >= 2
+    # every metric that lists the cell resolved to a file and a reader, and no other did
+    listed = [m for m in bench["per_layer"] if "workloads" not in m or name in m["workloads"]]
+    assert [m.name for m in cell.per_layer] == [m["name"] for m in listed] and listed
+    for got, m in zip(cell.per_layer, listed):
+        assert got.spec["moves"][loop] == m["moves"] and m["moves"] in e2e
+        assert got.reader_path or "read" in got.spec
+    # the stage_* pair is found by name, and listed together or not at all
+    assert [m["name"] for m in listed if m["name"] in NEW] in (NEW, [])
 
 
 @pytest.mark.parametrize("counters,want", [
