@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import datetime
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -652,25 +652,29 @@ class _TpchMetadata(ConnectorMetadata):
         n = counts[handle.table]
         pk = self.PRIMARY_KEYS[handle.table]
 
-        def key_max(table: str) -> int:
-            # orderkeys are sparse (8 of every 32): domain max != rowcount
+        def key_range(table: str) -> Tuple[int, int]:
+            # the specification numbers nations and regions from 0,
+            # every other key from 1; orderkeys are sparse (8 of every
+            # 32): domain max != rowcount
+            if table in ("nation", "region"):
+                return 0, counts[table] - 1
             if table == "orders":
-                return int(_orderkey(np.asarray([counts["orders"] - 1]))[0])
-            return counts[table]
+                return 1, int(_orderkey(np.asarray([counts["orders"] - 1]))[0])
+            return 1, counts[table]
 
         cols: Dict[str, ColumnStats] = {}
         for name in TABLE_SCHEMAS[handle.table]:
             if len(pk) == 1 and name == pk[0]:
+                lo, hi = key_range(handle.table)
                 cols[name] = ColumnStats(
-                    distinct_count=n, min_value=1, max_value=key_max(handle.table)
+                    distinct_count=n, min_value=lo, max_value=hi
                 )
             elif name in self.FOREIGN_KEYS:
                 ref_table = self.FOREIGN_KEYS[name]
                 ref = counts[ref_table]
+                lo, hi = key_range(ref_table)
                 cols[name] = ColumnStats(
-                    distinct_count=min(ref, n),
-                    min_value=1,
-                    max_value=key_max(ref_table),
+                    distinct_count=min(ref, n), min_value=lo, max_value=hi
                 )
             elif name == "l_linenumber":
                 # closed form: 1..7 lines per order

@@ -22,6 +22,7 @@ plan-time placeholder, never a runtime value.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -1816,6 +1817,7 @@ class LocalQueryRunner:
         pages: List[Page],
         stats_out: Optional[List] = None,
         fetch_result: bool = True,
+        cut_agg: bool = False,
     ) -> Page:
         """Run the compiled whole-plan program, retrying on capacity
         overflow: :meth:`_resolve`, :meth:`_dispatch`, :meth:`_collect`
@@ -1830,7 +1832,7 @@ class LocalQueryRunner:
         fetched (one round trip) — and the return value is
         ``(device_page_rebucketed, n)`` instead of a host page."""
         resolved = self._resolve(
-            root, scans, analyzed=stats_out is not None
+            root, scans, analyzed=stats_out is not None, cut_agg=cut_agg
         )
         return self._collect(
             self._dispatch(resolved, pages),
@@ -1845,6 +1847,7 @@ class LocalQueryRunner:
         analyzed: bool = False,
         prog: Optional[N.PlanNode] = None,
         batches: int = 1,
+        cut_agg: bool = False,
     ) -> "_Resolved":
         """What is the same for every batch a task runs through
         ``root``: the canonical root and its fingerprint, the parameter
@@ -1854,7 +1857,10 @@ class LocalQueryRunner:
         dispatch several ``batches`` gets the parameter vector on the
         device: handed over from the host, each of its scalars is a
         transfer of its own at every call (0.8 ms of a 2.3 ms Q1 call
-        on the chip; the one put costs about as much: PERF.md §7)."""
+        on the chip; the one put costs about as much: PERF.md §7).
+        ``cut_agg``: ``root`` is the partial step of a cut aggregation
+        run over one split batch, so each page it returns is counted
+        (``DEVICE.count_agg_page``)."""
         from presto_tpu.plan import canonical
 
         scan_ids = {id(s): i for i, s in enumerate(scans)}
@@ -1952,6 +1958,7 @@ class LocalQueryRunner:
             msgs_cell=msgs_cell,
             nodes_cell=nodes_cell,
             fresh=fresh,
+            cut_agg=cut_agg,
         )
 
     def _dispatch(
@@ -1999,6 +2006,7 @@ class LocalQueryRunner:
                 literal = self._resolve(
                     resolved.root, resolved.scans,
                     analyzed=resolved.analyzed, prog=resolved.prog,
+                    cut_agg=resolved.cut_agg,
                 )
                 resolved.__dict__.update(literal.__dict__)
         t_disped = time.perf_counter()
@@ -2084,6 +2092,7 @@ class LocalQueryRunner:
             scaled = self._resolve(
                 _scale_capacities(res.root, 4), res.scans,
                 analyzed=res.analyzed, prog=res.prog,
+                cut_agg=res.cut_agg,
             )
             return self._collect(
                 self._dispatch(scaled, pending.pages),
@@ -2129,6 +2138,8 @@ class LocalQueryRunner:
         # program computed over vs the rows anyone will read
         if DEVICE.enabled:
             DEVICE.count_padding(n, page.capacity)
+            if res.cut_agg:
+                DEVICE.count_agg_page(n, page.capacity)
             self._fold_device_stat(
                 device_pad_rows=page.capacity - n,
                 device_live_rows=n,
@@ -2678,22 +2689,46 @@ def _page_from_prefix(page: Page, prefix_leaves, n: int) -> Page:
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _prefix_program(k: int):
+    """ONE program that cuts every block of a page to its first ``k``
+    rows (``Page.prefix_leaves``). Sliced eagerly, a page cost a device
+    program a leaf, and an exact length a compile of each: Q15 at SF10
+    reads 120 partial pages of 31-34 thousand groups a statement —
+    1,088 small compiles in every process's set-up, some 90 s, and 800
+    of a statement's 935 device programs (my chip runs, PR 35)."""
+    def page_prefix(page):
+        return page.prefix_leaves(k)
+
+    page_prefix.__name__ = f"page_prefix_{k}"
+    return jax.jit(page_prefix)
+
+
 def materialize_page(page: Page, n: int) -> Page:
     """Fetch the live prefix of a (prefix-form) device page to host in
-    ONE batched transfer: slice every block to ``n`` rows on device, then
-    a single ``jax.device_get`` for all of them. Downstream host work
-    (host root stage, wire serialization, to_pylist) then runs on numpy
-    with zero further device round trips.
+    ONE batched transfer: cut every block on the device, in one
+    program, to the power-of-two bucket of its ``n`` live rows (a
+    length among few, so the program is compiled once a bucket and not
+    once a row count; at most twice the live bytes cross the link),
+    then a single ``jax.device_get`` for all of them. Downstream host
+    work (host root stage, wire serialization, to_pylist) then runs on
+    numpy with zero further device round trips.
 
-    Capacity is re-padded host-side to the power-of-two bucket (numpy
-    zeros — far cheaper than the round trip saved) so a materialized
-    page that is fed back into a later program (streamed fragments)
-    still hits the per-bucket compile cache."""
+    Capacity is re-padded host-side to the same bucket (numpy zeros —
+    far cheaper than the round trip saved) so a materialized page that
+    is fed back into a later program (streamed fragments) still hits
+    the per-bucket compile cache."""
     if not page.blocks or page.is_host:
         return page
+    k = bucket_capacity(n)
     with tracing.phase("fetch", site="materialize"):
-        leaves = jax.device_get(page.prefix_leaves(n))
+        if k >= page.capacity:
+            leaves = page.prefix_leaves(page.capacity)
+        else:
+            leaves = _prefix_program(k)(page)
+        leaves = jax.device_get(leaves)
     DEVICE.count_sync()
+    DEVICE.count_d2h(sum(int(getattr(x, "nbytes", 0)) for x in leaves))
     return _page_from_prefix(page, leaves, n)
 
 
@@ -2778,6 +2813,7 @@ class _Resolved:
     msgs_cell: list
     nodes_cell: list
     fresh: bool
+    cut_agg: bool = False
 
 
 @dataclasses.dataclass
@@ -2923,6 +2959,7 @@ def _execute_node_inner(
             node.aggs,
             node.max_groups,
             errors_out=errors,
+            key_ranges=node.key_ranges,
         )
         flags.append(overflow)
         return out
@@ -3145,6 +3182,10 @@ def _scale_capacities(node: N.PlanNode, factor: int) -> N.PlanNode:
             )
     if isinstance(node, (N.AggregationNode, N.DistinctNode)):
         changes["max_groups"] = node.max_groups * factor
+    if isinstance(node, N.AggregationNode) and node.key_ranges:
+        # the overflow may be a key outside its stated range
+        # (ops.aggregation._packed_key): run again with nothing stated
+        changes["key_ranges"] = ()
     if (
         isinstance(node, (N.JoinNode, N.CrossJoinNode, N.UnnestNode))
         and node.out_capacity is not None

@@ -168,8 +168,10 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
     batch = min(
         int(runner.session.get("page_capacity")), max_rows
     )
+    # the cut aggregation's page is sized where it meets its batch
+    # (ops.aggregation._out_capacity): no more slots than the batch has
+    # rows, here as in the served worker
     batch_cap = bucket_capacity(batch)
-    worker_root = _cap_cut_groups(worker_root, batch_cap)
     part_scan = list(N.walk(worker_root))[stage.partition_scan]
     n_buckets = _n_buckets_for(stage.partition_rows, max_rows)
     key_names = _bucket_key_names(worker_root)
@@ -187,6 +189,7 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
         elif n is not part_scan:
             base_pages[id(n)] = runner._load_table(n)
 
+    cut_agg = isinstance(worker_root, (N.AggregationNode, N.DistinctNode))
     spill: List[List[tuple]] = [[] for _ in range(n_buckets)]
     # fixed capacity: every batch (incl. the tail) reuses ONE compiled
     # partial-fragment program; prefetch staging overlaps batch N+1's
@@ -202,7 +205,9 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
             batch_page if n is part_scan else base_pages[id(n)]
             for n in leaves
         ]
-        out = runner._run_with_pages(worker_root, leaves, pages)
+        out = runner._run_with_pages(
+            worker_root, leaves, pages, cut_agg=cut_agg
+        )
         part_payload, _, nrows = _page_to_payload(out)
         if nrows == 0:
             continue
@@ -289,8 +294,7 @@ def merge_spilled_buckets(
         if bucket_root is None:
             outs.append(_page_to_payload(page))
             continue
-        broot = _cap_cut_groups(bucket_root, page.capacity)
-        out = runner._run_with_pages(broot, [frag_remote], [page])
+        out = runner._run_with_pages(bucket_root, [frag_remote], [page])
         pl = _page_to_payload(out)
         if pl[2]:
             outs.append(pl)
@@ -366,29 +370,6 @@ def _split_final(
         path[: j + 1], bucket_root, rest_remote
     )
     return bucket_root, rest_root, remote, rest_remote
-
-
-def _cap_cut_groups(root: N.PlanNode, cap: int) -> N.PlanNode:
-    """Rebind the cut agg/distinct's max_groups to the batch/bucket
-    capacity: distinct groups in a batch can never exceed its rows, so
-    this is always sufficient (no overflow retries on the stream)."""
-    if isinstance(root, (N.AggregationNode, N.DistinctNode)):
-        return dataclasses.replace(root, max_groups=cap)
-    target = next(
-        (
-            n
-            for n in N.walk(root)
-            if isinstance(n, (N.AggregationNode, N.DistinctNode))
-            and isinstance(n.source, N.RemoteSourceNode)
-        ),
-        None,
-    )
-    if target is None:
-        return root
-    path = _path_to(root, target)
-    return _replace_on_path(
-        path[:-1], target, dataclasses.replace(target, max_groups=cap)
-    )
 
 
 def _bucket_key_names(worker_root: N.PlanNode) -> List[str]:

@@ -19,17 +19,31 @@ but cost minutes of compile; one-hot reduction and cumsum are ~10ms):
   keys together; every accumulator is then a *scan*, not a scatter:
   sums/counts are inclusive-cumsum differences at group boundaries,
   min/max are segmented associative scans read at group ends.
-- Shapes stay static, and each path sizes its output from what it
-  knows. The planner supplies ``max_groups``, a bucket of its ROW
-  estimate (half the source's rows: 2^24 for Q1 at SF10, which has four
-  groups). The sorted path has nothing better — unbounded keys carry no
-  proof — so its output is ``max_groups`` long; kernels report overflow
-  instead of reallocating, and the host re-runs at a bigger bucket on
-  overflow (SURVEY.md §7 "Hard parts: dynamic shapes"). The one-hot
-  path holds a proof of its key domain (``nseg`` <= 256 segments), so it
-  builds its output at that domain's bucket,
-  ``min(max_groups, bucket_capacity(nseg))``, whatever the planner
-  estimated. The global path emits one row.
+- **packed sort** (PR 35): where every key's values are proved to lie
+  in a range — a dictionary's length, a boolean, or the inclusive
+  ``(lo, hi)`` the connector's column statistics state
+  (``AggregationNode.key_ranges``) — and the composite fits 32 bits,
+  the keys pack into ONE uint32 with dead rows as its largest value.
+  One two-operand sort then does what a lane-by-lane ``lexsort`` over
+  emulated int64 did (2.0 against 20.3 ms for 2^20 rows on the v5e,
+  ``chiprun_out/pr33/micro_q15.json``), the sorted key itself says
+  which rows live and where groups start (no gather of the key and
+  mask columns, 17.4 and 10.9 ms), and a second such sort compacts the
+  group starts (against 73.5 ms for ``nonzero``'s scatter). The
+  accumulators are the sorted path's own, so both give the same page.
+- Shapes stay static, and ONE rule sizes a grouped aggregation's page
+  (``_out_capacity``), applied here, where the aggregation meets the
+  page it is bound to, for every caller alike: no more slots than the
+  planner's ``max_groups`` (a bucket of its ROW estimate: half the
+  source's rows, 2^24 for a scan of ``lineitem`` at SF10), no more than
+  the input page has row slots (a 2^20-row split batch cannot hold more
+  groups than rows), and no more than the keys' proved domain holds
+  (Q1: 1,024 for four groups; Q15 at SF10: 2^17 for 100,000
+  suppliers). Kernels report overflow instead of reallocating, and the
+  host re-runs at a bigger ``max_groups`` on overflow (SURVEY.md §7
+  "Hard parts: dynamic shapes"): only the planner's bucket can
+  overflow, the other two bounds are proofs. The global path emits one
+  row.
 
 Aggregate functions: count(*), count(x), sum, min, max, avg. Null
 semantics match SQL: aggregates skip nulls; count(*) counts rows;
@@ -71,6 +85,9 @@ from presto_tpu.ops.common import (
     sort_order,
 )
 from presto_tpu.page import Block, Page, nonzero_1d
+
+#: the packed sort key of a dead row: past every composite
+_DEAD = 0xFFFFFFFF
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,24 +184,50 @@ def _static_domain(e: Expr, lowerer: ExprLowerer) -> Optional[int]:
     return None
 
 
+def _out_capacity(max_groups: int, rows: int, proved: Optional[int]) -> int:
+    """Slots of a grouped aggregation's output page — the one rule
+    (module docstring): the planner's bucket, capped by the bucket of
+    the input page's row slots and by the bucket of the key domain
+    where one is ``proved``. A ``bucket_capacity`` bucket throughout,
+    the capacity ``materialize_page`` re-pads a fetched prefix to."""
+    from presto_tpu.exec.staging import bucket_capacity
+
+    cap = min(max_groups, bucket_capacity(rows))
+    if proved is not None:
+        cap = min(cap, bucket_capacity(proved))
+    return cap
+
+
 def hash_aggregate(
     page: Page,
     group_keys: Sequence[Tuple[str, Expr]],
     aggs: Sequence[AggCall],
     max_groups: int,
     errors_out: Optional[List] = None,
+    key_ranges: Sequence[Optional[Tuple[int, int]]] = (),
 ) -> Tuple[Page, jnp.ndarray]:
     """Group ``page`` by key expressions, compute aggregates.
 
     Returns (result_page, overflow) where overflow is a traced bool: True
-    when the data had more than ``max_groups`` groups (host must re-run
-    with a larger bucket; surplus groups were dropped).
+    when the data had more groups than the planner's ``max_groups``
+    allows (surplus groups were dropped), or a key left the range
+    ``key_ranges`` states for it (its row went to another group's
+    slot). Either way the host runs the batch again, at a bigger bucket
+    and with nothing stated (``local_runner._scale_capacities``). The
+    page has ``_out_capacity`` slots.
 
     ``errors_out``, when given, collects ``(message, traced_bool)`` hard
     errors — currently the bigint-sum overflow trap of the sorted path
     (the reference raises on per-group bigint overflow; the sorted path's
     page-wide running total would otherwise wrap *silently* even when
     individual group sums are in range — see _sorted_one_agg).
+
+    ``key_ranges`` (``AggregationNode.key_ranges``): per key the
+    inclusive ``(lo, hi)`` the connector's statistics state for its
+    values, or None. A statement, checked against every batch: a
+    connector whose statistics went stale costs a second run, never an
+    answer. A caller that passes ranges re-runs without them on
+    overflow.
 
     Global aggregation (no keys) is the plain-reduction degenerate case.
     """
@@ -195,31 +238,51 @@ def hash_aggregate(
         return _global_aggregate(page, aggs, live, lowerer)
 
     keys = [(name, *lowerer.eval(e), e) for name, e in group_keys]
+    rows = page.capacity
 
-    domains = [_static_domain(e, lowerer) for _, _, _, e in keys]
     if any(a.func in _ORDER_FUNCS for a in aggs):
         # these need the sorted layout (array_agg: group spans ARE the
         # output arrays; percentile/min_by/max_by: a per-group value
-        # ordering); skip the one-hot fast path
+        # ordering, by a second sort over the keys as they are); skip
+        # the one-hot and packed paths
         return _sorted_aggregate(
             page, keys, aggs, max_groups, live, lowerer, errors_out
         )
-    if all(d is not None for d in domains):
+
+    domains = [_static_domain(e, lowerer) for _, _, _, e in keys]
+    ranges = list(key_ranges) + [None] * (len(keys) - len(key_ranges))
+    # per key: (smallest value, how many values) it is proved to take
+    proofs = [
+        (0, dom) if dom is not None
+        else (rng[0], rng[1] - rng[0] + 1) if rng is not None
+        else None
+        for dom, rng in zip(domains, ranges)
+    ]
+    nseg = None
+    if all(p is not None for p in proofs):
         slots = [
-            d + (1 if v is not None else 0)
-            for d, (_, _, v, _) in zip(domains, keys)
+            max(n + (1 if v is not None else 0), 1)
+            for (_, n), (_, _, v, _) in zip(proofs, keys)
         ]
         nseg = 1
-        for s in slots:
-            nseg *= max(s, 1)
-        if 0 < nseg <= _ONEHOT_MAX_SEGMENTS:
-            return _onehot_aggregate(
-                page, keys, domains, slots, nseg, aggs, max_groups,
-                live, lowerer,
-            )
+        for sl in slots:
+            nseg *= sl
+    out_cap = _out_capacity(max_groups, rows, nseg)
 
+    if (
+        nseg is not None and nseg <= _ONEHOT_MAX_SEGMENTS
+        and all(d is not None for d in domains)
+    ):
+        return _onehot_aggregate(
+            page, keys, domains, slots, nseg, aggs, max_groups, out_cap,
+            live, lowerer,
+        )
+    packed = None
+    if nseg is not None and nseg <= _DEAD:
+        packed = _packed_key(keys, proofs, slots, domains, live)
     return _sorted_aggregate(
-        page, keys, aggs, max_groups, live, lowerer, errors_out
+        page, keys, aggs, max_groups, live, lowerer, errors_out,
+        out_cap=out_cap, packed=packed,
     )
 
 
@@ -234,6 +297,7 @@ def _onehot_aggregate(
     nseg: int,
     aggs: Sequence[AggCall],
     max_groups: int,
+    out_cap: int,
     live: jnp.ndarray,
     lowerer: ExprLowerer,
 ) -> Tuple[Page, jnp.ndarray]:
@@ -244,23 +308,19 @@ def _onehot_aggregate(
     order-preserving); a key's NULL slot is its largest id (nulls group
     last, matching the sorted path's NULLS LAST grouping order).
 
-    The output page is ``min(max_groups, bucket_capacity(nseg))`` long:
-    there are at most ``nseg`` groups by construction (dead rows match no
-    column), so nothing here is allocated, scanned, scattered or gathered
-    at ``max_groups``. Sized by the planner's bucket instead, Q1's
-    partial stage at SF10 built 2^24-slot pages for four groups: 17.6 ms
-    of ``reduce-window`` (the compaction's cumsum over the slots) and
-    13 ms of copies a 2^20-row batch, 1.73 GB of output of which 105 KB
-    was fetched, 2.1 s of device time a statement where 41 ms do
-    (PERF.md §6, PR 30). The capacity stays a ``bucket_capacity``
-    bucket, the one ``materialize_page`` re-pads a fetched prefix to.
+    The output page is ``out_cap`` long, which ``_out_capacity`` holds
+    to the bucket of ``nseg``: there are at most ``nseg`` groups by
+    construction (dead rows match no column), so nothing here is
+    allocated, scanned, scattered or gathered at ``max_groups``. Sized
+    by the planner's bucket instead, Q1's partial stage at SF10 built
+    2^24-slot pages for four groups: 17.6 ms of ``reduce-window`` (the
+    compaction's cumsum over the slots) and 13 ms of copies a 2^20-row
+    batch, 1.73 GB of output of which 105 KB was fetched, 2.1 s of
+    device time a statement where 41 ms do (PERF.md §6, PR 30).
     ``overflow`` can only be true where a caller passes ``max_groups``
     under ``nseg``; the first ``max_groups`` groups are kept then.
     """
-    from presto_tpu.exec.staging import bucket_capacity
-
     cap = page.capacity
-    out_cap = min(max_groups, bucket_capacity(nseg))
 
     strides = []
     s = 1
@@ -420,21 +480,153 @@ def _segmented_scan_reduce(
 
 
 def _group_spans(
-    bnd: jnp.ndarray, max_groups: int, cap: int
+    starts: jnp.ndarray, cap: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(starts, ends) sorted-space positions per group (gather-safe).
+    """(starts, ends) sorted-space positions per group (gather-safe),
+    from the groups' first positions with ``cap`` in the slots past the
+    last group.
 
     ``ends[i] = starts[i+1] - 1`` with cap-1 for the final/fill groups —
     safe because rows past the live prefix carry neutral values for every
     accumulator (0 for cumsum deltas, +-inf fills for min/max scans).
     """
-    starts = nonzero_1d(bnd, max_groups, cap)
     nxt = jnp.concatenate(
         [starts[1:], jnp.full((1,), cap, starts.dtype)]
     )
     ends = jnp.clip(nxt - 1, 0, cap - 1)
     safe_starts = jnp.minimum(starts, cap - 1).astype(jnp.int32)
     return safe_starts, ends.astype(jnp.int32)
+
+
+def _packed_key(keys, proofs, slots, domains, live):
+    """The group keys of every row as ONE uint32, ordered as the sorted
+    path orders groups: the first key most significant, a key's NULL
+    slot its largest value (nulls group last), dead rows ``_DEAD``,
+    past every composite. ``proofs``: per key ``(lo, n)``, the smallest
+    value and the number of values it may take; ``slots``: ``n`` plus
+    the NULL slot where the key is nullable. Returns ``(key, proofs,
+    slots, stale)``.
+
+    A key proved by a dictionary or a type cannot leave its domain. One
+    whose range the connector's statistics state (``domains`` has None
+    for it) is held to them: ``stale`` is true where a live, non-null
+    value lies outside ``[lo, lo + n)`` — it would land in another
+    group's slot, so the caller reports the batch as overflowed and it
+    runs again with nothing stated."""
+    gid = jnp.zeros(live.shape, jnp.uint32)
+    stale = jnp.asarray(False)
+    for (name, d, v, e), (lo, n), sl, dom in zip(
+        keys, proofs, slots, domains
+    ):
+        d = jnp.broadcast_to(d, live.shape)
+        if dom is None:
+            wide = d.astype(jnp.int64)
+            outside = live & ((wide < lo) | (wide >= lo + n))
+            if v is not None:
+                outside = outside & v
+            stale = stale | jnp.any(outside)
+            comp = (wide - lo).astype(jnp.uint32)
+        else:
+            comp = d.astype(jnp.uint32)
+        if v is not None:
+            comp = jnp.where(v, comp, jnp.uint32(n))
+        gid = gid * jnp.uint32(sl) + comp
+    gid = jnp.where(live, gid, jnp.uint32(_DEAD))
+    return gid, proofs, slots, stale
+
+
+def _unpack_keys(key_g: jnp.ndarray, keys, proofs, slots, lowerer):
+    """The key blocks of the groups whose packed keys are ``key_g``:
+    ``_packed_key`` read backwards, so no key column is gathered."""
+    strides, stride = [], 1
+    for sl in reversed(slots):
+        strides.append(stride)
+        stride *= sl
+    blocks = []
+    for (name, d, v, e), (lo, n), sl, stride in zip(
+        keys, proofs, slots, reversed(strides)
+    ):
+        comp = key_g
+        if stride > 1:
+            comp = comp // jnp.uint32(stride)
+        if len(slots) > 1:
+            comp = comp % jnp.uint32(sl)
+        data = comp if lo == 0 else comp.astype(jnp.int64) + lo
+        blocks.append(
+            Block(
+                data=data.astype(jnp.asarray(d).dtype),
+                valid=None if v is None else comp != jnp.uint32(n),
+                dtype=e.dtype,
+                dictionary=(
+                    lowerer.dictionary_of(e) if e.dtype.is_string else None
+                ),
+            )
+        )
+    return blocks
+
+
+def _packed_groups(gid: jnp.ndarray, out_cap: int, carried=()):
+    """Sort rows by their packed key: ``(order, key_s, live_s, bnd,
+    num_groups, starts, carried_s)`` as the sorted path has them — the
+    permutation (stable, so a group's rows keep their order), the
+    sorted key, which sorted rows live, where a group starts, the
+    groups' first positions with the capacity in the slots past the
+    last group, and the ``carried`` columns in sorted order. Two sorts
+    of one uint32 key: the first takes the columns along as payloads —
+    a payload costs the sort little, a gather of 2^20 int64 by the
+    permutation cost 17.4 ms on the v5e
+    (``chiprun_out/pr33/micro_q15.json``; 20.5 ms a batch in Q15's
+    trace, PERF.md §6, PR 35) —, the second brings the rows that start
+    a group to the front in order, which is what ``nonzero`` computes
+    with a scatter."""
+    cap = gid.shape[0]
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    key_s, order, *carried_s = lax.sort(
+        (gid, iota, *carried), num_keys=1, is_stable=True
+    )
+    live_s = key_s != jnp.uint32(_DEAD)
+    prev = jnp.concatenate([key_s[:1], key_s[:-1]])
+    bnd = live_s & ((iota == 0) | (key_s != prev))
+    num_groups = jnp.sum(bnd).astype(jnp.int32)
+    _, first = lax.sort(
+        (jnp.where(bnd, 0, 1).astype(jnp.uint32), iota),
+        num_keys=1, is_stable=True,
+    )
+    if out_cap > cap:
+        first = jnp.concatenate(
+            [first, jnp.full((out_cap - cap,), cap, jnp.int32)]
+        )
+    starts = jnp.where(
+        jnp.arange(out_cap, dtype=jnp.int32) < num_groups,
+        first[:out_cap], cap,
+    )
+    return order, key_s, live_s, bnd, num_groups, starts, carried_s
+
+
+def _carried_args(aggs, cap: int, lowerer: ExprLowerer):
+    """The aggregates' arguments that ride the packed sort: per
+    aggregate with an integer-typed argument (ints, dates, scaled
+    decimals, dictionary ids, booleans) the positions of its data and
+    its validity among the ``columns`` to carry. Floats and long
+    decimals are gathered by the permutation as before."""
+    columns, where, seen = [], {}, {}
+    for i, agg in enumerate(aggs):
+        if agg.arg is None:
+            continue
+        if agg.arg in seen:  # sum(x) and count(x) share x
+            where[i] = seen[agg.arg]
+            continue
+        d, v = lowerer.eval(agg.arg)
+        d = jnp.broadcast_to(d, (cap,) + jnp.shape(d)[1:])
+        if d.ndim != 1 or jnp.issubdtype(d.dtype, jnp.floating):
+            continue
+        at = [len(columns), None]
+        columns.append(d.astype(jnp.uint8) if d.dtype == jnp.bool_ else d)
+        if v is not None:
+            at[1] = len(columns)
+            columns.append(jnp.broadcast_to(v, (cap,)).astype(jnp.uint8))
+        where[i] = seen[agg.arg] = (at[0], at[1], d.dtype)
+    return columns, where
 
 
 def _sorted_aggregate(
@@ -445,39 +637,71 @@ def _sorted_aggregate(
     live: jnp.ndarray,
     lowerer: ExprLowerer,
     errors_out: Optional[List] = None,
+    out_cap: Optional[int] = None,
+    packed: Optional[tuple] = None,
 ) -> Tuple[Page, jnp.ndarray]:
+    """Sort rows by key, then every accumulator is a scan read at the
+    groups' spans. ``packed`` (``_packed_key``) takes the packed sort;
+    without it the keys sort lane by lane as they are. Both bring the
+    same rows together in the same order, so the page is the same.
+    ``out_cap``: the page's slots where the caller knows a proved
+    domain; ``_out_capacity`` without one."""
     cap = page.capacity
-    order = sort_order(
-        [(d, v, e.dtype) for _, d, v, e in keys], live
-    )
-    live_s = live[order]
-    keys_s = [
-        (name, d[order], None if v is None else v[order], e)
-        for name, d, v, e in keys
-    ]
-    bnd = boundaries([(d, v) for _, d, v, _ in keys_s], live_s)
-    num_groups = jnp.sum(bnd).astype(jnp.int32)
-    overflow = num_groups > max_groups
+    if out_cap is None:
+        out_cap = _out_capacity(max_groups, cap, None)
+    sorted_args = {}  # aggregate -> its argument, already in sorted order
+    if packed is not None:
+        gid, proofs, slots, stale = packed
+        columns, where = _carried_args(aggs, cap, lowerer)
+        order, key_s, live_s, bnd, num_groups, first, columns = (
+            _packed_groups(gid, out_cap, columns)
+        )
+        for i, (at_d, at_v, dtype) in where.items():
+            d = columns[at_d]
+            sorted_args[i] = (
+                d != 0 if dtype == jnp.bool_ else d,
+                None if at_v is None else columns[at_v] != 0,
+            )
+    else:
+        order = sort_order(
+            [(d, v, e.dtype) for _, d, v, e in keys], live
+        )
+        live_s = live[order]
+        keys_s = [
+            (name, d[order], None if v is None else v[order], e)
+            for name, d, v, e in keys
+        ]
+        bnd = boundaries([(d, v) for _, d, v, _ in keys_s], live_s)
+        num_groups = jnp.sum(bnd).astype(jnp.int32)
+        first = nonzero_1d(bnd, out_cap, cap)
+        stale = False
+    overflow = (num_groups > max_groups) | stale
 
-    starts, ends = _group_spans(bnd, max_groups, cap)
+    starts, ends = _group_spans(first, cap)
 
     names: List[str] = []
     blocks: List[Block] = []
-    for name, d, v, e in keys_s:
-        names.append(name)
-        dictionary = None
-        if e.dtype.is_string:
-            dictionary = lowerer.dictionary_of(e)
-        blocks.append(
-            Block(
-                data=d[starts],
-                valid=None if v is None else v[starts],
-                dtype=e.dtype,
-                dictionary=dictionary,
-            )
+    if packed is not None:
+        blocks.extend(
+            _unpack_keys(key_s[starts], keys, proofs, slots, lowerer)
         )
+        names.extend(name for name, _, _, _ in keys)
+    else:
+        for name, d, v, e in keys_s:
+            names.append(name)
+            dictionary = None
+            if e.dtype.is_string:
+                dictionary = lowerer.dictionary_of(e)
+            blocks.append(
+                Block(
+                    data=d[starts],
+                    valid=None if v is None else v[starts],
+                    dtype=e.dtype,
+                    dictionary=dictionary,
+                )
+            )
 
-    for agg in aggs:
+    for i, agg in enumerate(aggs):
         if agg.func in ("approx_percentile", "min_by", "max_by"):
             blk = _order_stat_agg(
                 agg, page, keys, live, starts, ends, lowerer
@@ -485,7 +709,7 @@ def _sorted_aggregate(
         else:
             blk = _sorted_one_agg(
                 agg, page, order, live_s, bnd, starts, ends, lowerer,
-                errors_out,
+                errors_out, arg_s=sorted_args.get(i),
             )
         names.append(agg.out_name)
         blocks.append(blk)
@@ -578,9 +802,11 @@ def _cumsum_span(
     w: jnp.ndarray, starts: jnp.ndarray, ends: jnp.ndarray
 ) -> jnp.ndarray:
     """Per-group totals of ``w`` via inclusive cumsum differenced over
-    [start, end] spans (no scatter)."""
+    [start, end] spans (no scatter): the running total at the span's
+    end less the one before its start, two gathers a span (each 2.7 ms
+    at 2^17 slots on the v5e, ``chiprun_out/pr33/micro_q15.json``)."""
     c = cumsum(w)
-    return c[ends] - c[starts] + w[starts]
+    return c[ends] - (c - w)[starts]
 
 
 def _sorted_one_agg(
@@ -593,7 +819,12 @@ def _sorted_one_agg(
     ends: jnp.ndarray,
     lowerer: ExprLowerer,
     errors_out: Optional[List] = None,
+    arg_s: Optional[tuple] = None,
 ) -> Block:
+    """One aggregate over rows in sorted order. ``arg_s``: its
+    argument's ``(data, valid)`` where they are in sorted order already
+    (they rode the packed sort); evaluated and gathered by ``order``
+    otherwise."""
     rt = agg.result_type()
 
     if agg.func == "count_star":
@@ -645,18 +876,31 @@ def _sorted_one_agg(
             offsets=offsets,
         )
 
-    d, v = lowerer.eval(agg.arg)
-    d = jnp.broadcast_to(d, (page.capacity,))[order]
-    valid_s = live_s if v is None else (
-        live_s & jnp.broadcast_to(v, (page.capacity,))[order]
-    )
+    if arg_s is None:
+        d, v = lowerer.eval(agg.arg)
+        d = jnp.broadcast_to(d, (page.capacity,))[order]
+        if v is not None:
+            v = jnp.broadcast_to(v, (page.capacity,))[order]
+    else:
+        d, v = arg_s
+    valid_s = live_s if v is None else (live_s & v)
 
     if agg.func == "count":
         data = _cumsum_span(valid_s.astype(jnp.int64), starts, ends)
         return Block(data=data, valid=None, dtype=T.BIGINT)
 
-    cnt = _cumsum_span(valid_s.astype(jnp.int64), starts, ends)
-    group_has_value = cnt > 0
+    if v is None and agg.func in ("sum", "min", "max"):
+        # an argument that is never NULL: a group has a value because it
+        # has a row, so no count is scanned and gathered to say so (a
+        # cumsum and two gathers at the page's slots: some 7 ms of a
+        # 2^20-row batch on the v5e)
+        cnt = None
+        group_has_value = (
+            jnp.arange(starts.shape[0], dtype=jnp.int32) < jnp.sum(bnd)
+        )
+    else:
+        cnt = _cumsum_span(valid_s.astype(jnp.int64), starts, ends)
+        group_has_value = cnt > 0
 
     if agg.func in _VARIANCE_FUNCS:
         at = agg.arg.dtype
@@ -694,10 +938,21 @@ def _sorted_one_agg(
             # the check must be per group — a float64 shadow of the same
             # span difference. A real per-group overflow displaces the
             # int result ~2^64 from the shadow; float cancellation error
-            # stays many orders below the 2^62 threshold.
-            sf = _cumsum_span(x.astype(jnp.float64), starts, ends)
-            wrapped = jnp.any(
-                jnp.abs(s.astype(jnp.float64) - sf) > 2.0**62
+            # stays many orders below the 2^62 threshold. The shadow (a
+            # float64 scan and two gathers) runs only for a page whose
+            # magnitudes add up to 2^62 or more: under that no group of
+            # it can overflow, and a sum of magnitudes is one reduction.
+            xf = x.astype(jnp.float64)
+
+            def shadow():
+                sf = _cumsum_span(xf, starts, ends)
+                return jnp.any(
+                    jnp.abs(s.astype(jnp.float64) - sf) > 2.0**62
+                )
+
+            wrapped = lax.cond(
+                jnp.sum(jnp.abs(xf)) < 2.0**62,
+                lambda: jnp.asarray(False), shadow,
             )
             errors_out.append(
                 (f"bigint sum overflow in {agg.out_name}", wrapped)

@@ -92,6 +92,26 @@ class AggregationNode(PlanNode):
     group_keys: Tuple[Tuple[str, Expr], ...]
     aggs: Tuple[AggCall, ...]
     max_groups: int = 1 << 16  # capacity bucket; optimizer refines by stats
+    #: per group key, the inclusive ``(lo, hi)`` its values lie in by
+    #: the connector's column statistics (``optimizer.key_ranges``), or
+    #: None where nothing is proved; ``()`` proves nothing. The kernel
+    #: sorts proved keys as one uint32 and sizes its page by the range
+    #: (``ops.aggregation.hash_aggregate``).
+    key_ranges: Tuple[Optional[Tuple[int, int]], ...] = ()
+
+    def __repr__(self):
+        # the repr is the fingerprint that names the compiled program,
+        # in this process and in the persistent compile cache: a node
+        # that proves nothing reads as it did before the field existed
+        proved = (
+            f", key_ranges={self.key_ranges!r}"
+            if any(r is not None for r in self.key_ranges) else ""
+        )
+        return (
+            f"AggregationNode(source={self.source!r}, "
+            f"group_keys={self.group_keys!r}, aggs={self.aggs!r}, "
+            f"max_groups={self.max_groups!r}{proved})"
+        )
 
     def output_schema(self):
         out = {n: e.dtype for n, e in self.group_keys}

@@ -72,6 +72,28 @@ def _column_stats(node: N.PlanNode, col: str, catalogs):
     return None
 
 
+def key_ranges(source: N.PlanNode, group_keys, catalogs) -> tuple:
+    """Per group key of an aggregation over ``source``, the inclusive
+    ``(lo, hi)`` the connector's column statistics state for it, or
+    None: ``AggregationNode.key_ranges``. Only a plain integer or date
+    column read through to its scan qualifies, the rule
+    ``Planner._pack_composite_keys`` packs join keys by: a filter or a
+    join above the scan can only narrow a column's values. ``()`` when
+    no key has a range."""
+    out = []
+    for _, e in group_keys:
+        cs = None
+        if isinstance(e, E.ColumnRef) and (
+            e.dtype.is_integer or e.dtype.name == "date"
+        ):
+            cs = _column_stats(source, e.name, catalogs)
+        if cs is None or cs.min_value is None or cs.max_value is None:
+            out.append(None)
+        else:
+            out.append((int(cs.min_value), int(cs.max_value)))
+    return tuple(out) if any(r is not None for r in out) else ()
+
+
 def _conjuncts_of(e: E.Expr) -> List[E.Expr]:
     if isinstance(e, E.And):
         out: List[E.Expr] = []

@@ -2325,11 +2325,15 @@ class _Planner:
             for a in distinct_aggs:
                 arg = self._lower(a.args[0], scope)
                 dcol = self._fresh("dist")
+                pre_keys = tuple(group_keys) + ((dcol, arg),)
                 pre = N.AggregationNode(
                     source=node,
-                    group_keys=tuple(group_keys) + ((dcol, arg),),
+                    group_keys=pre_keys,
                     aggs=(),
                     max_groups=self._agg_bucket(node),
+                    key_ranges=optimizer.key_ranges(
+                        node, pre_keys, self.catalogs
+                    ),
                 )
                 out_name = self._fresh("agg")
                 post = N.AggregationNode(
@@ -2456,6 +2460,9 @@ class _Planner:
             group_keys=tuple(group_keys),
             aggs=tuple(aggs),
             max_groups=self._agg_bucket(node) if group_keys else 1,
+            key_ranges=optimizer.key_ranges(
+                node, group_keys, self.catalogs
+            ),
         )
         if any_composed:
             projs: List[Tuple[str, E.Expr]] = [

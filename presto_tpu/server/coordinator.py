@@ -3654,7 +3654,11 @@ class CoordinatorServer:
             )
             if bucketed is not None:
                 return bucketed
-            merged = pages_wire.merge_payloads(payloads, schema)
+            # the root stage's merge of the tasks' partial pages: host
+            # work (a concatenation a column, dictionaries united) that
+            # is `exec` time; the site names it in a span profile
+            with tracing.phase("exec", site="merge_partials"):
+                merged = pages_wire.merge_payloads(payloads, schema)
             page = stage_page(merged, schema)
             # the final plan may contain real scans above the cut (e.g.
             # a join against another table after the final aggregation)

@@ -163,7 +163,11 @@ def _try_cut(
                 source=remote,
                 group_keys=fkeys,
                 aggs=faggs,
+                # the planner's bucket and the keys' proved ranges ride
+                # along; what the merge's page is sized by is decided
+                # where it meets its input (ops.aggregation)
                 max_groups=cut.max_groups,
+                key_ranges=cut.key_ranges,
             )
             if post:
                 final_sub = N.ProjectNode(

@@ -1083,7 +1083,10 @@ class WorkerServer:
             ) * 1000.0
             return page, release
 
-        resolved = self.runner._resolve(root, scans, batches=len(ranges))
+        resolved = self.runner._resolve(
+            root, scans, batches=len(ranges),
+            cut_agg=isinstance(root, (N.AggregationNode, N.DistinctNode)),
+        )
 
         def exec_batch(split_page, release):
             pages = [

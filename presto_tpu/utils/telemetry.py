@@ -118,6 +118,9 @@ class DeviceTelemetry:
         self._live = REGISTRY.counter("device.live_rows")
         self._program_out = REGISTRY.counter("device.program_out_bytes")
         self._syncs = REGISTRY.counter("device.syncs")
+        # pages of partial groups a cut aggregation emits, batch by batch
+        self._agg_rows = REGISTRY.counter("device.agg_partial_rows")
+        self._agg_slots = REGISTRY.counter("device.agg_out_slots")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -157,6 +160,17 @@ class DeviceTelemetry:
         second)."""
         if self.enabled:
             self._syncs.update()
+
+    def count_agg_page(self, rows: int, slots: int) -> None:
+        """One page of partial groups a cut aggregation emitted for a
+        batch: its live ``rows`` (the page's row count, among the
+        control outputs the batch's one read brings anyway) and its
+        static ``slots`` (a shape): nothing more is read from the
+        device. Their ratio says how well the page is sized
+        (ops.aggregation._out_capacity)."""
+        if self.enabled:
+            self._agg_rows.update(int(rows))
+            self._agg_slots.update(int(slots))
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -230,6 +244,10 @@ class DeviceTelemetry:
         fragment program's output page (beside ``d2h_bytes``, what of
         it was fetched), ``device_syncs`` the blocking fetches that
         brought it (local_runner._collect, materialize_page).
+        ``agg_partial_rows`` / ``agg_out_slots`` sum the live rows and
+        the static slots of the pages of partial groups that cut
+        aggregations emitted, one a split batch (the served worker's
+        scan task and the runner's own stream).
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -252,6 +270,8 @@ class DeviceTelemetry:
             "live_rows": int(self._live.total),
             "program_out_bytes": int(self._program_out.total),
             "device_syncs": int(self._syncs.total),
+            "agg_partial_rows": int(self._agg_rows.total),
+            "agg_out_slots": int(self._agg_slots.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

@@ -128,6 +128,24 @@ def _case_aggregate(one_chip, mesh):
     )
 
 
+def _case_aggregate_packed(one_chip, mesh):
+    """The packed sort (bigint and date keys with stated ranges): the
+    uint32 composite, the two single-key sorts, the keys unpacked, the
+    same accumulators, and the range check among the error flags."""
+    aggs = [AggCall("sum", _col("dec"), "s1"), AggCall("count_star", None, "c")]
+    keys = [(c, _col(c)) for c in ("i", "d")]
+
+    def fn(p):
+        errors = []
+        out, overflow = hash_aggregate(
+            p, keys, aggs, max_groups=1 << 22, errors_out=errors,
+            key_ranges=((1, 100_000), (9_000, 10_000)),
+        )
+        return out, overflow, [flag for _, flag in errors]
+
+    return jax.jit(fn), (_spec(_page(SORT_CAP, ("i", "d", "dec")), one_chip),)
+
+
 def _case_join(one_chip, mesh):
     def fn(probe, build):
         return hash_join(
@@ -183,6 +201,7 @@ CASES = {
     "filter_project[every type]": _case_filter_project,
     "sort[double desc, dictionary keys; long-decimal payload]": _case_sort,
     "hash_aggregate[double, date keys]": _case_aggregate,
+    "hash_aggregate[packed bigint, date keys]": _case_aggregate_packed,
     "hash_join[double key]": _case_join,
     "partition_exchange[2x2 mesh]": _case_partition_exchange,
 }

@@ -358,6 +358,7 @@ _TELEMETRY_CALLS = {
     "count_compile": {_TELEMETRY, _RUNNER},
     "count_program_out": {_TELEMETRY, _RUNNER},
     "count_sync": {_TELEMETRY, _RUNNER},
+    "count_agg_page": {_TELEMETRY, _RUNNER},
     "count_h2d": {_TELEMETRY, _STAGING},
     "count_d2h": {_TELEMETRY, _RUNNER, _STAGING, _EXCHANGE_SPI},
     "count_padding": {_TELEMETRY, _RUNNER, _STAGING},
