@@ -140,6 +140,24 @@ def lexsort_u32(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return perm
 
 
+def sort_u32_lanes(
+    lanes: Sequence[jnp.ndarray], payloads: Sequence[jnp.ndarray] = ()
+) -> Tuple[List[jnp.ndarray], List[jnp.ndarray]]:
+    """Rows sorted stably by uint32 lanes (last lane primary, as
+    :func:`lexsort_u32`), returned as the sorted lanes and payloads
+    themselves. Each pass still compares ONE key; the other lanes and
+    the payloads ride it as operands, so no ``lane[perm]`` gather runs
+    between the passes and none after them (on the v5e a gather of 2^20
+    rows costs ten such passes: PERF.md §6, PRs 35 and 36)."""
+    ops = list(lanes) + list(payloads)
+    for i in range(len(lanes)):
+        out = jax.lax.sort(
+            (ops[i], *ops[:i], *ops[i + 1:]), num_keys=1, is_stable=True
+        )
+        ops = [*out[1:i + 1], out[0], *out[i + 1:]]
+    return ops[:len(lanes)], ops[len(lanes):]
+
+
 def argsort_i64(x: jnp.ndarray) -> jnp.ndarray:
     """Stable argsort of one int64 key (int32 permutation), as two
     uint32 passes — see :func:`lexsort_u32`."""

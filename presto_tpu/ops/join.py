@@ -6,14 +6,25 @@ Reference parity: ``HashBuilderOperator`` -> ``PagesIndex`` ->
 — the two-pipeline build/probe split of SURVEY.md §3.3.
 
 TPU-first redesign (SURVEY.md §7 step 3): no pointer-chasing hash table.
-The build side is *sorted by key* once (XLA sort), and every probe row
-finds its match range with two vectorized ``searchsorted`` binary
-searches — a batched, branch-free probe that keeps the VPU lanes full.
+The build side is *sorted by key* (XLA sort), and every probe row finds
+its match range ``[lo, hi)`` in that order by RANK, not by search
+(``_match_ranges``): build and probe keys are sorted together, build
+rows leading every tie, and a running count of the build rows is the
+range — three sort passes, a cumsum and a cummax, no loop and no gather.
+The binary search it replaced (two ``jnp.searchsorted`` a join) is a
+loop of log2(n) rounds, each a gather of one emulated-int64 key a probe
+row, and on the v5e a gather is the dear primitive: ~28 ns an element,
+17-20 ms for 2^20 rows, where a sort pass over as many rows with its
+payloads takes 1.1-1.7 ms (PERF.md §6, PRs 35 and 36). Q3 at SF1 made
+516 M such gathers a statement — its four ``while`` loops were 14.7 s of
+the 18.5 s the device worked (ledger, PR 35, ``sf1_join``). For the
+same reason a unique build's output passes the probe's columns through
+instead of gathering them by an iota (XLA keeps that gather).
 Duplicate build keys become [lo, hi) ranges; the output expansion is the
-classic prefix-sum + inverse-searchsorted trick, entirely static-shape:
-the planner supplies ``out_capacity`` and the kernel reports overflow
-(host re-runs at a bigger bucket), mirroring the engine-wide
-capacity-bucket protocol (SURVEY.md §7 "Hard parts").
+classic prefix-sum + inverse-rank trick (the same ``_match_ranges``),
+entirely static-shape: the planner supplies ``out_capacity`` and the
+kernel reports overflow (host re-runs at a bigger bucket), mirroring the
+engine-wide capacity-bucket protocol (SURVEY.md §7 "Hard parts").
 
 Keys are single int64 columns; the planner packs two int32-representable
 key columns bijectively via ``pack_keys`` (wider composites: future
@@ -28,10 +39,16 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from presto_tpu import types as T
-from presto_tpu.ops.common import argsort_i64, orderable_i64
+from presto_tpu.ops.common import (
+    _u32_lanes,
+    argsort_i64,
+    orderable_i64,
+    sort_u32_lanes,
+)
 from presto_tpu.page import Block, Page
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
@@ -97,6 +114,38 @@ def _mask_out(page: Page, keep: jnp.ndarray) -> Page:
     )
 
 
+def _match_ranges(
+    build_keys: jnp.ndarray, probe_keys: jnp.ndarray
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """For every probe key its range ``[lo, hi)`` among the build keys
+    in sorted order: exactly ``searchsorted(sorted(build_keys),
+    probe_keys, "left")`` and ``(..., "right")``, int32 — found by
+    ranking, not by searching (the module docstring says why). No loop,
+    no gather; the build keys need not arrive sorted.
+
+    Both sides are concatenated, build first, and sorted stably by key
+    with their origin index riding along, so the build rows lead every
+    run of equal keys. The running count of build rows is then ``hi``
+    at every probe row, and the count before the run of its key — the
+    running maximum of the counts at the runs' first rows, which never
+    decrease — is ``lo``. A sort on the origin brings both back to
+    probe order (a sort, not a scatter: a scatter costs what a gather
+    costs)."""
+    nb = build_keys.shape[0]
+    keys = jnp.concatenate([build_keys, probe_keys]).astype(jnp.int64)
+    origin = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    (low, high), (origin,) = sort_u32_lanes(
+        _u32_lanes(keys, False), (origin,)
+    )
+    is_build = (origin < nb).astype(jnp.int32)
+    hi = jnp.cumsum(is_build)
+    change = (low[1:] != low[:-1]) | (high[1:] != high[:-1])
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), change])
+    lo = jax.lax.cummax(jnp.where(first, hi - is_build, 0))
+    _, lo, hi = jax.lax.sort((origin, lo, hi), num_keys=1)
+    return lo[nb:], hi[nb:]
+
+
 def hash_join(
     probe: Page,
     build: Page,
@@ -136,18 +185,19 @@ def hash_join(
     pk, p_ok = _key_of(probe, probe_keys)
     bk, b_ok = _key_of(build, build_keys)
 
-    # sort build by key; unmatchable rows carry the sentinel and sort last
-    b_sort_key = jnp.where(b_ok, bk, _I64_MAX)
-    b_order = argsort_i64(b_sort_key)
-    bk_s = b_sort_key[b_order]
-    nb = jnp.sum(b_ok).astype(jnp.int32)
+    with jax.named_scope("join_build"):
+        # sort build by key; unmatchable rows carry the sentinel and
+        # sort last
+        b_sort_key = jnp.where(b_ok, bk, _I64_MAX)
+        b_order = argsort_i64(b_sort_key)
+        nb = jnp.sum(b_ok).astype(jnp.int32)
 
-    pk_eff = jnp.where(p_ok, pk, _I64_MAX)
-    lo = jnp.searchsorted(bk_s, pk_eff, side="left")
-    hi = jnp.searchsorted(bk_s, pk_eff, side="right")
-    lo = jnp.minimum(lo, nb)
-    hi = jnp.minimum(hi, nb)
-    m = jnp.where(p_ok, hi - lo, 0)  # matches per probe row
+    with jax.named_scope("join_probe"):
+        pk_eff = jnp.where(p_ok, pk, _I64_MAX)
+        lo, hi = _match_ranges(b_sort_key, pk_eff)
+        lo = jnp.minimum(lo, nb)
+        hi = jnp.minimum(hi, nb)
+        m = jnp.where(p_ok, hi - lo, 0)  # matches per probe row
 
     if join_type == "semi":
         return _mask_out(probe, m > 0), jnp.asarray(False)
@@ -155,70 +205,78 @@ def hash_join(
         keep = (m == 0) & probe.row_mask()
         return _mask_out(probe, keep), jnp.asarray(False)
 
-    if build_unique:
-        # PK side: m in {0,1}; output row i <-> probe row i (static!)
-        matched = m > 0
-        b_idx = b_order[jnp.clip(lo, 0, build.capacity - 1)]
+    with jax.named_scope("join_output"):
+        if build_unique:
+            # PK side: m in {0,1}; output row i IS probe row i (static!), so
+            # the probe's columns pass through and only the build's payload
+            # is gathered
+            matched = m > 0
+            b_idx = b_order[jnp.clip(lo, 0, build.capacity - 1)]
+            out = _join_output(
+                probe,
+                build,
+                None,
+                b_idx,
+                matched,
+                build_payload,
+                payload_rename,
+                left_outer=(join_type in ("left", "full")),
+            )
+            if join_type == "inner":
+                keep = matched & probe.row_mask()
+                return _mask_out(out, keep), jnp.asarray(False)
+            # left/full outer keep every probe row: positional layout, so
+            # the probe's own liveness (mask or prefix) carries over
+            out = dataclasses.replace(out, live=probe.live)
+            if join_type == "full":
+                out = _append_unmatched_build(
+                    out, probe, build, pk_eff, p_ok, bk, b_ok,
+                    build_payload, payload_rename,
+                )
+            return out, jnp.asarray(False)
+
+        # general duplicate-capable expansion
+        if out_capacity is None:
+            raise ValueError(
+                "non-unique inner/left join requires out_capacity"
+            )
+        m_eff = jnp.maximum(m, 1) if join_type in ("left", "full") else m
+        m_eff = jnp.where(probe.row_mask(), m_eff, 0)
+        total = jnp.cumsum(m_eff)
+        out_count = (
+            total[-1] if probe.capacity else jnp.asarray(0, jnp.int64)
+        )
+        overflow = out_count > out_capacity
+
+        j = jnp.arange(out_capacity, dtype=jnp.int64)
+        _, p_idx = _match_ranges(total, j)  # searchsorted(..., "right")
+        p_idx = jnp.minimum(p_idx, probe.capacity - 1)
+        prev = jnp.where(p_idx > 0, total[jnp.maximum(p_idx - 1, 0)], 0)
+        offset = j - prev
+        row_m = m[p_idx]
+        matched = row_m > 0
+        b_pos = lo[p_idx] + jnp.minimum(offset, jnp.maximum(row_m - 1, 0))
+        b_idx = b_order[jnp.clip(b_pos, 0, build.capacity - 1)]
         out = _join_output(
             probe,
             build,
-            jnp.arange(probe.capacity),
+            p_idx,
             b_idx,
             matched,
             build_payload,
             payload_rename,
             left_outer=(join_type in ("left", "full")),
         )
-        if join_type == "inner":
-            keep = matched & probe.row_mask()
-            return _mask_out(out, keep), jnp.asarray(False)
-        # left/full outer keep every probe row: positional layout, so
-        # the probe's own liveness (mask or prefix) carries over
-        out = dataclasses.replace(out, live=probe.live)
+        out = dataclasses.replace(
+            out,
+            num_valid=jnp.minimum(out_count, out_capacity).astype(jnp.int32),
+        )
         if join_type == "full":
             out = _append_unmatched_build(
                 out, probe, build, pk_eff, p_ok, bk, b_ok,
                 build_payload, payload_rename,
             )
-        return out, jnp.asarray(False)
-
-    # general duplicate-capable expansion
-    if out_capacity is None:
-        raise ValueError("non-unique inner/left join requires out_capacity")
-    m_eff = jnp.maximum(m, 1) if join_type in ("left", "full") else m
-    m_eff = jnp.where(probe.row_mask(), m_eff, 0)
-    total = jnp.cumsum(m_eff)
-    out_count = total[-1] if probe.capacity else jnp.asarray(0, jnp.int64)
-    overflow = out_count > out_capacity
-
-    j = jnp.arange(out_capacity, dtype=jnp.int64)
-    p_idx = jnp.searchsorted(total, j, side="right")
-    p_idx = jnp.minimum(p_idx, probe.capacity - 1)
-    prev = jnp.where(p_idx > 0, total[jnp.maximum(p_idx - 1, 0)], 0)
-    offset = j - prev
-    row_m = m[p_idx]
-    matched = row_m > 0
-    b_pos = lo[p_idx] + jnp.minimum(offset, jnp.maximum(row_m - 1, 0))
-    b_idx = b_order[jnp.clip(b_pos, 0, build.capacity - 1)]
-    out = _join_output(
-        probe,
-        build,
-        p_idx,
-        b_idx,
-        matched,
-        build_payload,
-        payload_rename,
-        left_outer=(join_type in ("left", "full")),
-    )
-    out = dataclasses.replace(
-        out, num_valid=jnp.minimum(out_count, out_capacity).astype(jnp.int32)
-    )
-    if join_type == "full":
-        out = _append_unmatched_build(
-            out, probe, build, pk_eff, p_ok, bk, b_ok,
-            build_payload, payload_rename,
-        )
-    return out, overflow
+        return out, overflow
 
 
 def cross_join(
@@ -240,7 +298,7 @@ def cross_join(
     overflow = out_count > out_capacity
 
     j = jnp.arange(out_capacity, dtype=jnp.int64)
-    p_idx = jnp.searchsorted(total, j, side="right")
+    _, p_idx = _match_ranges(total, j)  # searchsorted(..., "right")
     p_idx = jnp.minimum(p_idx, left.capacity - 1)
     prev = jnp.where(p_idx > 0, total[jnp.maximum(p_idx - 1, 0)], 0)
     b_idx = jnp.clip(j - prev, 0, right_c.capacity - 1)
@@ -292,15 +350,12 @@ def _append_unmatched_build(
     appended after the left-outer section with NULL probe columns. The
     result is a masked-form Page (section 1's liveness concatenated
     with the unmatched-build mask) — zero gathers."""
-    # membership of each build key among the live probe keys, by binary
-    # search in the sorted probe keys; matches beyond the live count are
-    # sentinel slots, not real keys — clip like the main probe path does
-    pk_sorted = jnp.where(p_ok, pk_eff, _I64_MAX)
-    pk_sorted = pk_sorted[argsort_i64(pk_sorted)]
-    n_live = jnp.sum(p_ok)
-    lo = jnp.minimum(jnp.searchsorted(pk_sorted, bk, side="left"), n_live)
-    hi = jnp.minimum(jnp.searchsorted(pk_sorted, bk, side="right"), n_live)
-    matched_b = b_ok & (hi > lo)
+    # membership of each build key among the live probe keys: its range
+    # in their sorted order; matches beyond the live count are sentinel
+    # slots, not real keys — clip like the main probe path does
+    n_live = jnp.sum(p_ok).astype(jnp.int32)
+    lo, hi = _match_ranges(pk_eff, bk)
+    matched_b = b_ok & (jnp.minimum(hi, n_live) > jnp.minimum(lo, n_live))
     keep_b = build.row_mask() & ~matched_b
 
     rename = payload_rename or {}
@@ -349,13 +404,17 @@ def _append_unmatched_build(
 def _join_output(
     probe: Page,
     build: Page,
-    p_idx: jnp.ndarray,
+    p_idx: Optional[jnp.ndarray],
     b_idx: jnp.ndarray,
     matched: jnp.ndarray,
     build_payload: Sequence[str],
     payload_rename: dict,
     left_outer: bool,
 ) -> Page:
+    """Output row j = probe row ``p_idx[j]`` (row j itself where
+    ``p_idx`` is None: the blocks pass through, nothing is gathered — XLA
+    does not fold a gather by an iota into a copy) beside the payload of
+    build row ``b_idx[j]``."""
     for name in list(probe.names) + list(build_payload):
         src = probe if name in probe.names else build
         blk = src.block(name)
@@ -372,13 +431,13 @@ def _join_output(
     blocks: List[Block] = []
     for name in probe.names:
         blk = probe.block(name)
-        blocks.append(
-            dataclasses.replace(
+        if p_idx is not None:
+            blk = dataclasses.replace(
                 blk,
                 data=blk.data[p_idx],
                 valid=None if blk.valid is None else blk.valid[p_idx],
             )
-        )
+        blocks.append(blk)
         names.append(name)
     for name in build_payload:
         blk = build.block(name)

@@ -161,6 +161,31 @@ def _case_join(one_chip, mesh):
     )
 
 
+def _case_join_ranked(join_type, unique):
+    """The probe that ranks by sort, on an int64 key: the three-operand
+    sorts of ``_match_ranges`` (two lanes and the origin; the origin
+    with lo and hi) at ``BUILD_CAP + SORT_CAP`` rows, and for a
+    duplicate build once more over the output slots, for FULL once more
+    from the build's side."""
+
+    def case(one_chip, mesh):
+        def fn(probe, build):
+            return hash_join(
+                probe, build, ["i"], ["i"], join_type,
+                build_payload=["dec", "b"],
+                payload_rename={"dec": "b_dec", "b": "b_b"},
+                build_unique=unique,
+                out_capacity=None if unique else SORT_CAP,
+            )
+
+        return jax.jit(fn), (
+            _spec(_page(SORT_CAP, ("i", "dbl")), one_chip),
+            _spec(_page(BUILD_CAP, ("i", "dec", "b")), one_chip),
+        )
+
+    return case
+
+
 def _case_partition_exchange(one_chip, mesh):
     """The mesh executor's REPARTITION and REPLICATE under shard_map on
     the 2x2 topology: hash over a DOUBLE, a dictionary and an int64
@@ -203,6 +228,9 @@ CASES = {
     "hash_aggregate[double, date keys]": _case_aggregate,
     "hash_aggregate[packed bigint, date keys]": _case_aggregate_packed,
     "hash_join[double key]": _case_join,
+    "hash_join[unique build]": _case_join_ranked("inner", True),
+    "hash_join[duplicate build, left]": _case_join_ranked("left", False),
+    "hash_join[duplicate build, full]": _case_join_ranked("full", False),
     "partition_exchange[2x2 mesh]": _case_partition_exchange,
 }
 
