@@ -56,6 +56,7 @@ from presto_tpu.ops import (
     unnest as unnest_op,
     window as window_op,
 )
+from presto_tpu.ops.aggregation import record_row_moves
 from presto_tpu.ops.join import JoinBuild, cut_build, join_build
 from presto_tpu.page import (
     Block,
@@ -1484,14 +1485,15 @@ class LocalQueryRunner:
         answer. ``None`` = the exact scalar program, 5-tuple, with
         bit-identical full-capacity output.
 
-        The fourth cell returned lists the slots of each join build the
-        program sorts itself (its build side is no prepared leaf:
-        :meth:`prepare_builds`), filled when it is traced."""
+        The fourth cell returned (``_Tally``) holds the slots of each
+        join build the program sorts itself (its build side is no
+        prepared leaf: :meth:`prepare_builds`) and the row moves of its
+        grouped aggregations, filled when it is traced."""
         from presto_tpu.plan import canonical
 
         msgs_cell: List[str] = []
         nodes_cell: List = []
-        builds_cell: List[int] = []
+        tally = _Tally()
 
         def trace(
             pages_in,
@@ -1500,7 +1502,7 @@ class LocalQueryRunner:
             _ids=cscan_ids,
             _m=msgs_cell,
             _n=nodes_cell,
-            _b=builds_cell,
+            _t=tally,
         ):
             flags: List = []
             errors: List = []
@@ -1508,12 +1510,14 @@ class LocalQueryRunner:
                 [] if counted else None
             )
             dyn: List = []
-            _b.clear()
+            _t.builds.clear()
             with canonical.active_params(params_in):
-                out = _execute_node(
-                    _root, pages_in, _ids, flags, errors,
-                    counters, dyn, count_all=analyzed, builds=_b,
-                )
+                with record_row_moves() as moves:
+                    out = _execute_node(
+                        _root, pages_in, _ids, flags, errors,
+                        counters, dyn, count_all=analyzed, builds=_t.builds,
+                    )
+                _t.agg_row_moves = moves[0]
                 # program boundary: host materialization /
                 # exchanges need prefix form (lazy selection
                 # masks stop here). num_valid is the TRUE live
@@ -1600,7 +1604,7 @@ class LocalQueryRunner:
                 return base
             return base + (jnp.asarray(true_n, jnp.int32),)
 
-        return trace, msgs_cell, nodes_cell, builds_cell
+        return trace, msgs_cell, nodes_cell, tally
 
     # ------------------------------------------------ micro-batched serving
 
@@ -1742,7 +1746,7 @@ class LocalQueryRunner:
             entry = self._compiled.get(key)
             fresh = entry is None
             if fresh:
-                trace, msgs_cell, nodes_cell, builds_cell = (
+                trace, msgs_cell, nodes_cell, tally = (
                     self._make_trace(
                         croot, cscan_ids, counted, False,
                         out_capacity=spec,
@@ -1751,7 +1755,7 @@ class LocalQueryRunner:
                 batched = canonical.vmap_program(trace)
                 batched.__name__ = _program_name(croot, cfp)
                 entry = (
-                    jax.jit(batched), msgs_cell, nodes_cell, builds_cell
+                    jax.jit(batched), msgs_cell, nodes_cell, tally
                 )
                 self._compiled[key] = entry
         REGISTRY.counter(
@@ -1991,15 +1995,13 @@ class LocalQueryRunner:
             entry = self._compiled.get(key)
             fresh = entry is None
             if fresh:
-                trace, msgs_cell, nodes_cell, builds_cell = (
+                trace, msgs_cell, nodes_cell, tally = (
                     self._make_trace(croot, cscan_ids, counted, analyzed)
                 )
                 trace.__name__ = _program_name(croot, cfp)
-                entry = (
-                    jax.jit(trace), msgs_cell, nodes_cell, builds_cell
-                )
+                entry = (jax.jit(trace), msgs_cell, nodes_cell, tally)
                 self._compiled[key] = entry
-        fn, msgs_cell, nodes_cell, builds_cell = entry
+        fn, msgs_cell, nodes_cell, tally = entry
         if batches > 1 and params:
             with self._device_scope():
                 params = stage_params(params)
@@ -2020,7 +2022,7 @@ class LocalQueryRunner:
             fn=fn,
             msgs_cell=msgs_cell,
             nodes_cell=nodes_cell,
-            builds_cell=builds_cell,
+            tally=tally,
             fresh=fresh,
             cut_agg=cut_agg,
         )
@@ -2082,9 +2084,11 @@ class LocalQueryRunner:
             compile_ms = (t_disped - t_disp) * 1000.0 if fresh else 0.0
             DEVICE.count_dispatch()
             DEVICE.count_program_out(_static_page_nbytes(page))
-            # the builds the program sorts itself, once a dispatch
-            for slots in resolved.builds_cell:
+            # the builds the program sorts itself and the rows its
+            # aggregations move, once a dispatch
+            for slots in resolved.tally.builds:
                 DEVICE.count_join_build(slots)
+            DEVICE.count_agg_row_moves(resolved.tally.agg_row_moves)
             if fresh:
                 DEVICE.count_compile(compile_ms)
             self._fold_device_stat(
@@ -2895,9 +2899,20 @@ class _Resolved:
     fn: object
     msgs_cell: list
     nodes_cell: list
-    builds_cell: list
+    tally: "_Tally"
     fresh: bool
     cut_agg: bool = False
+
+
+@dataclasses.dataclass
+class _Tally:
+    """What a program's trace counted (``LocalQueryRunner._make_trace``)
+    for ``_dispatch`` to add at each dispatch: the slots of each join
+    build the program sorts itself, and the page-sized row moves of its
+    grouped aggregations (``ops.aggregation.record_row_moves``)."""
+
+    builds: List[int] = dataclasses.field(default_factory=list)
+    agg_row_moves: int = 0
 
 
 @dataclasses.dataclass
@@ -3280,7 +3295,7 @@ def _scale_capacities(node: N.PlanNode, factor: int) -> N.PlanNode:
         changes["max_groups"] = node.max_groups * factor
     if isinstance(node, N.AggregationNode) and node.key_ranges:
         # the overflow may be a key outside its stated range
-        # (ops.aggregation._packed_key): run again with nothing stated
+        # (ops.aggregation._key_lanes): run again with nothing stated
         changes["key_ranges"] = ()
     if (
         isinstance(node, (N.JoinNode, N.CrossJoinNode, N.UnnestNode))

@@ -15,22 +15,33 @@ but cost minutes of compile; one-hot reduction and cumsum are ~10ms):
   one-hot key matrix — XLA fuses it into a single pass, no sort, no
   scatter. TPU analogue of the reference's array-based
   ``BigintGroupByHash`` fast path.
-- **sorted path**: general keys — one stable multi-key sort brings equal
-  keys together; every accumulator is then a *scan*, not a scatter:
-  sums/counts are inclusive-cumsum differences at group boundaries,
-  min/max are segmented associative scans read at group ends.
-- **packed sort** (PR 35): where every key's values are proved to lie
-  in a range — a dictionary's length, a boolean, or the inclusive
-  ``(lo, hi)`` the connector's column statistics state
-  (``AggregationNode.key_ranges``) — and the composite fits 32 bits,
-  the keys pack into ONE uint32 with dead rows as its largest value.
-  One two-operand sort then does what a lane-by-lane ``lexsort`` over
-  emulated int64 did (2.0 against 20.3 ms for 2^20 rows on the v5e,
-  ``chiprun_out/pr33/micro_q15.json``), the sorted key itself says
-  which rows live and where groups start (no gather of the key and
-  mask columns, 17.4 and 10.9 ms), and a second such sort compacts the
-  group starts (against 73.5 ms for ``nonzero``'s scatter). The
-  accumulators are the sorted path's own, so both give the same page.
+- **sorted path**: every other grouping, and no row moves by a
+  permutation. The keys become uint32 lanes packed as tightly as their
+  proofs allow (``_key_lanes``): a key whose values are proved to lie in
+  a range — a dictionary's length, a boolean, or the inclusive ``(lo,
+  hi)`` the connector's column statistics state
+  (``AggregationNode.key_ranges``, checked every batch) — is a digit of
+  a mixed-radix lane shared with its neighbours while their product
+  fits; any other key takes the one or two lanes of its stored width.
+  One stable single-key sort a lane (``common.sort_u32_lanes``) carries
+  the other lanes and every aggregate's argument along; a group starts
+  and ends where any lane differs from its neighbour; every accumulator
+  is a *scan* over the sorted rows (running totals for counts and
+  integer sums, segmented scans for min/max and float sums); and ONE
+  more sort, of the flag of the rows that end a group, brings them to
+  the front in group order with the lanes and the scans riding — the
+  groups' keys are unpacked from their lanes, their totals are the
+  differences of consecutive running totals. On the v5e a gather of
+  2^20 int64 by a permutation costs 17.4 ms and ``nonzero``'s scatter
+  47.6 ms, a sort pass with its payloads 1.1-1.7 ms (PERF.md §6); Q3's
+  lane-by-lane aggregation spent 1.8 s of its 2.5 s of device time a
+  statement at SF1 in those gathers and that scatter (PERF.md §5).
+- **lane-by-lane branch** (``_ordered_aggregate``): ``array_agg`` and
+  the order statistics (``_ORDER_FUNCS``) alone, which need the sort's
+  permutation and the groups' spans themselves: ``common.sort_order``,
+  keys and arguments gathered by the permutation, the group starts by
+  ``nonzero_1d``. Every such move is counted while a program traces
+  (``record_row_moves``; ``device.agg_row_moves`` at each dispatch).
 - Shapes stay static, and ONE rule sizes a grouped aggregation's page
   (``_out_capacity``), applied here, where the aggregation meets the
   page it is bound to, for every caller alike: no more slots than the
@@ -56,39 +67,44 @@ int64, sum(double)->double, count->bigint, avg->double (deviation: the
 reference returns decimal for decimal inputs; exact decimal avg lands
 with int128), min/max preserve the input type.
 
-Exactness note: decimal/bigint sums on the sorted path are inclusive
-int64 cumsums differenced at boundaries — exact unless the *running
-total over the whole page* exceeds int64, a stricter-than-SQL bound
-(the reference overflows per-group). A traced overflow trap (float64
-shadow cumsum compared against the int64 cumsum; a wrap displaces the
+Exactness note: decimal/bigint sums on the sorted path are differences
+of page-wide int64 running totals read at the groups' last rows — exact
+in modular arithmetic whenever a group's true sum fits int64, even where
+the page's running total wraps. Where a group's sum does not fit, a
+traced per-group float64 shadow (``_sum_wraps``: a wrap displaces the
 value by ~2^64, far beyond float accumulation error) raises through the
-error-flag channel instead of returning silently wrong sums. Float sums
-use per-segment scans (not the cumsum trick) so no cross-group
-cancellation is introduced.
+error-flag channel instead of returning a silently wrong sum. Float
+sums use per-segment scans (not the running-total trick) so no
+cross-group cancellation is introduced.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
+import math
+import operator
+import threading
 from typing import List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 from presto_tpu import types as T
 from presto_tpu.expr import Expr, ExprLowerer
 from presto_tpu.ops.common import (
+    _SIGN32,
+    _is_narrow,
+    _u32_lanes,
     boundaries,
     cumsum,
+    key_lanes,
     lexsort_u32,
     sort_order,
+    sort_u32_lanes,
 )
 from presto_tpu.page import Block, Page, nonzero_1d
-
-#: the packed sort key of a dead row: past every composite
-_DEAD = 0xFFFFFFFF
-
 
 @dataclasses.dataclass(frozen=True)
 class AggCall:
@@ -220,7 +236,7 @@ def hash_aggregate(
     errors — currently the bigint-sum overflow trap of the sorted path
     (the reference raises on per-group bigint overflow; the sorted path's
     page-wide running total would otherwise wrap *silently* even when
-    individual group sums are in range — see _sorted_one_agg).
+    individual group sums are in range — see _sum_wraps).
 
     ``key_ranges`` (``AggregationNode.key_ranges``): per key the
     inclusive ``(lo, hi)`` the connector's statistics state for its
@@ -241,20 +257,21 @@ def hash_aggregate(
     rows = page.capacity
 
     if any(a.func in _ORDER_FUNCS for a in aggs):
-        # these need the sorted layout (array_agg: group spans ARE the
-        # output arrays; percentile/min_by/max_by: a per-group value
-        # ordering, by a second sort over the keys as they are); skip
-        # the one-hot and packed paths
-        return _sorted_aggregate(
+        # these need the sort's permutation and the groups' spans
+        # (array_agg: the spans ARE the output arrays; percentile /
+        # min_by / max_by: a per-group value ordering, by a second sort
+        # over the keys as they are)
+        return _ordered_aggregate(
             page, keys, aggs, max_groups, live, lowerer, errors_out
         )
 
     domains = [_static_domain(e, lowerer) for _, _, _, e in keys]
     ranges = list(key_ranges) + [None] * (len(keys) - len(key_ranges))
-    # per key: (smallest value, how many values) it is proved to take
+    # per key: (smallest value, how many values, whether only stated) it
+    # is proved to take
     proofs = [
-        (0, dom) if dom is not None
-        else (rng[0], rng[1] - rng[0] + 1) if rng is not None
+        (0, dom, False) if dom is not None
+        else (rng[0], rng[1] - rng[0] + 1, True) if rng is not None
         else None
         for dom, rng in zip(domains, ranges)
     ]
@@ -262,11 +279,9 @@ def hash_aggregate(
     if all(p is not None for p in proofs):
         slots = [
             max(n + (1 if v is not None else 0), 1)
-            for (_, n), (_, _, v, _) in zip(proofs, keys)
+            for (_, n, _), (_, _, v, _) in zip(proofs, keys)
         ]
-        nseg = 1
-        for sl in slots:
-            nseg *= sl
+        nseg = math.prod(slots)
     out_cap = _out_capacity(max_groups, rows, nseg)
 
     if (
@@ -277,12 +292,9 @@ def hash_aggregate(
             page, keys, domains, slots, nseg, aggs, max_groups, out_cap,
             live, lowerer,
         )
-    packed = None
-    if nseg is not None and nseg <= _DEAD:
-        packed = _packed_key(keys, proofs, slots, domains, live)
     return _sorted_aggregate(
         page, keys, aggs, max_groups, live, lowerer, errors_out,
-        out_cap=out_cap, packed=packed,
+        out_cap=out_cap, proofs=proofs,
     )
 
 
@@ -462,6 +474,42 @@ def _onehot_one_agg(
 
 # ---------------------------------------------------------- sorted path
 
+#: the first key lane of a dead row: past every packed composite
+_DEAD = 0xFFFFFFFF
+
+#: trace-time tally of the row moves grouped aggregations issue
+#: (``record_row_moves``)
+_MOVES = threading.local()
+
+
+@contextlib.contextmanager
+def record_row_moves():
+    """Count the page-sized row moves that grouped aggregations issue
+    while a program traces inside the block: each gather by a sort's
+    permutation or at the groups' spans, each ``nonzero_1d``, each
+    scatter of values to their ranks. Yields a one-item list that holds
+    the count. Only the lane-by-lane branch, which ``array_agg`` and the
+    order statistics take, issues any (``device.agg_row_moves``)."""
+    cell = [0]
+    prev = getattr(_MOVES, "cell", None)
+    _MOVES.cell = cell
+    try:
+        yield cell
+    finally:
+        _MOVES.cell = prev
+
+
+def _moved() -> None:
+    cell = getattr(_MOVES, "cell", None)
+    if cell is not None:
+        cell[0] += 1
+
+
+def _take(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``a[idx]``, counted as a row move (``record_row_moves``)."""
+    _moved()
+    return a[idx]
+
 
 def _segmented_scan_reduce(
     x: jnp.ndarray, bnd: jnp.ndarray, op
@@ -479,83 +527,174 @@ def _segmented_scan_reduce(
     return vals
 
 
-def _group_spans(
-    starts: jnp.ndarray, cap: int
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(starts, ends) sorted-space positions per group (gather-safe),
-    from the groups' first positions with ``cap`` in the slots past the
-    last group.
-
-    ``ends[i] = starts[i+1] - 1`` with cap-1 for the final/fill groups —
-    safe because rows past the live prefix carry neutral values for every
-    accumulator (0 for cumsum deltas, +-inf fills for min/max scans).
-    """
-    nxt = jnp.concatenate(
-        [starts[1:], jnp.full((1,), cap, starts.dtype)]
-    )
-    ends = jnp.clip(nxt - 1, 0, cap - 1)
-    safe_starts = jnp.minimum(starts, cap - 1).astype(jnp.int32)
-    return safe_starts, ends.astype(jnp.int32)
+def _diff(c: jnp.ndarray) -> jnp.ndarray:
+    """Running totals read at the groups' last rows, in group order, as
+    each group's own total: the difference of consecutive reads, which
+    is ``c[end] - (c - w)[start]`` (groups are contiguous, the first
+    starts at row 0) and exact in modular int64 arithmetic."""
+    return c - jnp.concatenate([jnp.zeros((1,), c.dtype), c[:-1]])
 
 
-def _packed_key(keys, proofs, slots, domains, live):
-    """The group keys of every row as ONE uint32, ordered as the sorted
-    path orders groups: the first key most significant, a key's NULL
-    slot its largest value (nulls group last), dead rows ``_DEAD``,
-    past every composite. ``proofs``: per key ``(lo, n)``, the smallest
-    value and the number of values it may take; ``slots``: ``n`` plus
-    the NULL slot where the key is nullable. Returns ``(key, proofs,
-    slots, stale)``.
+def _slots(a: jnp.ndarray, out_cap: int) -> jnp.ndarray:
+    """The first ``out_cap`` rows of ``a``, zero-padded where it has
+    fewer."""
+    if out_cap <= a.shape[0]:
+        return a[:out_cap]
+    pad = jnp.zeros((out_cap - a.shape[0],) + a.shape[1:], a.dtype)
+    return jnp.concatenate([a, pad])
 
-    A key proved by a dictionary or a type cannot leave its domain. One
-    whose range the connector's statistics state (``domains`` has None
-    for it) is held to them: ``stale`` is true where a live, non-null
-    value lies outside ``[lo, lo + n)`` — it would land in another
-    group's slot, so the caller reports the batch as overflowed and it
-    runs again with nothing stated."""
-    gid = jnp.zeros(live.shape, jnp.uint32)
+
+@dataclasses.dataclass(frozen=True)
+class _Place:
+    """Where ``_key_lanes`` put one key: a key with a proof ``(lo, n)``
+    is the digit ``factor`` — ``(lane, position)`` — of a packed lane,
+    ``n`` its NULL; any other key has lanes of its own, ``raw``, and
+    where it is nullable the digit ``null_at`` (1 for NULL)."""
+
+    factor: Optional[Tuple[int, int]] = None
+    lo: int = 0
+    n: int = 0
+    raw: Tuple[int, ...] = ()
+    null_at: Optional[Tuple[int, int]] = None
+
+
+def _key_lanes(keys, proofs, live):
+    """The group keys of every row as uint32 lanes, most significant
+    first, in the order the page lists groups: the first key most
+    significant, a key's NULL after its values, dead rows after
+    everything.
+
+    A key with a proof (``proofs``: per key ``(lo, n, stated)`` or
+    None) — a dictionary's or a type's domain, or the inclusive range
+    the connector's statistics state — is a digit of ``n`` values, ``n +
+    1`` where it is nullable (NULL the largest), of a lane packed in
+    mixed radix; neighbouring keys share a lane while the product of
+    their digits stays under ``_DEAD``. Any other key takes its
+    ``_u32_lanes`` (one lane where it is stored in 32 bits or fewer, two
+    otherwise; the value 0 under a NULL) and, where it is nullable, a
+    digit of two in the lane before them. The first lane is always a
+    packed one, of no digit where the first key has lanes of its own,
+    and holds ``_DEAD`` for a dead row.
+
+    Returns ``(lanes, radix, places, stale)``: ``radix`` gives each lane
+    its digits' sizes (None for a key's own lane), ``places`` where each
+    key is (``_Place``), and ``stale`` is true where a live, non-null
+    value of a stated key lies outside its range — it would take
+    another group's slot, so the caller reports the batch as overflowed
+    and it runs again with nothing stated."""
+    shape = live.shape
+    lanes, radix, places = [], [], []
     stale = jnp.asarray(False)
-    for (name, d, v, e), (lo, n), sl, dom in zip(
-        keys, proofs, slots, domains
-    ):
-        d = jnp.broadcast_to(d, live.shape)
-        if dom is None:
+    acc, sizes = None, []
+
+    def close():
+        nonlocal acc, sizes
+        lanes.append(jnp.zeros(shape, jnp.uint32) if acc is None else acc)
+        radix.append(tuple(sizes))
+        acc, sizes = None, []
+
+    def digit(comp, size):
+        nonlocal acc
+        if sizes and math.prod(sizes) * size > _DEAD:
+            close()
+        acc = comp if acc is None else acc * jnp.uint32(size) + comp
+        sizes.append(size)
+        return len(lanes), len(sizes) - 1
+
+    for (name, d, v, e), proof in zip(keys, proofs):
+        d = jnp.broadcast_to(d, shape) if jnp.ndim(d) == 0 else d
+        v = None if v is None else jnp.broadcast_to(v, shape)
+        if proof is not None and proof[2]:
+            lo, n, _ = proof
             wide = d.astype(jnp.int64)
             outside = live & ((wide < lo) | (wide >= lo + n))
             if v is not None:
                 outside = outside & v
             stale = stale | jnp.any(outside)
-            comp = (wide - lo).astype(jnp.uint32)
-        else:
-            comp = d.astype(jnp.uint32)
+        size = None if proof is None else max(proof[1] + (v is not None), 1)
+        if size is not None and size <= _DEAD:
+            lo, n, stated = proof
+            if stated:
+                comp = (d.astype(jnp.int64) - lo).astype(jnp.uint32)
+            else:
+                comp = d.astype(jnp.uint32)
+            if v is not None:
+                comp = jnp.where(v, comp, jnp.uint32(n))
+            places.append(_Place(factor=digit(comp, size), lo=lo, n=n))
+            continue
+        null_at = None
         if v is not None:
-            comp = jnp.where(v, comp, jnp.uint32(n))
-        gid = gid * jnp.uint32(sl) + comp
-    gid = jnp.where(live, gid, jnp.uint32(_DEAD))
-    return gid, proofs, slots, stale
+            null_at = digit(jnp.where(v, 0, 1).astype(jnp.uint32), 2)
+        if sizes or not lanes:
+            close()
+        raw = []
+        narrow = _is_narrow(d, e.dtype)
+        for lane in key_lanes(d, e.dtype):
+            if v is not None:
+                lane = jnp.where(v, lane, 0)
+            for u in reversed(_u32_lanes(lane, narrow)):
+                raw.append(len(lanes))
+                lanes.append(u)
+                radix.append(None)
+        places.append(_Place(raw=tuple(raw), null_at=null_at))
+    if sizes or not lanes:
+        close()
+    lanes[0] = jnp.where(live, lanes[0], jnp.uint32(_DEAD))
+    return lanes, radix, places, stale
 
 
-def _unpack_keys(key_g: jnp.ndarray, keys, proofs, slots, lowerer):
-    """The key blocks of the groups whose packed keys are ``key_g``:
-    ``_packed_key`` read backwards, so no key column is gathered."""
-    strides, stride = [], 1
-    for sl in reversed(slots):
-        strides.append(stride)
-        stride *= sl
+def _digit(lanes, radix, at) -> jnp.ndarray:
+    """The digit ``at`` (``(lane, position)``) of packed lanes."""
+    lane, pos = at
+    comp = lanes[lane]
+    stride = math.prod(radix[lane][pos + 1:])
+    if stride > 1:
+        comp = comp // jnp.uint32(stride)
+    if pos > 0:
+        comp = comp % jnp.uint32(radix[lane][pos])
+    return comp
+
+
+def _from_lanes(us, d: jnp.ndarray, dtype: T.DataType) -> jnp.ndarray:
+    """A key's values from its own uint32 lanes (most significant
+    first): ``_u32_lanes`` and ``key_lanes`` read backwards — an int64
+    bit pattern goes back to float64 by bitcast, the direction the v5e
+    compiles (``common.float_bits_i64``)."""
+    narrow = _is_narrow(d, dtype)
+    wide = []
+    for i in range(0, len(us), 1 if narrow else 2):
+        hi = lax.bitcast_convert_type(
+            us[i] ^ jnp.uint32(_SIGN32), jnp.int32
+        ).astype(jnp.int64)
+        wide.append(hi if narrow else (hi << 32) | us[i + 1].astype(jnp.int64))
+    if dtype.is_long_decimal:
+        hi, lo = wide
+        return jnp.stack([hi, lo ^ jnp.int64(-(2 ** 63))], axis=-1)
+    if dtype.name in ("double", "real"):
+        (o,) = wide  # orderable_i64's map is its own inverse
+        bits = jnp.where(o >= 0, o, (~o) | jnp.int64(-(2 ** 63)))
+        return lax.bitcast_convert_type(bits, jnp.float64)
+    return wide[0]
+
+
+def _unpack_keys(lanes, radix, places, keys, lowerer) -> List[Block]:
+    """The key blocks of the groups whose key lanes are ``lanes``:
+    ``_key_lanes`` read backwards, so no key column is gathered."""
     blocks = []
-    for (name, d, v, e), (lo, n), sl, stride in zip(
-        keys, proofs, slots, reversed(strides)
-    ):
-        comp = key_g
-        if stride > 1:
-            comp = comp // jnp.uint32(stride)
-        if len(slots) > 1:
-            comp = comp % jnp.uint32(sl)
-        data = comp if lo == 0 else comp.astype(jnp.int64) + lo
+    for (name, d, v, e), place in zip(keys, places):
+        if place.factor is not None:
+            comp = _digit(lanes, radix, place.factor)
+            data = comp if place.lo == 0 else comp.astype(jnp.int64) + place.lo
+            valid = None if v is None else comp != jnp.uint32(place.n)
+        else:
+            data = _from_lanes([lanes[i] for i in place.raw], d, e.dtype)
+            valid = None if v is None else (
+                _digit(lanes, radix, place.null_at) == 0
+            )
         blocks.append(
             Block(
                 data=data.astype(jnp.asarray(d).dtype),
-                valid=None if v is None else comp != jnp.uint32(n),
+                valid=valid,
                 dtype=e.dtype,
                 dictionary=(
                     lowerer.dictionary_of(e) if e.dtype.is_string else None
@@ -565,68 +704,196 @@ def _unpack_keys(key_g: jnp.ndarray, keys, proofs, slots, lowerer):
     return blocks
 
 
-def _packed_groups(gid: jnp.ndarray, out_cap: int, carried=()):
-    """Sort rows by their packed key: ``(order, key_s, live_s, bnd,
-    num_groups, starts, carried_s)`` as the sorted path has them — the
-    permutation (stable, so a group's rows keep their order), the
-    sorted key, which sorted rows live, where a group starts, the
-    groups' first positions with the capacity in the slots past the
-    last group, and the ``carried`` columns in sorted order. Two sorts
-    of one uint32 key: the first takes the columns along as payloads —
-    a payload costs the sort little, a gather of 2^20 int64 by the
-    permutation cost 17.4 ms on the v5e
-    (``chiprun_out/pr33/micro_q15.json``; 20.5 ms a batch in Q15's
-    trace, PERF.md §6, PR 35) —, the second brings the rows that start
-    a group to the front in order, which is what ``nonzero`` computes
-    with a scatter."""
-    cap = gid.shape[0]
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    key_s, order, *carried_s = lax.sort(
-        (gid, iota, *carried), num_keys=1, is_stable=True
-    )
-    live_s = key_s != jnp.uint32(_DEAD)
-    prev = jnp.concatenate([key_s[:1], key_s[:-1]])
-    bnd = live_s & ((iota == 0) | (key_s != prev))
-    num_groups = jnp.sum(bnd).astype(jnp.int32)
-    _, first = lax.sort(
-        (jnp.where(bnd, 0, 1).astype(jnp.uint32), iota),
-        num_keys=1, is_stable=True,
-    )
-    if out_cap > cap:
-        first = jnp.concatenate(
-            [first, jnp.full((out_cap - cap,), cap, jnp.int32)]
-        )
-    starts = jnp.where(
-        jnp.arange(out_cap, dtype=jnp.int32) < num_groups,
-        first[:out_cap], cap,
-    )
-    return order, key_s, live_s, bnd, num_groups, starts, carried_s
-
-
 def _carried_args(aggs, cap: int, lowerer: ExprLowerer):
-    """The aggregates' arguments that ride the packed sort: per
-    aggregate with an integer-typed argument (ints, dates, scaled
-    decimals, dictionary ids, booleans) the positions of its data and
-    its validity among the ``columns`` to carry. Floats and long
-    decimals are gathered by the permutation as before."""
-    columns, where, seen = [], {}, {}
-    for i, agg in enumerate(aggs):
-        if agg.arg is None:
-            continue
-        if agg.arg in seen:  # sum(x) and count(x) share x
-            where[i] = seen[agg.arg]
+    """The aggregates' arguments as operands to ride the key sort — a
+    payload costs a sort pass little, a gather of 2^20 int64 by its
+    permutation cost 17.4 ms on the v5e (PERF.md §6): per
+    distinct argument its data (a ``(rows, limbs)`` array as its limbs)
+    and its validity. Returns ``(rides, args)``: ``args(rode)`` maps
+    each argument to its ``(data, valid)`` among the rides as the sort
+    returns them."""
+    rides, at = [], {}
+    for agg in aggs:
+        if agg.arg is None or agg.arg in at:  # sum(x) and count(x) share x
             continue
         d, v = lowerer.eval(agg.arg)
         d = jnp.broadcast_to(d, (cap,) + jnp.shape(d)[1:])
-        if d.ndim != 1 or jnp.issubdtype(d.dtype, jnp.floating):
-            continue
-        at = [len(columns), None]
-        columns.append(d.astype(jnp.uint8) if d.dtype == jnp.bool_ else d)
+        limbs = [d] if d.ndim == 1 else list(d.reshape(cap, -1).T)
+        at[agg.arg] = (len(rides), d.shape, v is not None)
+        rides += limbs
         if v is not None:
-            at[1] = len(columns)
-            columns.append(jnp.broadcast_to(v, (cap,)).astype(jnp.uint8))
-        where[i] = seen[agg.arg] = (at[0], at[1], d.dtype)
-    return columns, where
+            rides.append(jnp.broadcast_to(v, (cap,)))
+
+    def args(rode):
+        out = {}
+        for arg, (i, shape, nullable) in at.items():
+            k = math.prod(shape[1:])
+            d = rode[i] if len(shape) == 1 else (
+                jnp.stack(rode[i:i + k], axis=1).reshape(shape)
+            )
+            out[arg] = (d, rode[i + k] if nullable else None)
+        return out
+
+    return rides, args
+
+
+def _sum_wraps(x: jnp.ndarray, bnd: jnp.ndarray, end: jnp.ndarray):
+    """The bigint-sum overflow trap: true where a group's true sum of
+    ``x`` (int64, in key order) leaves int64. A group's running total
+    read at its last row is exact under modular arithmetic whenever the
+    TRUE group sum fits int64, even where the page-wide total wraps, so
+    the check is per group: a float64 shadow of the same segmented sum.
+    A real overflow displaces the int result ~2^64 from the shadow; the
+    float error stays many orders below the 2^62 threshold. The shadow
+    runs only for a page whose magnitudes add up to 2^62 or more: under
+    that no group of it can overflow, and a sum of magnitudes is one
+    reduction. Read at the groups' last rows (``end``) as a mask, so
+    nothing is gathered."""
+    xf = x.astype(jnp.float64)
+
+    def shadow():
+        si = _segmented_scan_reduce(x, bnd, jnp.add)
+        sf = _segmented_scan_reduce(xf, bnd, jnp.add)
+        return jnp.any(end & (jnp.abs(si.astype(jnp.float64) - sf) > 2.0**62))
+
+    return lax.cond(
+        jnp.sum(jnp.abs(xf)) < 2.0**62, lambda: jnp.asarray(False), shadow
+    )
+
+
+def _accumulators(aggs, args, live_s, bnd, end, lowerer, errors_out):
+    """Every aggregate as inclusive scans over the rows in key order,
+    each to be read at the rows that end a group: running totals
+    (``cumsum``) for counts and integer sums — a group's total is the
+    difference of consecutive reads (``_diff``) — and segmented scans
+    for min/max and float sums, whose value at a group's last row is
+    the group's. A scan two aggregates need is made once. Returns
+    ``(scans, finish)``: ``finish(reads, has_rows)`` builds the
+    aggregates' blocks from the scans read at the groups' last rows in
+    group order (``has_rows``: the slots that hold a group)."""
+    scans, index = [], {}
+
+    def scan(key, make):
+        if key not in index:
+            index[key] = len(scans)
+            scans.append(make())
+        return index[key]
+
+    plans = [
+        _accumulate(agg, args.get(agg.arg), live_s, bnd, end, scan,
+                    lowerer, errors_out)
+        for agg in aggs
+    ]
+
+    def finish(reads, has_rows):
+        return [plan(reads, has_rows) for plan in plans]
+
+    return scans, finish
+
+
+def _accumulate(agg, arg, live_s, bnd, end, scan, lowerer, errors_out):
+    """One aggregate for ``_accumulators``: registers the scans it
+    reads with ``scan(key, make)`` and returns how to build its block
+    from them, ``(reads, has_rows) -> Block``."""
+    if agg.func == "count_star":
+        c = scan(("count", None), lambda: cumsum(live_s.astype(jnp.int32)))
+        return lambda r, has: Block(
+            data=_diff(r[c]).astype(jnp.int64), valid=None, dtype=T.BIGINT
+        )
+
+    d, v = arg
+    valid_s = live_s if v is None else (live_s & v)
+
+    def count():
+        # an argument that is never NULL counts the group's rows
+        return scan(
+            ("count", None if v is None else agg.arg),
+            lambda: cumsum(valid_s.astype(jnp.int32)),
+        )
+
+    if agg.func == "count":
+        c = count()
+        return lambda r, has: Block(
+            data=_diff(r[c]).astype(jnp.int64), valid=None, dtype=T.BIGINT
+        )
+
+    # an argument that is never NULL: a group has a value because it has
+    # a row, so no count is scanned to say so
+    c = None if v is None and agg.func in ("sum", "min", "max") else count()
+
+    def has_value(r, has):
+        return has if c is None else _diff(r[c]) > 0
+
+    at = agg.arg.dtype
+
+    def as_float():
+        x = d.astype(jnp.float64)
+        if at.is_decimal:
+            x = x / (10 ** at.scale)
+        return jnp.where(valid_s, x, 0.0)
+
+    if agg.func in _VARIANCE_FUNCS:
+        s1 = scan(
+            ("fsum", agg.arg),
+            lambda: _segmented_scan_reduce(as_float(), bnd, jnp.add),
+        )
+        s2 = scan(
+            ("fsq", agg.arg),
+            lambda: _segmented_scan_reduce(as_float() ** 2, bnd, jnp.add),
+        )
+        return lambda r, has: _variance_block(
+            r[s1], r[s2], _diff(r[c]), agg.func
+        )
+
+    if agg.func in ("sum", "avg"):
+        if at.name in ("double", "real") or agg.func == "avg":
+            # decimal avg and double sums: a SEGMENTED scan, not a global
+            # cumsum — differencing a whole-page running float total
+            # would cancel catastrophically for small late groups
+            s = scan(
+                ("fsum", agg.arg),
+                lambda: _segmented_scan_reduce(as_float(), bnd, jnp.add),
+            )
+            if agg.func == "avg":
+                return lambda r, has: Block(
+                    data=r[s] / jnp.maximum(_diff(r[c]), 1),
+                    valid=_diff(r[c]) > 0,
+                    dtype=T.DOUBLE,
+                )
+            return lambda r, has: Block(
+                data=r[s], valid=has_value(r, has), dtype=T.DOUBLE
+            )
+        x = jnp.where(valid_s, d.astype(jnp.int64), 0)
+        s = scan(("sum", agg.arg), lambda: cumsum(x))
+        if errors_out is not None:
+            errors_out.append(
+                (f"bigint sum overflow in {agg.out_name}",
+                 _sum_wraps(x, bnd, end))
+            )
+        return lambda r, has: Block(
+            data=_diff(r[s]), valid=has_value(r, has), dtype=agg.result_type()
+        )
+
+    if agg.func in ("min", "max"):
+        op = jnp.minimum if agg.func == "min" else jnp.maximum
+        if at.name in ("double", "real"):
+            fill = jnp.inf if agg.func == "min" else -jnp.inf
+            x = d.astype(jnp.float64)
+        else:
+            info = jnp.iinfo(jnp.int64)
+            fill = info.max if agg.func == "min" else info.min
+            x = d.astype(jnp.int64)
+        m = scan(
+            (agg.func, agg.arg),
+            lambda: _segmented_scan_reduce(jnp.where(valid_s, x, fill), bnd, op),
+        )
+        dictionary = lowerer.dictionary_of(agg.arg) if at.is_string else None
+        return lambda r, has: Block(
+            data=r[m].astype(at.jnp_dtype), valid=has_value(r, has),
+            dtype=at, dictionary=dictionary,
+        )
+
+    raise NotImplementedError(f"aggregate {agg.func}")
 
 
 def _sorted_aggregate(
@@ -638,79 +905,148 @@ def _sorted_aggregate(
     lowerer: ExprLowerer,
     errors_out: Optional[List] = None,
     out_cap: Optional[int] = None,
-    packed: Optional[tuple] = None,
+    proofs: Sequence = (),
 ) -> Tuple[Page, jnp.ndarray]:
-    """Sort rows by key, then every accumulator is a scan read at the
-    groups' spans. ``packed`` (``_packed_key``) takes the packed sort;
-    without it the keys sort lane by lane as they are. Both bring the
-    same rows together in the same order, so the page is the same.
-    ``out_cap``: the page's slots where the caller knows a proved
-    domain; ``_out_capacity`` without one."""
+    """Group by sorting, and move no row by the sort's permutation.
+
+    The keys become uint32 lanes (``_key_lanes``: ``proofs`` per key
+    ``(lo, n, stated)`` or None, as ``hash_aggregate`` finds them) and
+    are sorted one lane a pass with the other lanes and the aggregates'
+    arguments riding (``common.sort_u32_lanes``); a group starts or ends
+    where any lane differs from the row before or after. Every
+    aggregate is a scan over the sorted rows (``_accumulators``), and
+    ONE more sort — stable, of the flag of the rows that end a group —
+    brings those rows to the front in group order with the lanes and the
+    scans riding: the groups' keys are unpacked from their lanes, their
+    aggregates read from their scans. ``out_cap``: the page's slots where
+    the caller knows a proved domain; ``_out_capacity`` without one."""
     cap = page.capacity
     if out_cap is None:
         out_cap = _out_capacity(max_groups, cap, None)
-    sorted_args = {}  # aggregate -> its argument, already in sorted order
-    if packed is not None:
-        gid, proofs, slots, stale = packed
-        columns, where = _carried_args(aggs, cap, lowerer)
-        order, key_s, live_s, bnd, num_groups, first, columns = (
-            _packed_groups(gid, out_cap, columns)
-        )
-        for i, (at_d, at_v, dtype) in where.items():
-            d = columns[at_d]
-            sorted_args[i] = (
-                d != 0 if dtype == jnp.bool_ else d,
-                None if at_v is None else columns[at_v] != 0,
-            )
-    else:
-        order = sort_order(
-            [(d, v, e.dtype) for _, d, v, e in keys], live
-        )
-        live_s = live[order]
-        keys_s = [
-            (name, d[order], None if v is None else v[order], e)
-            for name, d, v, e in keys
-        ]
-        bnd = boundaries([(d, v) for _, d, v, _ in keys_s], live_s)
-        num_groups = jnp.sum(bnd).astype(jnp.int32)
-        first = nonzero_1d(bnd, out_cap, cap)
-        stale = False
-    overflow = (num_groups > max_groups) | stale
+    proofs = list(proofs) + [None] * (len(keys) - len(proofs))
+    lanes, radix, places, stale = _key_lanes(keys, proofs, live)
+    rides, args = _carried_args(aggs, cap, lowerer)
+    lanes, rode = sort_u32_lanes(lanes[::-1], rides)  # last lane primary
+    lanes = lanes[::-1]
+    live_s = lanes[0] != jnp.uint32(_DEAD)
+    differs = functools.reduce(
+        operator.or_, [lane[1:] != lane[:-1] for lane in lanes]
+    )
+    one = jnp.ones((1,), jnp.bool_)
+    bnd = live_s & jnp.concatenate([one, differs])
+    end = live_s & jnp.concatenate([differs, one])
+    num_groups = jnp.sum(bnd).astype(jnp.int32)
+    scans, finish = _accumulators(
+        aggs, args(rode), live_s, bnd, end, lowerer, errors_out
+    )
+    _, *ended = lax.sort(
+        (jnp.where(end, 0, 1).astype(jnp.uint32), *lanes, *scans),
+        num_keys=1, is_stable=True,
+    )
+    ended = [_slots(a, out_cap) for a in ended]
+    has_rows = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
+    blocks = _unpack_keys(ended[:len(lanes)], radix, places, keys, lowerer)
+    blocks += finish(ended[len(lanes):], has_rows)
+    out = Page(
+        blocks=tuple(blocks),
+        num_valid=jnp.minimum(num_groups, max_groups).astype(jnp.int32),
+        names=tuple([name for name, _, _, _ in keys]
+                    + [agg.out_name for agg in aggs]),
+    )
+    return out, (num_groups > max_groups) | stale
 
+
+# ------------------------------------------- lane-by-lane (order funcs)
+
+
+def _group_spans(
+    starts: jnp.ndarray, cap: int
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(starts, ends) sorted-space positions per group (gather-safe),
+    from the groups' first positions with ``cap`` in the slots past the
+    last group.
+
+    ``ends[i] = starts[i+1] - 1`` with cap-1 for the final/fill groups —
+    safe because rows past the live prefix carry neutral values for every
+    accumulator (0 for running totals, +-inf fills for min/max scans).
+    """
+    nxt = jnp.concatenate(
+        [starts[1:], jnp.full((1,), cap, starts.dtype)]
+    )
+    ends = jnp.clip(nxt - 1, 0, cap - 1)
+    safe_starts = jnp.minimum(starts, cap - 1).astype(jnp.int32)
+    return safe_starts, ends.astype(jnp.int32)
+
+
+def _ordered_aggregate(
+    page: Page,
+    keys,
+    aggs: Sequence[AggCall],
+    max_groups: int,
+    live: jnp.ndarray,
+    lowerer: ExprLowerer,
+    errors_out: Optional[List] = None,
+) -> Tuple[Page, jnp.ndarray]:
+    """The lane-by-lane branch, for ``array_agg`` and the order
+    statistics alone (``_ORDER_FUNCS``): they need the sort's
+    permutation and the groups' spans themselves. ``common.sort_order``
+    (a ``lexsort_u32`` pass a lane), keys and arguments gathered by the
+    permutation, the groups' first rows by ``nonzero_1d``; the other
+    aggregates are ``_accumulators``' scans read at the spans' ends.
+    Every such move is counted (``record_row_moves``)."""
+    cap = page.capacity
+    out_cap = _out_capacity(max_groups, cap, None)
+    order = sort_order([(d, v, e.dtype) for _, d, v, e in keys], live)
+    live_s = _take(live, order)
+    keys_s = [
+        (name, _take(d, order), None if v is None else _take(v, order), e)
+        for name, d, v, e in keys
+    ]
+    bnd = boundaries([(d, v) for _, d, v, _ in keys_s], live_s)
+    end = live_s & jnp.concatenate(
+        [bnd[1:] | ~live_s[1:], jnp.ones((1,), jnp.bool_)]
+    )
+    num_groups = jnp.sum(bnd).astype(jnp.int32)
+    first = nonzero_1d(bnd, out_cap, cap)
+    _moved()
     starts, ends = _group_spans(first, cap)
 
     names: List[str] = []
     blocks: List[Block] = []
-    if packed is not None:
-        blocks.extend(
-            _unpack_keys(key_s[starts], keys, proofs, slots, lowerer)
+    for name, d, v, e in keys_s:
+        names.append(name)
+        blocks.append(
+            Block(
+                data=_take(d, starts),
+                valid=None if v is None else _take(v, starts),
+                dtype=e.dtype,
+                dictionary=(
+                    lowerer.dictionary_of(e) if e.dtype.is_string else None
+                ),
+            )
         )
-        names.extend(name for name, _, _, _ in keys)
-    else:
-        for name, d, v, e in keys_s:
-            names.append(name)
-            dictionary = None
-            if e.dtype.is_string:
-                dictionary = lowerer.dictionary_of(e)
-            blocks.append(
-                Block(
-                    data=d[starts],
-                    valid=None if v is None else v[starts],
-                    dtype=e.dtype,
-                    dictionary=dictionary,
-                )
-            )
 
-    for i, agg in enumerate(aggs):
-        if agg.func in ("approx_percentile", "min_by", "max_by"):
-            blk = _order_stat_agg(
-                agg, page, keys, live, starts, ends, lowerer
+    plain = [a for a in aggs if a.func not in _ORDER_FUNCS]
+    args = {}
+    for agg in plain:
+        if agg.arg is not None and agg.arg not in args:
+            d, v = lowerer.eval(agg.arg)
+            args[agg.arg] = (
+                _take(jnp.broadcast_to(d, (cap,)), order),
+                None if v is None else _take(jnp.broadcast_to(v, (cap,)), order),
             )
+    scans, finish = _accumulators(
+        plain, args, live_s, bnd, end, lowerer, errors_out
+    )
+    has_rows = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
+    done = iter(finish([_take(s, ends) for s in scans], has_rows))
+    for agg in aggs:
+        if agg.func == "array_agg":
+            blk = _array_agg(agg, page, order, live_s, first, starts, lowerer)
+        elif agg.func in _ORDER_FUNCS:
+            blk = _order_stat_agg(agg, page, keys, live, starts, ends, lowerer)
         else:
-            blk = _sorted_one_agg(
-                agg, page, order, live_s, bnd, starts, ends, lowerer,
-                errors_out, arg_s=sorted_args.get(i),
-            )
+            blk = next(done)
         names.append(agg.out_name)
         blocks.append(blk)
 
@@ -719,7 +1055,7 @@ def _sorted_aggregate(
         num_valid=jnp.minimum(num_groups, max_groups).astype(jnp.int32),
         names=tuple(names),
     )
-    return out, overflow
+    return out, num_groups > max_groups
 
 
 def _order_stat_agg(
@@ -731,7 +1067,7 @@ def _order_stat_agg(
     ends: jnp.ndarray,
     lowerer: ExprLowerer,
 ) -> Block:
-    """approx_percentile / min_by / max_by on the sorted path.
+    """approx_percentile / min_by / max_by on the lane-by-lane branch.
 
     Each takes its own secondary sort: (group keys, ordering value) —
     within every group the ordering value's non-null rows form an
@@ -758,9 +1094,10 @@ def _order_stat_agg(
         + [(vd, vvb, val.dtype)],
         live,
     )
-    live2 = live[order2]
-    valid2 = live2 if vvb is None else (live2 & vvb[order2])
-    cntv = _cumsum_span(valid2.astype(jnp.int64), starts, ends)
+    live2 = _take(live, order2)
+    valid2 = live2 if vvb is None else (live2 & _take(vvb, order2))
+    cntv = _diff(_take(cumsum(valid2.astype(jnp.int32)), ends))
+    cntv = cntv.astype(jnp.int64)
     group_has = cntv > 0
 
     if agg.func == "approx_percentile":
@@ -774,11 +1111,12 @@ def _order_stat_agg(
             starts.astype(jnp.int64) + k, cap - 1
         ).astype(jnp.int32)
         return Block(
-            data=vd[order2][idx], valid=group_has, dtype=agg.arg.dtype
+            data=_take(_take(vd, order2), idx), valid=group_has,
+            dtype=agg.arg.dtype,
         )
 
     xd, xv = lowerer.eval(agg.arg)
-    xd2 = jnp.broadcast_to(xd, (cap,))[order2]
+    xd2 = _take(jnp.broadcast_to(xd, (cap,)), order2)
     if agg.func == "min_by":
         idx = starts
     else:
@@ -788,199 +1126,70 @@ def _order_stat_agg(
         ).astype(jnp.int32)
     valid = group_has
     if xv is not None:
-        valid = valid & jnp.broadcast_to(xv, (cap,))[order2][idx]
+        valid = valid & _take(
+            _take(jnp.broadcast_to(xv, (cap,)), order2), idx
+        )
     dictionary = None
     if agg.arg.dtype.is_string:
         dictionary = lowerer.dictionary_of(agg.arg)
     return Block(
-        data=xd2[idx], valid=valid, dtype=agg.arg.dtype,
+        data=_take(xd2, idx), valid=valid, dtype=agg.arg.dtype,
         dictionary=dictionary,
     )
 
 
-def _cumsum_span(
-    w: jnp.ndarray, starts: jnp.ndarray, ends: jnp.ndarray
-) -> jnp.ndarray:
-    """Per-group totals of ``w`` via inclusive cumsum differenced over
-    [start, end] spans (no scatter): the running total at the span's
-    end less the one before its start, two gathers a span (each 2.7 ms
-    at 2^17 slots on the v5e, ``chiprun_out/pr33/micro_q15.json``)."""
-    c = cumsum(w)
-    return c[ends] - (c - w)[starts]
-
-
-def _sorted_one_agg(
+def _array_agg(
     agg: AggCall,
     page: Page,
     order: jnp.ndarray,
     live_s: jnp.ndarray,
-    bnd: jnp.ndarray,
+    first: jnp.ndarray,
     starts: jnp.ndarray,
-    ends: jnp.ndarray,
     lowerer: ExprLowerer,
-    errors_out: Optional[List] = None,
-    arg_s: Optional[tuple] = None,
 ) -> Block:
-    """One aggregate over rows in sorted order. ``arg_s``: its
-    argument's ``(data, valid)`` where they are in sorted order already
-    (they rode the packed sort); evaluated and gathered by ``order``
-    otherwise."""
-    rt = agg.result_type()
-
-    if agg.func == "count_star":
-        data = _cumsum_span(live_s.astype(jnp.int64), starts, ends)
-        return Block(data=data, valid=None, dtype=T.BIGINT)
-
-    if agg.func == "array_agg":
-        # the sorted layout IS the concatenated per-group arrays
-        # (groups are contiguous spans); NULL inputs are SKIPPED, so
-        # valid values scatter to their rank among valid rows — stable,
-        # so groups stay contiguous — and group offsets are the valid
-        # counts at group starts. (Deviation: the reference's
-        # array_agg default INCLUDES nulls; arrays here carry no
-        # element validity.)
-        cap = page.capacity
-        d, v = lowerer.eval(agg.arg)
-        d_s = jnp.broadcast_to(d, (cap,))[order]
-        valid_s = live_s if v is None else (
-            live_s & jnp.broadcast_to(v, (cap,))[order]
-        )
-        cum = jnp.cumsum(valid_s.astype(jnp.int32))
-        total = cum[-1] if cap else jnp.int32(0)
-        pos = jnp.where(valid_s, cum - 1, cap)  # cap = dump slot
-        out_vals = jnp.zeros((cap + 1,), d_s.dtype).at[pos].set(d_s)
-        # padding group slots must read offset == total; the CLAMPED
-        # starts (cap-1) would read total-1 on a completely full page
-        # and silently drop the last group's last element, so detect
-        # padding from the UNCLAMPED boundary positions
-        raw_starts = nonzero_1d(bnd, starts.shape[0], cap)
-        start_off = jnp.where(
-            raw_starts >= cap,
-            total,
-            cum[starts] - valid_s[starts].astype(jnp.int32),
-        )
-        offsets = jnp.concatenate(
-            [
-                jnp.minimum(start_off, total).astype(jnp.int32),
-                total.reshape(1),
-            ]
-        )
-        dictionary = None
-        if agg.arg.dtype.is_string:
-            dictionary = lowerer.dictionary_of(agg.arg)
-        return Block(
-            data=out_vals[:cap],
-            valid=None,
-            dtype=rt,
-            dictionary=dictionary,
-            offsets=offsets,
-        )
-
-    if arg_s is None:
-        d, v = lowerer.eval(agg.arg)
-        d = jnp.broadcast_to(d, (page.capacity,))[order]
-        if v is not None:
-            v = jnp.broadcast_to(v, (page.capacity,))[order]
-    else:
-        d, v = arg_s
-    valid_s = live_s if v is None else (live_s & v)
-
-    if agg.func == "count":
-        data = _cumsum_span(valid_s.astype(jnp.int64), starts, ends)
-        return Block(data=data, valid=None, dtype=T.BIGINT)
-
-    if v is None and agg.func in ("sum", "min", "max"):
-        # an argument that is never NULL: a group has a value because it
-        # has a row, so no count is scanned and gathered to say so (a
-        # cumsum and two gathers at the page's slots: some 7 ms of a
-        # 2^20-row batch on the v5e)
-        cnt = None
-        group_has_value = (
-            jnp.arange(starts.shape[0], dtype=jnp.int32) < jnp.sum(bnd)
-        )
-    else:
-        cnt = _cumsum_span(valid_s.astype(jnp.int64), starts, ends)
-        group_has_value = cnt > 0
-
-    if agg.func in _VARIANCE_FUNCS:
-        at = agg.arg.dtype
-        x = d.astype(jnp.float64)
-        if at.is_decimal:
-            x = x / (10 ** at.scale)
-        x = jnp.where(valid_s, x, 0.0)
-        s1 = _segmented_scan_reduce(x, bnd, jnp.add)[ends]
-        s2 = _segmented_scan_reduce(x * x, bnd, jnp.add)[ends]
-        return _variance_block(s1, s2, cnt, agg.func)
-
-    if agg.func in ("sum", "avg"):
-        at = agg.arg.dtype
-        if at.name in ("double", "real") or agg.func == "avg":
-            # decimal avg and double sums: SEGMENTED scan, not a global
-            # cumsum — differencing a whole-page running float total
-            # would cancel catastrophically for small late groups
-            x = d.astype(jnp.float64)
-            if at.is_decimal:
-                x = x / (10 ** at.scale)
-            x = jnp.where(valid_s, x, 0.0)
-            s = _segmented_scan_reduce(x, bnd, jnp.add)[ends]
-            if agg.func == "avg":
-                data = s / jnp.maximum(cnt, 1)
-                return Block(
-                    data=data, valid=group_has_value, dtype=T.DOUBLE
-                )
-            return Block(data=s, valid=group_has_value, dtype=T.DOUBLE)
-        x = jnp.where(valid_s, d.astype(jnp.int64), 0)
-        s = _cumsum_span(x, starts, ends)
-        if errors_out is not None:
-            # per-group overflow trap: the differenced int64 sums are
-            # exact under modular arithmetic whenever the TRUE group sum
-            # fits int64 (even if the page-wide running total wraps), so
-            # the check must be per group — a float64 shadow of the same
-            # span difference. A real per-group overflow displaces the
-            # int result ~2^64 from the shadow; float cancellation error
-            # stays many orders below the 2^62 threshold. The shadow (a
-            # float64 scan and two gathers) runs only for a page whose
-            # magnitudes add up to 2^62 or more: under that no group of
-            # it can overflow, and a sum of magnitudes is one reduction.
-            xf = x.astype(jnp.float64)
-
-            def shadow():
-                sf = _cumsum_span(xf, starts, ends)
-                return jnp.any(
-                    jnp.abs(s.astype(jnp.float64) - sf) > 2.0**62
-                )
-
-            wrapped = lax.cond(
-                jnp.sum(jnp.abs(xf)) < 2.0**62,
-                lambda: jnp.asarray(False), shadow,
-            )
-            errors_out.append(
-                (f"bigint sum overflow in {agg.out_name}", wrapped)
-            )
-        return Block(data=s, valid=group_has_value, dtype=rt)
-
-    if agg.func in ("min", "max"):
-        at = agg.arg.dtype
-        op = jnp.minimum if agg.func == "min" else jnp.maximum
-        if at.name in ("double", "real"):
-            fill = jnp.inf if agg.func == "min" else -jnp.inf
-            x = jnp.where(valid_s, d.astype(jnp.float64), fill)
-            scan = _segmented_scan_reduce(x, bnd, op)
-            data = scan[ends].astype(at.jnp_dtype)
-        else:
-            info = jnp.iinfo(jnp.int64)
-            fill = info.max if agg.func == "min" else info.min
-            x = jnp.where(valid_s, d.astype(jnp.int64), fill)
-            scan = _segmented_scan_reduce(x, bnd, op)
-            data = scan[ends].astype(at.jnp_dtype)
-        dictionary = None
-        if at.is_string:
-            dictionary = lowerer.dictionary_of(agg.arg)
-        return Block(
-            data=data, valid=group_has_value, dtype=at, dictionary=dictionary
-        )
-
-    raise NotImplementedError(f"aggregate {agg.func}")
+    """array_agg on the lane-by-lane branch: the sorted layout IS the
+    concatenated per-group arrays (groups are contiguous spans). NULL
+    inputs are SKIPPED, so valid values scatter to their rank among
+    valid rows — stable, so groups stay contiguous — and group offsets
+    are the valid counts at group starts. (Deviation: the reference's
+    array_agg default INCLUDES nulls; arrays here carry no element
+    validity.)"""
+    cap = page.capacity
+    d, v = lowerer.eval(agg.arg)
+    d_s = _take(jnp.broadcast_to(d, (cap,)), order)
+    valid_s = live_s if v is None else (
+        live_s & _take(jnp.broadcast_to(v, (cap,)), order)
+    )
+    cum = jnp.cumsum(valid_s.astype(jnp.int32))
+    total = cum[-1] if cap else jnp.int32(0)
+    pos = jnp.where(valid_s, cum - 1, cap)  # cap = dump slot
+    out_vals = jnp.zeros((cap + 1,), d_s.dtype).at[pos].set(d_s)
+    _moved()
+    # padding group slots must read offset == total; the CLAMPED starts
+    # (cap-1) would read total-1 on a completely full page and silently
+    # drop the last group's last element, so detect padding from the
+    # UNCLAMPED first rows
+    start_off = jnp.where(
+        first >= cap,
+        total,
+        _take(cum, starts) - _take(valid_s, starts).astype(jnp.int32),
+    )
+    offsets = jnp.concatenate(
+        [
+            jnp.minimum(start_off, total).astype(jnp.int32),
+            total.reshape(1),
+        ]
+    )
+    dictionary = None
+    if agg.arg.dtype.is_string:
+        dictionary = lowerer.dictionary_of(agg.arg)
+    return Block(
+        data=out_vals[:cap],
+        valid=None,
+        dtype=agg.result_type(),
+        dictionary=dictionary,
+        offsets=offsets,
+    )
 
 
 # ---------------------------------------------------------- global path

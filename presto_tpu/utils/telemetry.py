@@ -125,6 +125,8 @@ class DeviceTelemetry:
         # split batches, or built inside a program, once a dispatch
         self._join_builds = REGISTRY.counter("device.join_builds")
         self._join_build_slots = REGISTRY.counter("device.join_build_slots")
+        # page-sized row moves of the grouped aggregations a program runs
+        self._agg_row_moves = REGISTRY.counter("device.agg_row_moves")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -185,6 +187,15 @@ class DeviceTelemetry:
         if self.enabled:
             self._join_builds.update()
             self._join_build_slots.update(int(slots))
+
+    def count_agg_row_moves(self, n: int) -> None:
+        """The page-sized row moves — gathers by a sort's permutation or
+        at group spans, ``nonzero_1d`` compactions, scatters — that the
+        grouped aggregations of one dispatched program issue, as its
+        trace counted them (``ops.aggregation.record_row_moves``):
+        nothing is read from the device."""
+        if self.enabled and n:
+            self._agg_row_moves.update(int(n))
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -265,6 +276,9 @@ class DeviceTelemetry:
         ``join_build_slots`` count the join build sides the device
         sorted — prepared once a task for its split batches, or inside
         a program at each dispatch — and their static slots.
+        ``agg_row_moves`` counts the page-sized row moves the dispatched
+        programs' grouped aggregations issue (0 where no aggregation
+        takes the lane-by-lane branch).
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -291,6 +305,7 @@ class DeviceTelemetry:
             "agg_out_slots": int(self._agg_slots.total),
             "join_builds": int(self._join_builds.total),
             "join_build_slots": int(self._join_build_slots.total),
+            "agg_row_moves": int(self._agg_row_moves.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

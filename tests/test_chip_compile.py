@@ -16,6 +16,7 @@ about results or times on the device.
 import concurrent.futures
 import dataclasses
 import decimal
+import re
 import time
 
 import jax
@@ -115,9 +116,12 @@ def _case_sort(one_chip, mesh):
 
 
 def _case_aggregate(one_chip, mesh):
-    """The sorted path (a DOUBLE key has no static domain): key images,
-    boundaries, the int64 cumsum with its float64 overflow shadow, the
-    segmented float64 scan."""
+    """The sorted path over keys with lanes of their own (a DOUBLE key
+    has no static domain, a DATE no stated range): the float64 and
+    int64 arguments riding the lane sorts, the int64 cumsum with its
+    float64 overflow shadow, the segmented float64 scan riding the
+    compacting sort, the DOUBLE key put back from its lanes by
+    bitcast."""
     aggs = [
         AggCall("sum", _col("dec"), "s1"), AggCall("avg", _col("dbl"), "a1"),
         AggCall("count_star", None, "c"),
@@ -130,8 +134,8 @@ def _case_aggregate(one_chip, mesh):
 
 
 def _case_aggregate_packed(one_chip, mesh):
-    """The packed sort (bigint and date keys with stated ranges): the
-    uint32 composite, the two single-key sorts, the keys unpacked, the
+    """The packed sort (bigint and date keys with stated ranges): one
+    uint32 lane, its sort and the compacting one, the keys unpacked, the
     same accumulators, and the range check among the error flags."""
     aggs = [AggCall("sum", _col("dec"), "s1"), AggCall("count_star", None, "c")]
     keys = [(c, _col(c)) for c in ("i", "d")]
@@ -145,6 +149,36 @@ def _case_aggregate_packed(one_chip, mesh):
         return out, overflow, [flag for _, flag in errors]
 
     return jax.jit(fn), (_spec(_page(SORT_CAP, ("i", "d", "dec")), one_chip),)
+
+
+#: Q3's partial aggregation at a split batch's real size
+Q3_CAP = 1 << 20
+
+
+def _case_aggregate_q3(one_chip, mesh):
+    """Q3's grouping at a split batch's 2^20 rows: a key with a stated
+    23-bit range (``l_orderkey``) and two int32 keys with none, three
+    lanes sorted one a pass with the decimal argument riding, then the
+    compacting sort."""
+    dec = T.decimal(18, 4)
+    schema = {"ok": T.INTEGER, "od": T.DATE, "sp": T.INTEGER, "rev": dec}
+    page = Page.from_pydict(
+        {"ok": [1, 2], "od": [9000, 9001], "sp": [0, 0], "rev": [1.0, 2.0]},
+        schema, capacity=Q3_CAP,
+    )
+    page = dataclasses.replace(page, live=jnp.ones(Q3_CAP, bool))
+    keys = [(c, ColumnRef(c, schema[c])) for c in ("ok", "od", "sp")]
+    aggs = [AggCall("sum", ColumnRef("rev", dec), "revenue")]
+
+    def fn(p):
+        errors = []
+        out, overflow = hash_aggregate(
+            p, keys, aggs, max_groups=1 << 22, errors_out=errors,
+            key_ranges=((1, 6_000_000), None, None),
+        )
+        return out, overflow, [flag for _, flag in errors]
+
+    return jax.jit(fn), (_spec(page, one_chip),)
 
 
 def _case_join(one_chip, mesh):
@@ -267,6 +301,7 @@ CASES = {
     "sort[double desc, dictionary keys; long-decimal payload]": _case_sort,
     "hash_aggregate[double, date keys]": _case_aggregate,
     "hash_aggregate[packed bigint, date keys]": _case_aggregate_packed,
+    "hash_aggregate[Q3's keys, 2^20 rows]": _case_aggregate_q3,
     "hash_join[double key]": _case_join,
     "hash_join[unique build]": _case_join_ranked("inner", True),
     "hash_join[duplicate build, left]": _case_join_ranked("left", False),
@@ -343,6 +378,21 @@ def test_compiles_for_v5e(compiled, name):
             f"the v5e compiler refused {name}: {out}"
         ) from out
     assert out.as_text()
+
+
+@pytest.mark.parametrize("name", [
+    "hash_aggregate[double, date keys]",
+    "hash_aggregate[packed bigint, date keys]",
+    "hash_aggregate[Q3's keys, 2^20 rows]",
+])
+def test_sorted_aggregation_moves_no_row(compiled, name):
+    """No gather and no scatter: the keys and arguments ride the lane
+    sorts, the groups are read by the compacting sort."""
+    out, _ = compiled[name]
+    assert not isinstance(out, Exception), out
+    ops = re.findall(r"\s(gather|scatter|sort)\(", out.as_text())
+    assert "gather" not in ops and "scatter" not in ops
+    assert "sort" in ops
 
 
 def test_partition_exchange_program_holds_the_collective(compiled):
