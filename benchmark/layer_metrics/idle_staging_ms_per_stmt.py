@@ -1,0 +1,5 @@
+"""Of the device's idle time, the milliseconds a statement during which the
+host was in a ``staging`` span (field ``idle_s.staging`` of
+``benchmark/idle_spans.py``)."""
+
+from benchmark.idle_spans import read  # noqa: F401

@@ -1212,7 +1212,10 @@ class LocalQueryRunner:
         for pid, sub in plan.params:
             sub_root = self._bind_params(sub)
             sub_root = push_scan_constraints(prune_columns(sub_root))
-            page = self._run(sub_root)
+            # running the subquery is execution, though the caller may
+            # still be planning the statement around it
+            with tracing.phase("exec", site="subquery"):
+                page = self._run(sub_root)
             col = sub.output_names[0]
             bindings[pid] = _scalar_literal(page, col)
         if not bindings:

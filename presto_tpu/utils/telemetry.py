@@ -133,6 +133,10 @@ class DeviceTelemetry:
             "worker.results_wait_timeouts"
         )
         self._pull_stalls = REGISTRY.counter("coordinator.pull_stalls")
+        # a join's probe that ran unfiltered because the wait for its
+        # dynamic filter ran out (server/coordinator.py): another
+        # fragment, compiled where the process has not run it
+        self._df_wait_expired = REGISTRY.counter("dynamic_filter.wait_expired")
         # residency by column of the staging cache (exec/staging.py)
         self._stage_col_hits = REGISTRY.counter("staging.col_hit")
         self._stage_col_misses = REGISTRY.counter("staging.col_miss")
@@ -265,6 +269,9 @@ class DeviceTelemetry:
         ``worker.results_waits`` / ``worker.results_wait_timeouts`` /
         ``coordinator.pull_stalls`` say whether the results long-poll
         engages (waits with no time-outs and no stalls).
+        ``dynamic_filter_wait_expired`` counts the joins whose probe ran
+        unfiltered because the wait for the build's filter summary ran
+        out (the registry's ``dynamic_filter.wait_expired``).
         ``program_out_bytes`` sums the static size of every dispatched
         fragment program's output page (beside ``d2h_bytes``, what of
         it was fetched), ``device_syncs`` the blocking fetches that
@@ -314,6 +321,7 @@ class DeviceTelemetry:
                 self._results_wait_timeouts.total
             ),
             "coordinator.pull_stalls": int(self._pull_stalls.total),
+            "dynamic_filter_wait_expired": int(self._df_wait_expired.total),
             "stage_col_hits": int(self._stage_col_hits.total),
             "stage_col_misses": int(self._stage_col_misses.total),
             "stage_evictions": int(self._stage_evictions.total),

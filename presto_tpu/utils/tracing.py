@@ -24,21 +24,24 @@ thread, a timer or a socket (``site=`` says which).
 ``telemetry.device_snapshot()`` serves the accumulator as
 ``span_ms.<name>`` / ``wait_ms.<site>`` / ``stmt_wall_ms``, which is
 how the benchmark reads host time per layer. :meth:`Trace.span` is a
-:func:`phase` that also hangs a node on the query's tree. With
-``PRESTO_TPU_PROFILE_SPANS=1`` every phase is also a
-``jax.profiler.TraceAnnotation`` named ``presto:<name>[/<site>]``, so
-a profile holds the engine's spans beside the device's operations
-(``tools/trace_gaps.py`` reads them).
+:func:`phase` that also hangs a node on the query's tree. While a
+profiler session collects (``jax.profiler.start_trace``), every phase
+is also a ``jax.profiler.TraceAnnotation`` named
+``presto:<name>[/<site>]``, so the profile holds the engine's spans on
+the device's clock, beside its operations (``benchmark/idle_spans.py``
+puts each idle instant of the device down to one of them). With no
+session a phase asks one flag of the profiler and builds nothing.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 import time
 import uuid
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 #: the work names: the thread is running the engine's code (for
 #: ``fetch``: blocked on the device). One name a layer boundary;
@@ -51,13 +54,8 @@ WAIT = "wait"
 #: prefix of the profiler annotations (``presto:<name>[/<site>]``)
 ANNOTATION = "presto:"
 
-#: ``PRESTO_TPU_PROFILE_SPANS=1``: phases are also TraceAnnotations on
-#: the profiler's clock. Read once; off by default because the
-#: benchmark's reduction counts every host event it does not know as
-#: time inside the JAX runtime.
-PROFILE_SPANS = os.environ.get("PRESTO_TPU_PROFILE_SPANS", "") not in (
-    "", "0",
-)
+#: true only while a profiler session collects (looked up once)
+_profiling = _Annotation.is_enabled
 
 #: the served tree's display names (README "Observability") and the
 #: accumulator name each one's self time belongs to
@@ -113,18 +111,14 @@ class _Phase:
         stack.append(self)
         if self._trace is not None:
             self._trace._push(self.span)
-        if PROFILE_SPANS:
-            import jax
-
+        if _profiling():
             label = ANNOTATION + self.name
             if self.site:
                 label += "/" + self.site
             if self.span is not None:
-                self._ann = jax.profiler.TraceAnnotation(
-                    label, trace_id=self.span.trace_id
-                )
+                self._ann = _Annotation(label, trace_id=self.span.trace_id)
             else:
-                self._ann = jax.profiler.TraceAnnotation(label)
+                self._ann = _Annotation(label)
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self.span

@@ -31,6 +31,7 @@ from presto_tpu.exec.local_runner import LocalQueryRunner  # noqa: E402
 from presto_tpu.exec.staging import CatalogManager  # noqa: E402
 from presto_tpu.utils import faults  # noqa: E402
 from presto_tpu.utils.metrics import REGISTRY  # noqa: E402
+from presto_tpu.utils.telemetry import device_snapshot  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -551,6 +552,7 @@ def test_wait_timeout_proceeds_unfiltered(cluster, warehouse):
     _, expected = warehouse
     built0 = _counter("dynamic_filter.built")
     expired0 = _counter("dynamic_filter.wait_expired")
+    snap0 = device_snapshot()["dynamic_filter_wait_expired"]
     _set_session(coord, "dynamic_filtering_wait_ms", "0")
     try:
         res = client.execute(_JOIN_SQL)
@@ -559,6 +561,8 @@ def test_wait_timeout_proceeds_unfiltered(cluster, warehouse):
     assert res.data == [[expected[2024][0], expected[2024][1]]]
     assert _counter("dynamic_filter.built") == built0
     assert _counter("dynamic_filter.wait_expired") > expired0
+    # served where the benchmark's window line reads the counters
+    assert device_snapshot()["dynamic_filter_wait_expired"] > snap0
 
 
 def test_build_worker_kill_degrades_to_unfiltered(cluster, warehouse):

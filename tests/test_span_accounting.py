@@ -1,11 +1,13 @@
 """Host time per layer: span self times as counters, named waits, true
-XLA compiles, and the attribution of device idle gaps to spans
-(``utils/tracing.py``, ``utils/telemetry.py``, ``tools/trace_gaps.py``).
+XLA compiles, the spans in a device profile, and the attribution of the
+device's idle time to them (``utils/tracing.py``, ``utils/telemetry.py``,
+``benchmark/idle_spans.py``).
 """
 
 import ast
 import os
 import sys
+import tempfile
 import threading
 import time
 
@@ -17,9 +19,10 @@ from presto_tpu.utils import tracing
 from presto_tpu.utils.telemetry import DEVICE, device_snapshot
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "tools"))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
 
-import trace_gaps  # noqa: E402
+from benchmark import discovery, harness, idle_spans, reduce  # noqa: E402
 
 WORK_KEYS = [f"span_ms.{n}" for n in tracing.WORK]
 
@@ -162,7 +165,7 @@ def test_snapshot_always_has_the_keys_the_benchmark_reads():
                           "xla_cache_loads", "xla_compile_ms",
                           "stage_col_hits", "stage_col_misses",
                           "stage_evictions", "stage_resident_bytes",
-                          "program_out_bytes"]:
+                          "program_out_bytes", "dynamic_filter_wait_expired"]:
         assert isinstance(snap[k], (int, float)), k
 
 
@@ -179,6 +182,31 @@ def test_xla_compiles_rise_on_a_new_shape_only():
     assert new_shape["xla_compiles"] + new_shape["xla_cache_loads"] == 1
     # the engine's own count does not see a compile it did not dispatch
     assert new_shape["compiles"] == 0
+
+
+def test_a_scalar_subquery_runs_in_an_exec_span(monkeypatch):
+    """The subquery's run is execution (site ``subquery``), not the
+    planning of the statement that binds its value."""
+    from presto_tpu.exec.local_runner import LocalQueryRunner
+
+    runner = LocalQueryRunner()
+    opened = []
+    real = tracing.phase
+
+    def spy(name, site=""):
+        opened.append((name, site, len(getattr(tracing._tls, "stack", ()))))
+        return real(name, site)
+
+    monkeypatch.setattr(tracing, "phase", spy)
+    res = runner.execute(
+        "select count(*) from tpch.tiny.nation where n_regionkey = "
+        "(select max(r_regionkey) from tpch.tiny.region)")
+    assert res.rows() == [(5,)]
+    sub = [o for o in opened if o[:2] == ("exec", "subquery")]
+    assert len(sub) == 1
+    # the subquery's own phases open inside it, under their own names
+    inside = opened[opened.index(sub[0]) + 1:]
+    assert any(depth > sub[0][2] for _, _, depth in inside)
 
 
 # ------------------------------------------------------- a served statement
@@ -304,7 +332,33 @@ def test_held_results_get_is_a_wait_not_schedule_work():
     assert d["span_ms.unworked"] < 10
 
 
-# -------------------------------------------------------------- trace_gaps
+# -------------------------------------------- spans on the device's clock
+
+
+def test_phase_is_an_annotation_only_inside_a_profile(tmp_path, monkeypatch):
+    """Outside a profiler session a phase builds no annotation; inside
+    one it leaves ``presto:<name>/<site>`` in the written profile."""
+    built = []
+
+    class Counted(tracing._Annotation):
+        def __init__(self, *a, **kw):
+            built.append(a[0])
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(tracing, "_Annotation", Counted)
+    with tracing.phase("exec", site="x"):
+        pass
+    assert built == []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.phase("exec", site="x"), tracing.Trace().span("gather"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert built == ["presto:exec/x", "presto:exec/gather"]
+    planes = idle_spans.load_planes_by_thread(reduce.find_xplane(str(tmp_path)))
+    names = {e[0] for lines in planes.values() for evs in lines.values() for e in evs}
+    assert {"presto:exec/x", "presto:exec/gather"} <= names
 
 
 def _planes(threads, ops=((0, 10), (90, 100))):
@@ -321,6 +375,15 @@ def _planes(threads, ops=((0, 10), (90, 100))):
     }
 
 
+def _by_span(out):
+    """``idle_by_stmt`` summed over statements: seconds by span."""
+    got = {}
+    for k, v in out["idle_by_stmt"].items():
+        span = k.split("|", 1)[1]
+        got[span] = got.get(span, 0.0) + v
+    return got
+
+
 @pytest.mark.parametrize("threads,want,parts", [
     # a gap under two nested spans goes to the inner one
     ([[("exec/query", 0, 100), ("schedule", 5, 95)]], "schedule", {"schedule": 80}),
@@ -328,33 +391,39 @@ def _planes(threads, ops=((0, 10), (90, 100))):
     ([[("exec/task", 5, 95)], [("protocol/client", 0, 100), ("wait/client.http", 1, 99)]],
      "exec/task", {"exec/task": 80}),
     # of two waits, the one that started last wins
-    ([[("wait/client.http", 1, 99)], [("wait/coordinator.pull_stall", 5, 95)]],
+    ([[("wait/coordinator.long_poll", 1, 99)], [("wait/coordinator.pull_stall", 5, 95)]],
      "wait/coordinator.pull_stall", {"wait/coordinator.pull_stall": 80}),
     # ... only while it lasts: the chain's earlier wait takes the rest
-    ([[("wait/client.http", 1, 99)], [("wait/coordinator.pull_stall", 20, 80)]],
+    ([[("wait/coordinator.long_poll", 1, 99)], [("wait/coordinator.pull_stall", 20, 80)]],
      "wait/coordinator.pull_stall",
-     {"wait/coordinator.pull_stall": 60, "wait/client.http": 20}),
+     {"wait/coordinator.pull_stall": 60, "wait/coordinator.long_poll": 20}),
     # a wait nested in work on the same thread is the innermost
     ([[("schedule/attempt", 0, 100), ("wait/coordinator.pull_stall", 12, 88)]],
      "wait/coordinator.pull_stall",
      {"wait/coordinator.pull_stall": 76, "schedule/attempt": 4}),
     # a span covering under half the gap does not take it
-    ([[("dispatch", 10, 30)]], "unattributed", {"dispatch": 20, "unattributed": 60}),
-    # a gap under none is unattributed
-    ([], "unattributed", {"unattributed": 80}),
+    ([[("dispatch", 10, 30)]], "untraced", {"dispatch": 20, "untraced": 60}),
+    # a gap under none is untraced
+    ([[("protocol/client", 0, 5)]], "untraced", {"untraced": 80}),
+    # ... and so is one only the client's round trip covers: the server
+    # ran code that opened no span
+    ([[("protocol/client", 0, 100), ("wait/client.http", 1, 99)]],
+     "untraced", {"untraced": 80}),
+    # a server's wait under the client's takes what it covers
+    ([[("protocol/client", 0, 100), ("wait/client.http", 1, 99)],
+      [("wait/coordinator.long_poll", 20, 80)]],
+     "wait/coordinator.long_poll", {"wait/coordinator.long_poll": 60, "untraced": 20}),
 ])
 def test_trace_gaps_attribution(threads, want, parts):
-    out = trace_gaps.analyse(_planes(threads), "tpu")
-    assert out["idle_s"] == pytest.approx(80e-9)
-    assert out["sums"] == [(want, pytest.approx(80e-9))]
-    assert dict(out["slices"]) == {k: pytest.approx(v * 1e-9) for k, v in parts.items()}
-    assert out["stmts"] == 1
-    assert {k for k, _ in out["by_stmt"]} == {f"q|{k}" for k in parts}
-    unworked = sum(
-        v for k, v in parts.items() if k == "unattributed" or k.startswith("wait/")
-    )
-    assert out["waits_plus_unattributed_s"] == pytest.approx(unworked * 1e-9)
-    assert out["unattributed_s"] == pytest.approx(parts.get("unattributed", 0) * 1e-9)
+    out = idle_spans.idle_by_span(_planes(threads), "tpu")
+    assert sum(out[f"idle_s.{x}"] for x in idle_spans.NAMES) == pytest.approx(80e-9)
+    assert _by_span(out) == {k: pytest.approx(v * 1e-9) for k, v in parts.items()}
+    assert max(_by_span(out).items(), key=lambda kv: kv[1])[0] == want
+    assert set(out["idle_by_stmt"]) == {f"q|{k}" for k in parts}
+    for x in idle_spans.NAMES:
+        held = sum(v for k, v in parts.items() if k.split("/", 1)[0] == x)
+        assert out[f"idle_s.{x}"] == pytest.approx(held * 1e-9), x
+    assert out["presto_events"] == sum(len(t) for t in threads)
 
 
 def test_trace_gaps_splits_gaps_between_spans():
@@ -362,13 +431,137 @@ def test_trace_gaps_splits_gaps_between_spans():
         [[("exec/task", 10, 40), ("wait/worker.output_buffer", 50, 90)]],
         ops=((0, 10), (40, 50), (90, 100)),
     )
-    out = trace_gaps.analyse(planes, "tpu")
-    assert dict(out["sums"]) == {
+    out = idle_spans.idle_by_span(planes, "tpu")
+    assert _by_span(out) == {
         "exec/task": pytest.approx(30e-9),
         "wait/worker.output_buffer": pytest.approx(40e-9),
     }
-    assert out["longest"][0][0] == "wait/worker.output_buffer"
-    assert out["unattributed_s"] == 0.0
+    assert out["idle_s.exec"] == pytest.approx(30e-9)
+    assert out["idle_s.wait"] == pytest.approx(40e-9)
+    assert out["idle_s.untraced"] == 0.0
+
+
+def _busy_trace():
+    """Two devices, three threads, statements back to back: spans that
+    nest, overlap across threads and end inside gaps."""
+    spans = [
+        [("protocol/client", 0, 1000), ("wait/client.http", 10, 990)],
+        [("exec/task", 50, 400), ("dispatch", 60, 80), ("fetch/control", 90, 300),
+         ("staging/stage_column", 420, 480), ("exec/task", 500, 900),
+         ("dispatch", 510, 530), ("fetch/control", 540, 870)],
+        [("schedule", 30, 45), ("plan", 600, 640), ("wait/coordinator.pull", 650, 700)],
+    ]
+    planes = _planes(spans, ops=((70, 95), (300, 330), (700, 760)))
+    planes["/host:CPU"]["main#0"] = [("bench:window", 0.0, 1000.0), ("stmt:q1", 0.0, 480.0),
+                                     ("stmt:q6", 480.0, 1000.0), ("PjitFunction(f)", 60.0, 75.0)]
+    planes["/device:TPU:1"] = {
+        "XLA Modules": [("jit_y(2)", 100.0, 900.0)],
+        "XLA Ops": [("%g = g()", 100.0, 250.0), ("%g = g()", 800.0, 890.0)],
+    }
+    return planes
+
+
+def _without_spans(planes):
+    return {p: {ln: [e for e in evs if not e[0].startswith(idle_spans.PREFIX)]
+                for ln, evs in lines.items()} for p, lines in planes.items()}
+
+
+def test_the_nine_sum_to_the_idle_time_and_need_a_span():
+    planes = _busy_trace()
+    red = reduce.reduce_trace(planes, "tpu")
+    out = idle_spans.idle_by_span(planes, "tpu")
+    idle_s = red["window_s"] - red["busy_s"]  # per device, averaged
+    assert sum(out[f"idle_s.{x}"] for x in idle_spans.NAMES) == pytest.approx(idle_s)
+    assert sum(out["idle_by_stmt"].values()) == pytest.approx(idle_s)
+    assert {k.split("|")[0] for k in out["idle_by_stmt"]} <= {"q1", "q6"}
+    assert out["presto_events"] == 12
+    assert idle_spans.idle_by_span(_without_spans(planes), "tpu") == {}
+
+
+def test_spans_leave_the_device_numbers_and_relabel_the_gaps():
+    """The spans add host events. The device's numbers and the idle gaps'
+    times and statements stay what they were; ``reduce.py`` counts the
+    spans as JAX calls, so a gap they cover reads ``in-jax-runtime``
+    where it read ``outside-jax`` (the ``breakdown``'s split is lost
+    until ``reduce.py`` leaves them out)."""
+    planes = _busy_trace()
+    got = reduce.reduce_trace(planes, "tpu", top=100)
+    bare = reduce.reduce_trace(_without_spans(planes), "tpu", top=100)
+    for k in ("window_s", "busy_s", "devices", "events", "programs", "device_ops"):
+        assert got[k] == bare[k], k
+
+    def gaps(red):
+        return sorted((k[len("gap:"):].split("/")[0], v)
+                      for k, v in red["idle_gaps"] if k.startswith("gap:"))
+
+    def by_stmt(red):
+        out = {}
+        for k, v in red["idle_gaps"]:
+            if k.startswith("sum:"):
+                stmt = k[len("sum:"):].split("/")[0]
+                out[stmt] = out.get(stmt, 0.0) + v
+        return out
+
+    assert gaps(got) == gaps(bare)
+    assert by_stmt(got) == pytest.approx(by_stmt(bare))
+    split = {k.split("/")[-1] for k, _ in got["idle_gaps"]}
+    assert split == {reduce.IN_JAX}
+    assert reduce.OUTSIDE_JAX in {k.split("/")[-1] for k, _ in bare["idle_gaps"]}
+
+
+def test_layer_metrics_read_the_nine(tmp_path, monkeypatch):
+    cell = discovery.load_cell(ROOT, "sf1_scan_agg")
+    names = [f"idle_{x}_ms_per_stmt.pass" for x in idle_spans.NAMES]
+    assert names == [m.name for m in cell.per_layer if m.name.startswith("idle_")]
+    fields = {f"idle_s.{x}": 0.001 * (i + 1) for i, x in enumerate(idle_spans.NAMES)}
+    obs = {"trace": dict(fields, window_s=1.0, busy_s=0.5), "stmts": 4,
+           "input_bytes": 0, "counters": {}, "peak": {}}
+    got = harness._layer_metrics(cell, obs)
+    for i, name in enumerate(names):
+        assert got[name] == {"value": pytest.approx(0.25 * (i + 1)), "unit": "ms/stmt"}
+    # no field and no trace of this run's window: all nine left out
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    obs["trace"] = {"window_s": 1.0, "busy_s": 0.5}
+    assert not set(names) & set(harness._layer_metrics(cell, obs))
+    assert obs["idle_spans"] == {}  # looked for once, not nine times
+
+
+def test_the_readers_find_the_runs_own_trace(tmp_path, monkeypatch):
+    """The path a traced run takes: a real profile in a ``bench_trace_*``
+    directory of the temporary directory, its window annotated as the
+    harness does, reduced by ``reduce.py``; the nine readers find it by
+    the window's length and sum to its idle time."""
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+    f = jax.jit(lambda x: jnp.cumsum(x * 2.0 + 1.0))
+    x = jnp.ones(1 << 16)
+    f(x).block_until_ready()  # compiled before the window, as the warm-up does
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(reduce.WINDOW):
+            for _ in range(3):
+                with jax.profiler.TraceAnnotation(reduce.STMT + "q"), \
+                        tracing.phase("exec", site="task"):
+                    with tracing.phase("dispatch"):
+                        y = f(x)
+                    with tracing.phase("fetch"):
+                        jax.device_get(y)
+                time.sleep(0.002)  # between statements: no span
+    finally:
+        jax.profiler.stop_trace()
+    red = reduce.reduce_trace(reduce.load_planes(reduce.find_xplane(trace_dir)), "cpu")
+    assert red["busy_s"] > 0
+    cell = discovery.load_cell(ROOT, "sf1_scan_agg")
+    obs = {"trace": dict(red), "stmts": 3, "input_bytes": 0, "counters": {}, "peak": {}}
+    got = harness._layer_metrics(cell, obs)
+    names = [f"idle_{x}_ms_per_stmt.pass" for x in idle_spans.NAMES]
+    assert set(names) <= set(got)
+    idle_ms = 1e3 * (red["window_s"] - red["busy_s"]) / 3
+    assert sum(got[n]["value"] for n in names) == pytest.approx(idle_ms, rel=1e-9)
+    assert got["idle_untraced_ms_per_stmt.pass"]["value"] >= 1.5  # the sleeps
+    assert obs["idle_spans"]["presto_events"] == 9
 
 
 # ------------------------------------------------------------ static check
