@@ -57,7 +57,12 @@ from presto_tpu.ops import (
     window as window_op,
 )
 from presto_tpu.ops.aggregation import record_row_moves
-from presto_tpu.ops.join import JoinBuild, cut_build, join_build
+from presto_tpu.ops.join import (
+    JoinBuild,
+    cut_build,
+    join_build,
+    record_probe_lanes,
+)
 from presto_tpu.page import (
     Block,
     Page,
@@ -1453,7 +1458,7 @@ class LocalQueryRunner:
                 leaf = self._execute_to_leaf(
                     N.JoinBuildNode(
                         node.right, tuple(node.right_keys),
-                        tuple(node.payload),
+                        tuple(node.payload), node.key_ranges,
                     ),
                     pages_map,
                 )
@@ -1490,8 +1495,9 @@ class LocalQueryRunner:
 
         The fourth cell returned (``_Tally``) holds the slots of each
         join build the program sorts itself (its build side is no
-        prepared leaf: :meth:`prepare_builds`) and the row moves of its
-        grouped aggregations, filled when it is traced."""
+        prepared leaf: :meth:`prepare_builds`), the row moves of its
+        grouped aggregations and the key lanes its join probes sort,
+        filled when it is traced."""
         from presto_tpu.plan import canonical
 
         msgs_cell: List[str] = []
@@ -1515,12 +1521,14 @@ class LocalQueryRunner:
             dyn: List = []
             _t.builds.clear()
             with canonical.active_params(params_in):
-                with record_row_moves() as moves:
+                with record_row_moves() as moves, \
+                        record_probe_lanes() as lanes:
                     out = _execute_node(
                         _root, pages_in, _ids, flags, errors,
                         counters, dyn, count_all=analyzed, builds=_t.builds,
                     )
                 _t.agg_row_moves = moves[0]
+                _t.join_probe_lanes = lanes[0]
                 # program boundary: host materialization /
                 # exchanges need prefix form (lazy selection
                 # masks stop here). num_valid is the TRUE live
@@ -2087,11 +2095,13 @@ class LocalQueryRunner:
             compile_ms = (t_disped - t_disp) * 1000.0 if fresh else 0.0
             DEVICE.count_dispatch()
             DEVICE.count_program_out(_static_page_nbytes(page))
-            # the builds the program sorts itself and the rows its
-            # aggregations move, once a dispatch
+            # the builds the program sorts itself, the rows its
+            # aggregations move and the key lanes its probes sort, once a
+            # dispatch
             for slots in resolved.tally.builds:
                 DEVICE.count_join_build(slots)
             DEVICE.count_agg_row_moves(resolved.tally.agg_row_moves)
+            DEVICE.count_join_probe_lanes(resolved.tally.join_probe_lanes)
             if fresh:
                 DEVICE.count_compile(compile_ms)
             self._fold_device_stat(
@@ -2911,11 +2921,13 @@ class _Resolved:
 class _Tally:
     """What a program's trace counted (``LocalQueryRunner._make_trace``)
     for ``_dispatch`` to add at each dispatch: the slots of each join
-    build the program sorts itself, and the page-sized row moves of its
-    grouped aggregations (``ops.aggregation.record_row_moves``)."""
+    build the program sorts itself, the page-sized row moves of its
+    grouped aggregations (``ops.aggregation.record_row_moves``) and the
+    key lanes its join probes sort (``ops.join.record_probe_lanes``)."""
 
     builds: List[int] = dataclasses.field(default_factory=list)
     agg_row_moves: int = 0
+    join_probe_lanes: int = 0
 
 
 @dataclasses.dataclass
@@ -3077,13 +3089,22 @@ def _execute_node_inner(
         flags.append(overflow)
         return out
     if isinstance(node, N.JoinBuildNode):
-        return join_build(run(node.source), node.keys, node.payload)
+        build = join_build(
+            run(node.source), node.keys, node.payload, node.key_ranges
+        )
+        if build.stale is not None:
+            # a key left its stated range: built again on two lanes
+            # (_scale_capacities), before any batch probes it
+            flags.append(build.stale)
+        return build
     if isinstance(node, N.JoinNode):
         probe = run(node.left)
         build = run(node.right)
         if isinstance(build, Page):
             # not prepared for this program (prepare_builds): built here
-            build = join_build(build, node.right_keys, node.payload)
+            build = join_build(
+                build, node.right_keys, node.payload, node.key_ranges
+            )
             if builds is not None:
                 builds.append(build.capacity)
         out, overflow = hash_join(
@@ -3243,6 +3264,7 @@ def _substitute_params_node(node: N.PlanNode, bindings) -> N.PlanNode:
         elif isinstance(v, tuple) and v and isinstance(v[0], tuple):
             nt = []
             for item in v:
+                # (a key's range in ``key_ranges`` may be None)
                 nt.append(
                     tuple(
                         _substitute_params_expr(x, bindings)
@@ -3250,6 +3272,8 @@ def _substitute_params_node(node: N.PlanNode, bindings) -> N.PlanNode:
                         else x
                         for x in item
                     )
+                    if isinstance(item, tuple)
+                    else item
                 )
             changes[f.name] = tuple(nt)
         elif isinstance(v, tuple):
@@ -3296,9 +3320,13 @@ def _scale_capacities(node: N.PlanNode, factor: int) -> N.PlanNode:
             )
     if isinstance(node, (N.AggregationNode, N.DistinctNode)):
         changes["max_groups"] = node.max_groups * factor
-    if isinstance(node, N.AggregationNode) and node.key_ranges:
+    if (
+        isinstance(node, (N.AggregationNode, N.JoinNode, N.JoinBuildNode))
+        and node.key_ranges
+    ):
         # the overflow may be a key outside its stated range
-        # (ops.aggregation._key_lanes): run again with nothing stated
+        # (ops.aggregation._key_lanes, ops.join._key_of): run again with
+        # nothing stated
         changes["key_ranges"] = ()
     if (
         isinstance(node, (N.JoinNode, N.CrossJoinNode, N.UnnestNode))

@@ -10,7 +10,8 @@ The build side is *sorted by key* (XLA sort), and every probe row finds
 its match range ``[lo, hi)`` in that order by RANK, not by search
 (``_match_ranges``): build and probe keys are sorted together, build
 rows leading every tie, and a running count of the build rows is the
-range — three sort passes, a cumsum and a cummax, no loop and no gather.
+range — three sort passes (two on one lane, below), a cumsum and a
+cummax, no loop and no gather.
 The binary search it replaced (two ``jnp.searchsorted`` a join) is a
 loop of log2(n) rounds, each a gather of one emulated-int64 key a probe
 row, and on the v5e a gather is the dear primitive: ~28 ns an element,
@@ -36,17 +37,37 @@ batch's program as an argument (``exec/local_runner.py:
 prepare_builds``); a build that depends on the batch runs the same
 function inside the batch's program.
 
-Keys are single int64 columns; the planner packs two int32-representable
-key columns bijectively via ``pack_keys`` (wider composites: future
-round). NULL keys never match (SQL equi-join); anti join keeps unmatched
-probe rows (NOT EXISTS semantics — NOT IN null handling is a planner
-rewrite). Join keys of exactly int64-max are unsupported (sentinel);
+Keys ride ONE uint32 lane wherever their values are proved to fit it
+(``_lane_ranges``): each key column a dictionary's ids, a boolean, or an
+integer or date column with the inclusive range the connector's
+statistics state for it (``JoinNode.key_ranges``, the build side's), two
+columns as the mixed-radix digits ``(a - lo_a) * n_b + (b - lo_b)`` while
+the product of their sizes stays under ``_NONE`` (0xFFFFFFFF). A dead
+row, a NULL key and a probe key outside the ranges carry ``_NONE`` and
+rank among the build's dead rows, past ``nb``: they match nothing, in
+every join type. The key is then sorted in one pass, not two: the build's
+sort is one pass, the probe's two (``_match_ranges``). A stated range is
+checked against every build: a live build key outside it sets the
+build's ``stale``, which ``hash_join`` reports as ``overflow``, and the
+executor runs the batch again with nothing stated
+(``local_runner._scale_capacities``; a prepared build's own program
+reports it, so it is built again on two lanes): statistics gone stale
+cost a re-run, never an answer. Any other key is an int64 — one column,
+or two int32-representable columns packed bijectively by ``pack_keys``
+(wider composites are packed by the planner) — sorted as two uint32
+lanes, the program a join with no proof has always compiled. NULL keys
+never match (SQL equi-join); anti join keeps unmatched probe rows (NOT
+EXISTS semantics — NOT IN null handling is a planner rewrite). Join keys
+of exactly int64-max are unsupported on two lanes (sentinel);
 unreachable for real workloads.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
+import threading
 from functools import partial
 from typing import List, Optional, Sequence, Tuple, Union
 
@@ -64,14 +85,71 @@ from presto_tpu.page import Block, Page
 
 _I64_MAX = jnp.iinfo(jnp.int64).max
 
+#: the one lane's value for a row no probe key can match (dead, a NULL
+#: key, a key outside the range): past every packed key
+_NONE = 0xFFFFFFFF
+
+#: trace-time tally of the key lanes join probes sort
+#: (``record_probe_lanes``)
+_LANES = threading.local()
+
+
+@contextlib.contextmanager
+def record_probe_lanes():
+    """Count the key lanes that the join probes traced inside the block
+    sort: one where the keys ride one uint32 lane, two where they do not.
+    Yields a one-item list that holds the count
+    (``device.join_probe_lanes``)."""
+    cell = [0]
+    prev = getattr(_LANES, "cell", None)
+    _LANES.cell = cell
+    try:
+        yield cell
+    finally:
+        _LANES.cell = prev
+
 
 def pack_keys(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Bijectively pack two int32-representable key columns into int64."""
     return (a.astype(jnp.int64) << 32) | (b.astype(jnp.int64) & 0xFFFFFFFF)
 
 
-def _key_of(page: Page, key_cols: Sequence[str]) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(int64 key, ok-mask) for live rows with non-null key columns."""
+def _lane_ranges(
+    page: Page, key_cols: Sequence[str], key_ranges: Sequence = ()
+) -> tuple:
+    """Per key column ``(lo, n, stated)``: the ``n`` values from ``lo``
+    that it is proved to take (a dictionary's ids, a boolean's two) or
+    that the connector's statistics state for it (``key_ranges``, per
+    column an inclusive ``(lo, hi)`` or None: ``JoinNode.key_ranges``).
+    ``()`` where a column has neither, or where the product of the
+    sizes leaves no room below ``_NONE``: the key then takes two
+    lanes."""
+    stated = list(key_ranges) + [None] * (len(key_cols) - len(key_ranges))
+    out = []
+    for name, rng in zip(key_cols, stated):
+        blk = page.block(name)
+        if blk.dtype.is_string and blk.dictionary is not None:
+            out.append((0, len(blk.dictionary.values), False))
+        elif blk.dtype.name == "boolean":
+            out.append((0, 2, False))
+        elif rng is not None:
+            out.append((int(rng[0]), int(rng[1]) - int(rng[0]) + 1, True))
+        else:
+            return ()
+    if math.prod(n for _, n, _ in out) > _NONE:
+        return ()
+    return tuple(out)
+
+
+def _key_of(
+    page: Page, key_cols: Sequence[str], ranges: tuple = ()
+) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
+    """The key of every row, whether the row can match, and whether a
+    stated range left out a live row with no NULL key column (None
+    without ``ranges``). Without ``ranges`` (``_lane_ranges``) the key is
+    int64 and a row can match where it is live with no NULL key column;
+    with them it is one uint32 lane, each column a mixed-radix digit
+    ``value - lo``, and a row can match only inside every range."""
     ok = page.row_mask()
     datas = []
     widths = []
@@ -97,6 +175,16 @@ def _key_of(page: Page, key_cols: Sequence[str]) -> Tuple[jnp.ndarray, jnp.ndarr
         widths.append(blk.dtype.np_dtype.itemsize)
         if blk.valid is not None:
             ok = ok & blk.valid
+    if ranges:
+        lane, inside, outside = None, ok, jnp.asarray(False)
+        for d, (lo, n, stated) in zip(datas, ranges):
+            fits = (d >= lo) & (d < lo + n)
+            if stated:
+                outside = outside | jnp.any(ok & ~fits)
+            inside = inside & fits
+            digit = (d - lo).astype(jnp.uint32)
+            lane = digit if lane is None else lane * jnp.uint32(n) + digit
+        return lane, inside, outside
     if len(datas) == 1:
         key = datas[0]
     elif len(datas) == 2:
@@ -113,7 +201,21 @@ def _key_of(page: Page, key_cols: Sequence[str]) -> Tuple[jnp.ndarray, jnp.ndarr
         raise NotImplementedError(
             ">2 join key columns (pack wider composites in the planner)"
         )
-    return key, ok
+    return key, ok, None
+
+
+def _unmatchable(key: jnp.ndarray):
+    """The key of a row nothing can match: it sorts after every other."""
+    return jnp.uint32(_NONE) if key.dtype == jnp.uint32 else _I64_MAX
+
+
+def _lanes(key: jnp.ndarray) -> List[jnp.ndarray]:
+    """A join key as the uint32 lanes its sort compares, least
+    significant first: itself where it is one lane already, else an
+    int64's two."""
+    if key.dtype == jnp.uint32:
+        return [key]
+    return _u32_lanes(key.astype(jnp.int64), False)
 
 
 def _mask_out(page: Page, keep: jnp.ndarray) -> Page:
@@ -141,16 +243,20 @@ def _match_ranges(
     running maximum of the counts at the runs' first rows, which never
     decrease — is ``lo``. A sort on the origin brings both back to
     probe order (a sort, not a scatter: a scatter costs what a gather
-    costs)."""
+    costs).
+
+    The keys are int64, sorted as two uint32 lanes (three sort passes in
+    all), or both sides one uint32 lane already (``_key_of`` with
+    ranges: two passes, the key sort's operands two, not three)."""
     nb = build_keys.shape[0]
-    keys = jnp.concatenate([build_keys, probe_keys]).astype(jnp.int64)
+    keys = jnp.concatenate([build_keys, probe_keys])
     origin = jnp.arange(keys.shape[0], dtype=jnp.int32)
-    (low, high), (origin,) = sort_u32_lanes(
-        _u32_lanes(keys, False), (origin,)
-    )
+    lanes, (origin,) = sort_u32_lanes(_lanes(keys), (origin,))
     is_build = (origin < nb).astype(jnp.int32)
     hi = jnp.cumsum(is_build)
-    change = (low[1:] != low[:-1]) | (high[1:] != high[:-1])
+    change = lanes[0][1:] != lanes[0][:-1]
+    for lane in lanes[1:]:
+        change = change | (lane[1:] != lane[:-1])
     first = jnp.concatenate([jnp.ones((1,), jnp.bool_), change])
     lo = jax.lax.cummax(jnp.where(first, hi - is_build, 0))
     _, lo, hi = jax.lax.sort((origin, lo, hi), num_keys=1)
@@ -159,24 +265,30 @@ def _match_ranges(
 
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=["keys", "nb", "live", "page"],
-    meta_fields=["key_dicts"],
+    data_fields=["keys", "nb", "live", "page", "stale"],
+    meta_fields=["key_dicts", "ranges"],
 )
 @dataclasses.dataclass
 class JoinBuild:
     """A join's build side in key order (:func:`join_build`): all that
-    the probe and the output steps read. ``keys`` are ascending int64; a
-    row whose key is null, or that is not live, carries the sentinel and
-    sorts last, so the first ``nb`` rows are those a probe key can match.
-    ``live`` is the build's row mask and ``page`` its payload columns,
-    both in the keys' order. ``key_dicts``: (is string, dictionary) of
-    each key column, for the probe side's dictionary check."""
+    the probe and the output steps read. ``keys`` are ascending: int64,
+    or one uint32 lane where ``ranges`` (``_lane_ranges``) prove the
+    key columns fit it; a row whose key is null, or that is not live,
+    carries the sentinel and sorts last, so the first ``nb`` rows are
+    those a probe key can match. ``live`` is the build's row mask and
+    ``page`` its payload columns, both in the keys' order. ``stale``
+    (one lane only, else None): a live row's key left a stated range, so
+    the join is reported as overflowed. ``key_dicts``: (is string,
+    dictionary) of each key column, for the probe side's dictionary
+    check."""
 
     keys: jnp.ndarray
     nb: jnp.ndarray
     live: jnp.ndarray
     page: Page
+    stale: Optional[jnp.ndarray] = None
     key_dicts: tuple = ()
+    ranges: tuple = ()
 
     @property
     def capacity(self) -> int:
@@ -189,16 +301,22 @@ class JoinBuild:
 
 
 def join_build(
-    build: Page, build_keys: Sequence[str], build_payload: Sequence[str] = ()
+    build: Page,
+    build_keys: Sequence[str],
+    build_payload: Sequence[str] = (),
+    key_ranges: Sequence = (),
 ) -> JoinBuild:
-    """The build side sorted by key: the keys' two uint32 lanes sorted
-    with the row mask and the payload's values and validity riding along
-    (no gather by the sort's permutation). Dead rows sort last like null
-    keys, so the sort is the build's compaction too."""
+    """The build side sorted by key: the key's lanes (one where
+    ``_lane_ranges`` proves it fits, from ``key_ranges`` and the key
+    columns, two otherwise) sorted with the row mask and the payload's
+    values and validity riding along (no gather by the sort's
+    permutation). Dead rows sort last like null keys, so the sort is the
+    build's compaction too."""
     for name in build_payload:
         _check_flat(build.block(name), name)
+    ranges = _lane_ranges(build, build_keys, key_ranges)
     with jax.named_scope("join_build"):
-        bk, b_ok = _key_of(build, build_keys)
+        bk, b_ok, stale = _key_of(build, build_keys, ranges)
         blocks = [build.block(c) for c in build_payload]
         arrays = [build.row_mask()]
         for b in blocks:
@@ -210,15 +328,18 @@ def join_build(
             for col in (a.reshape(a.shape[0], -1).T if a.ndim > 1 else [a])
         ]
         # unmatchable rows carry the sentinel and sort last
-        (low, high), moved = sort_u32_lanes(
-            _u32_lanes(jnp.where(b_ok, bk, _I64_MAX), False), rides
+        lanes, moved = sort_u32_lanes(
+            _lanes(jnp.where(b_ok, bk, _unmatchable(bk))), rides
         )
-        keys = (
-            jax.lax.bitcast_convert_type(high ^ _SIGN32, jnp.int32).astype(
-                jnp.int64
-            )
-            << 32
-        ) | low.astype(jnp.int64)
+        if ranges:
+            (keys,) = lanes
+        else:
+            low, high = lanes
+            keys = (
+                jax.lax.bitcast_convert_type(high ^ _SIGN32, jnp.int32)
+                .astype(jnp.int64)
+                << 32
+            ) | low.astype(jnp.int64)
         moved = iter(moved)
 
         def back(a):
@@ -242,10 +363,12 @@ def join_build(
             page=Page(
                 blocks=sorted_blocks, num_valid=nb, names=tuple(build_payload)
             ),
+            stale=stale,
             key_dicts=tuple(
                 (build.block(c).dtype.is_string, build.block(c).dictionary)
                 for c in build_keys
             ),
+            ranges=ranges,
         )
 
 
@@ -280,15 +403,20 @@ def hash_join(
     build_unique: bool = False,
     out_capacity: Optional[int] = None,
     payload_rename: Optional[dict] = None,
+    key_ranges: Sequence = (),
 ) -> Tuple[Page, jnp.ndarray]:
     """Join ``probe`` with ``build`` on equality of packed keys.
 
     Returns (result, overflow). Result columns = all probe columns plus
     ``build_payload`` columns (optionally renamed via ``payload_rename``).
     join_type: inner | left | full | semi | anti. ``build`` is a page, or
-    that page already put in key order with the same keys and payload
-    (:func:`join_build`, then :func:`cut_build` at no fewer than its
-    ``nb`` rows — all of them for FULL); the result is the same.
+    that page already put in key order with the same keys, payload and
+    ``key_ranges`` (:func:`join_build`, then :func:`cut_build` at no
+    fewer than its ``nb`` rows — all of them for FULL); the result is
+    the same. The probe keys take the build's lanes: on one lane a probe
+    key outside the build's ranges matches nothing. ``overflow`` is also
+    set where the build's ``stale`` is: the caller runs again with
+    nothing stated.
 
     FULL OUTER executes as left outer plus an appended section of
     unmatched build rows (probe columns NULL) — the appended section
@@ -298,7 +426,7 @@ def hash_join(
     build_payload = list(build_payload or [])
     payload_rename = payload_rename or {}
     if isinstance(build, Page):
-        build = join_build(build, build_keys, build_payload)
+        build = join_build(build, build_keys, build_payload, key_ranges)
 
     for pc, (b_string, b_dict) in zip(probe_keys, build.key_dicts):
         pb = probe.block(pc)
@@ -310,22 +438,26 @@ def hash_join(
                 "dictionaries: planner must re-encode first"
             )
 
-    pk, p_ok = _key_of(probe, probe_keys)
+    pk, p_ok, _ = _key_of(probe, probe_keys, build.ranges)
     nb = build.nb
     last = build.capacity - 1
+    stale = jnp.asarray(False) if build.stale is None else build.stale
 
     with jax.named_scope("join_probe"):
-        pk_eff = jnp.where(p_ok, pk, _I64_MAX)
+        pk_eff = jnp.where(p_ok, pk, _unmatchable(pk))
+        cell = getattr(_LANES, "cell", None)
+        if cell is not None:
+            cell[0] += 1 if build.ranges else 2
         lo, hi = _match_ranges(build.keys, pk_eff)
         lo = jnp.minimum(lo, nb)
         hi = jnp.minimum(hi, nb)
         m = jnp.where(p_ok, hi - lo, 0)  # matches per probe row
 
     if join_type == "semi":
-        return _mask_out(probe, m > 0), jnp.asarray(False)
+        return _mask_out(probe, m > 0), stale
     if join_type == "anti":
         keep = (m == 0) & probe.row_mask()
-        return _mask_out(probe, keep), jnp.asarray(False)
+        return _mask_out(probe, keep), stale
 
     with jax.named_scope("join_output"):
         if build_unique:
@@ -345,7 +477,7 @@ def hash_join(
             )
             if join_type == "inner":
                 keep = matched & probe.row_mask()
-                return _mask_out(out, keep), jnp.asarray(False)
+                return _mask_out(out, keep), stale
             # left/full outer keep every probe row: positional layout, so
             # the probe's own liveness (mask or prefix) carries over
             out = dataclasses.replace(out, live=probe.live)
@@ -353,7 +485,7 @@ def hash_join(
                 out = _append_unmatched_build(
                     out, pk_eff, p_ok, build, build_payload, payload_rename,
                 )
-            return out, jnp.asarray(False)
+            return out, stale
 
         # general duplicate-capable expansion
         if out_capacity is None:
@@ -367,6 +499,8 @@ def hash_join(
             total[-1] if probe.capacity else jnp.asarray(0, jnp.int64)
         )
         overflow = out_count > out_capacity
+        if build.stale is not None:
+            overflow = overflow | stale
 
         j = jnp.arange(out_capacity, dtype=jnp.int64)
         _, p_idx = _match_ranges(total, j)  # searchsorted(..., "right")

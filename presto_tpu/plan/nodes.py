@@ -20,6 +20,20 @@ from presto_tpu.ops.sort import SortKey
 from presto_tpu.ops.window import WindowCall
 
 
+def _repr_proved(node: "PlanNode") -> str:
+    """The dataclass repr of a node with ``key_ranges``, the field left
+    out where it proves nothing: the repr is the fingerprint that names
+    the compiled program, in this process and in the persistent compile
+    cache, so such a node reads as it did before the field existed."""
+    shown = ", ".join(
+        f"{f.name}={getattr(node, f.name)!r}"
+        for f in dataclasses.fields(node)
+        if f.name != "key_ranges"
+        or any(r is not None for r in node.key_ranges)
+    )
+    return f"{type(node).__name__}({shown})"
+
+
 class PlanNode:
     def output_schema(self) -> Dict[str, T.DataType]:
         raise NotImplementedError
@@ -135,6 +149,13 @@ class JoinNode(PlanNode):
     build_unique: bool = False
     out_capacity: Optional[int] = None  # None: planner fills from stats
     residual: Optional[Expr] = None  # non-equi conjuncts applied post-join
+    #: per build key, the inclusive ``(lo, hi)`` the connector's column
+    #: statistics state for its values (``optimizer.key_ranges``), or
+    #: None; ``()`` states nothing. The kernel sorts keys proved to fit
+    #: one uint32 lane on it (``ops.join._lane_ranges``)
+    key_ranges: Tuple[Optional[Tuple[int, int]], ...] = ()
+
+    __repr__ = _repr_proved
 
     def output_schema(self):
         out = dict(self.left.output_schema())
@@ -159,6 +180,9 @@ class JoinBuildNode(PlanNode):
     source: PlanNode
     keys: Tuple[str, ...]
     payload: Tuple[str, ...]
+    key_ranges: Tuple[Optional[Tuple[int, int]], ...] = ()  # JoinNode's
+
+    __repr__ = _repr_proved
 
     def output_schema(self):
         return self.source.output_schema()

@@ -94,6 +94,17 @@ def key_ranges(source: N.PlanNode, group_keys, catalogs) -> tuple:
     return tuple(out) if any(r is not None for r in out) else ()
 
 
+def join_key_ranges(node: N.JoinNode, catalogs) -> tuple:
+    """``JoinNode.key_ranges``: ``key_ranges`` of the build side's key
+    columns (a probe key outside them cannot match)."""
+    schema = node.right.output_schema()
+    return key_ranges(
+        node.right,
+        [(c, E.ColumnRef(c, schema[c])) for c in node.right_keys],
+        catalogs,
+    )
+
+
 def _conjuncts_of(e: E.Expr) -> List[E.Expr]:
     if isinstance(e, E.And):
         out: List[E.Expr] = []

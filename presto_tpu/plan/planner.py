@@ -171,7 +171,21 @@ class _Planner:
         if not isinstance(stmt, ast.Select):
             raise PlanningError(f"cannot plan {type(stmt).__name__}")
         node, scope, names = self.plan_select(stmt, outer=None)
-        return Plan(root=node, params=self.params, output_names=names)
+        return Plan(
+            root=self._state_join_ranges(node), params=self.params,
+            output_names=names,
+        )
+
+    def _state_join_ranges(self, node: N.PlanNode) -> N.PlanNode:
+        """Every join of the finished tree with ``key_ranges``: what the
+        connector's statistics state for its build keys
+        (``optimizer.join_key_ranges``)."""
+        node = N.map_children(node, self._state_join_ranges)
+        if isinstance(node, N.JoinNode):
+            ranges = optimizer.join_key_ranges(node, self.catalogs)
+            if ranges:
+                node = dataclasses.replace(node, key_ranges=ranges)
+        return node
 
     # ---------------------------------------------------------- SELECT core
 

@@ -127,6 +127,8 @@ class DeviceTelemetry:
         self._join_build_slots = REGISTRY.counter("device.join_build_slots")
         # page-sized row moves of the grouped aggregations a program runs
         self._agg_row_moves = REGISTRY.counter("device.agg_row_moves")
+        # key lanes the join probes of a program sort
+        self._join_probe_lanes = REGISTRY.counter("device.join_probe_lanes")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -200,6 +202,15 @@ class DeviceTelemetry:
         nothing is read from the device."""
         if self.enabled and n:
             self._agg_row_moves.update(int(n))
+
+    def count_join_probe_lanes(self, n: int) -> None:
+        """The key lanes that the join probes of one dispatched program
+        sort — one a probe whose keys ride one uint32 lane, two one
+        whose keys do not — as its trace counted them
+        (``ops.join.record_probe_lanes``): nothing is read from the
+        device."""
+        if self.enabled and n:
+            self._join_probe_lanes.update(int(n))
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -285,7 +296,9 @@ class DeviceTelemetry:
         a program at each dispatch — and their static slots.
         ``agg_row_moves`` counts the page-sized row moves the dispatched
         programs' grouped aggregations issue (0 where no aggregation
-        takes the lane-by-lane branch).
+        takes the lane-by-lane branch). ``join_probe_lanes`` counts the
+        key lanes the dispatched programs' join probes sort (one a probe
+        on one uint32 lane, two on two).
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -313,6 +326,7 @@ class DeviceTelemetry:
             "join_builds": int(self._join_builds.total),
             "join_build_slots": int(self._join_build_slots.total),
             "agg_row_moves": int(self._agg_row_moves.total),
+            "join_probe_lanes": int(self._join_probe_lanes.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

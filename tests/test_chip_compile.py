@@ -221,43 +221,59 @@ def _case_join_ranked(join_type, unique):
     return case
 
 
-def _case_join_build(one_chip, mesh):
+#: a stated range for the build's BIGINT key: one uint32 lane
+ONE_LANE = ((1, 100_000),)
+
+
+def _case_join_build(key_ranges=()):
     """A build side put in key order by a program of its own (what a
-    task prepares once for its batches): the keys' lanes sorted with the
+    task prepares once for its batches): the key's lanes sorted with the
     row mask and a payload of every stored type riding (DOUBLE, the
-    long decimal's limbs, BOOLEAN, dictionary ids), the int64 key put
-    back from its lanes by bitcast."""
+    long decimal's limbs, BOOLEAN, dictionary ids); on two lanes the
+    int64 key put back from them by bitcast, on one lane (``key_ranges``)
+    one sort pass and the range check."""
 
-    def fn(build):
-        return join_build(build, ["i"], ["dec", "b", "dbl", "ld", "s"])
+    def case(one_chip, mesh):
+        def fn(build):
+            return join_build(
+                build, ["i"], ["dec", "b", "dbl", "ld", "s"], key_ranges
+            )
 
-    return jax.jit(fn), (
-        _spec(
-            _page(BUILD_CAP, ("i", "dec", "b", "dbl", "ld", "s")), one_chip
-        ),
-    )
-
-
-def _case_join_prepared(one_chip, mesh):
-    """A batch's join over a prepared build: the probe and the output
-    only."""
-    build = jax.eval_shape(
-        lambda b: join_build(b, ["i"], ["dec", "b"]),
-        _page(BUILD_CAP, ("i", "dec", "b")),
-    )
-
-    def fn(probe, prepared):
-        return hash_join(
-            probe, prepared, ["i"], ["i"], "inner",
-            build_payload=["dec", "b"],
-            payload_rename={"dec": "b_dec", "b": "b_b"},
-            build_unique=True,
+        return jax.jit(fn), (
+            _spec(
+                _page(BUILD_CAP, ("i", "dec", "b", "dbl", "ld", "s")),
+                one_chip,
+            ),
         )
 
-    return jax.jit(fn), (
-        _spec(_page(SORT_CAP, ("i", "dbl")), one_chip),
-        _spec(build, one_chip),
-    )
+    return case
+
+
+def _case_join_prepared(key_ranges=()):
+    """A batch's join over a prepared build: the probe and the output
+    only — on one lane (``key_ranges``) the probe keys packed into the
+    build's lane and two sort passes, not three."""
+
+    def case(one_chip, mesh):
+        build = jax.eval_shape(
+            lambda b: join_build(b, ["i"], ["dec", "b"], key_ranges),
+            _page(BUILD_CAP, ("i", "dec", "b")),
+        )
+
+        def fn(probe, prepared):
+            return hash_join(
+                probe, prepared, ["i"], ["i"], "inner",
+                build_payload=["dec", "b"],
+                payload_rename={"dec": "b_dec", "b": "b_b"},
+                build_unique=True,
+            )
+
+        return jax.jit(fn), (
+            _spec(_page(SORT_CAP, ("i", "dbl")), one_chip),
+            _spec(build, one_chip),
+        )
+
+    return case
 
 
 def _case_partition_exchange(one_chip, mesh):
@@ -306,8 +322,10 @@ CASES = {
     "hash_join[unique build]": _case_join_ranked("inner", True),
     "hash_join[duplicate build, left]": _case_join_ranked("left", False),
     "hash_join[duplicate build, full]": _case_join_ranked("full", False),
-    "join_build[prepared once a task]": _case_join_build,
-    "hash_join[prepared build]": _case_join_prepared,
+    "join_build[prepared once a task]": _case_join_build(),
+    "join_build[one lane]": _case_join_build(ONE_LANE),
+    "hash_join[prepared build]": _case_join_prepared(),
+    "join_ranked[one lane]": _case_join_prepared(ONE_LANE),
     "partition_exchange[2x2 mesh]": _case_partition_exchange,
 }
 
@@ -393,6 +411,28 @@ def test_sorted_aggregation_moves_no_row(compiled, name):
     ops = re.findall(r"\s(gather|scatter|sort)\(", out.as_text())
     assert "gather" not in ops and "scatter" not in ops
     assert "sort" in ops
+
+
+@pytest.mark.parametrize("one_lane,two_lanes,sorts", [
+    ("join_ranked[one lane]", "hash_join[prepared build]", (2, 3)),
+    ("join_build[one lane]", "join_build[prepared once a task]", (1, 2)),
+])
+def test_one_lane_join_sorts_one_pass_fewer(
+    compiled, one_lane, two_lanes, sorts
+):
+    """A key with a stated range rides one uint32 lane: the unique probe
+    ranks in two sorts where the int64 key takes three (the lane with
+    the origin; the origin with lo and hi), the build sorts once where
+    it sorts twice, and neither gathers more."""
+    ops = {}
+    for name in (one_lane, two_lanes):
+        out, _ = compiled[name]
+        assert not isinstance(out, Exception), out
+        ops[name] = re.findall(r"\s(gather|scatter|sort)\(", out.as_text())
+    one, two = ops[one_lane], ops[two_lanes]
+    assert (one.count("sort"), two.count("sort")) == sorts
+    assert one.count("gather") <= two.count("gather")
+    assert one.count("scatter") <= two.count("scatter")
 
 
 def test_partition_exchange_program_holds_the_collective(compiled):

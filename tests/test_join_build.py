@@ -4,7 +4,9 @@ that a program of its own sorted by key and another cut to the bucket of
 its matchable rows are those of the same join building in its own
 program, for every join type; and a worker's scan task prepares each
 replicated build once, counted from shapes, with no read of the device a
-batch. Counts and results on the CPU, no times."""
+batch; a stated range gone stale costs a re-run on two lanes, in the
+batch's program or in the prepared build's. Counts and results on the
+CPU, no times."""
 
 import dataclasses
 
@@ -145,3 +147,69 @@ def test_scan_task_prepares_each_build_once(worker):
     assert after["xla_compiles"] == before["xla_compiles"]
     assert run.rows() == _in_program(worker, "1995-03-01")
     assert sum(len(p) for p in run.rows()) > 0
+
+
+@pytest.fixture
+def stale_orderkeys(monkeypatch):
+    """The connector states ``o_orderkey`` in [1, 20,000] where tiny's
+    keys reach 59,976: a build of orders is stale, one of customer not."""
+    from presto_tpu.connectors import tpch
+
+    stats = tpch._TpchMetadata.get_table_stats
+
+    def stale(self, handle):
+        got = stats(self, handle)
+        cols = dict(got.columns)
+        if "o_orderkey" in cols:
+            cols["o_orderkey"] = dataclasses.replace(
+                cols["o_orderkey"], max_value=20_000
+            )
+        return dataclasses.replace(got, columns=cols)
+
+    return lambda: monkeypatch.setattr(
+        tpch._TpchMetadata, "get_table_stats", stale
+    )
+
+
+def _lanes_and_rows(run):
+    """What ``run()`` returns, and the key lanes its join probes sorted."""
+    from presto_tpu.utils.telemetry import device_snapshot
+
+    before = device_snapshot()["join_probe_lanes"]
+    out = run()
+    return device_snapshot()["join_probe_lanes"] - before, out
+
+
+def test_a_stale_range_runs_the_batch_program_again_on_two_lanes(
+    worker, stale_orderkeys
+):
+    """Builds in the batch's program: with the true statistics both
+    probes take one lane (2 a batch); with ``o_orderkey`` stated too
+    narrow each batch overflows and runs again with nothing stated, on
+    two lanes for both (2 + 4 a batch), and answers the same."""
+    date = "1995-03-01"
+    lanes, want = _lanes_and_rows(lambda: _in_program(worker, date))
+    assert lanes == 2 * K
+    stale_orderkeys()
+    lanes, got = _lanes_and_rows(lambda: _in_program(worker, date))
+    assert lanes == (2 + 4) * K
+    assert got == want and sum(len(p) for p in got) > 0
+
+
+def test_a_stale_range_builds_a_prepared_build_again_on_two_lanes(
+    worker, stale_orderkeys
+):
+    """A build prepared once a task: its own program reports the key
+    outside the range with the one read it makes anyway, and runs again
+    with nothing stated; the batches then probe orders on two lanes and
+    customer on one, read the device once each, and answer as the
+    batches that build both sides themselves did before."""
+    date = "1995-03-01"
+    want = _in_program(worker, date)
+    _date_run(worker, date).go()  # stages and compiles
+    stale_orderkeys()
+    lanes, run = _lanes_and_rows(lambda: _date_run(worker, date).go())
+    assert run.error is None and run.task.stats.retries == 1
+    assert lanes == (2 + 1) * K
+    assert run.syncs == K + 2 + 1
+    assert run.rows() == want

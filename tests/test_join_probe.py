@@ -3,8 +3,10 @@ ranges it finds against ``np.searchsorted``, ``hash_join``'s pages
 against a plain reference and, leaf for leaf, against the pages the
 binary-search probe it replaced builds, and the shape of the traced
 program — no loop, and no gather of a probe column where the build is
-unique."""
+unique. Keys with stated ranges on one uint32 lane against their two
+lanes and the reference, and a key outside its range."""
 
+import dataclasses
 import itertools
 
 import jax
@@ -259,3 +261,146 @@ def test_traced_join_has_no_loop(join_type, unique):
     else:
         # (the walk does see a probe column's gather where there is one)
         assert found["gathers"]
+
+
+# ------------------------------------------------------------ one lane
+
+
+#: the build keys' stated ranges: one BIGINT key, or two INTEGER keys
+#: packed as mixed-radix digits
+LANE_RANGES = {
+    "one key with a range": ((-3, 12),),
+    "two packed keys": ((-3, 12), (5, 7)),
+}
+
+
+def _ranged_pages(seed, shape, unique):
+    """A probe of 64 slots against a build of 32, both with NULL keys
+    and dead rows under a mask (and a dead tail): the build's keys in
+    their stated ranges, both ends among them; the probe's a few steps
+    beyond them on either side too."""
+    rng = np.random.default_rng([seed, len(shape), unique])
+    # without a range: the two INTEGER keys, nothing stated
+    (lo, hi), (lo2, hi2) = LANE_RANGES["two packed keys"]
+    two = shape != "one key with a range"
+    kt = T.INTEGER if two else T.BIGINT
+
+    def with_nulls(vals, share):
+        return [None if rng.random() < share else int(v) for v in vals]
+
+    if unique:
+        if two:
+            combos = [(a, b) for a in range(lo, hi + 1)
+                      for b in range(lo2, hi2 + 1)]
+            picked = [combos[i] for i in rng.permutation(len(combos))[:12]]
+        else:
+            picked = [
+                (int(a), lo2) for a in rng.permutation(hi - lo + 1)[:12] + lo
+            ]
+        ends = [(lo, lo2), (hi, hi2 if two else lo2)]
+        pairs = list(dict.fromkeys(ends + picked))
+        a_vals, b_vals = [p[0] for p in pairs], [p[1] for p in pairs]
+    else:
+        a_vals = list(rng.integers(lo, hi + 1, 22)) + [lo, hi]
+        b_vals = list(rng.integers(lo2, hi2 + 1, 22)) + [lo2, hi2]
+    n_b = len(a_vals)
+    build = {"bk": with_nulls(a_vals, 0.15),
+             "w": list(range(100, 100 + n_b)),
+             "wd": [None if i % 5 == 0 else i / 4 for i in range(n_b)]}
+    probe = {"pk": with_nulls(
+        list(rng.integers(lo - 3, hi + 4, 44)) + [lo, hi, lo - 1, hi + 1],
+        0.15,
+    ), "v": [None if i % 7 == 0 else i for i in range(48)]}
+    pschema = {"pk": kt, "v": T.BIGINT}
+    bschema = {"bk": kt, "w": T.BIGINT, "wd": T.DOUBLE}
+    pkeys, bkeys = ["pk"], ["bk"]
+    if two:
+        build["bk2"] = [int(x) for x in b_vals]
+        probe["pk2"] = [int(x) for x in rng.integers(lo2 - 1, hi2 + 2, 48)]
+        pschema["pk2"] = bschema["bk2"] = kt
+        pkeys, bkeys = ["pk", "pk2"], ["bk", "bk2"]
+    probe_page = Page.from_pydict(probe, pschema, capacity=64)
+    build_page = Page.from_pydict(build, bschema, capacity=32)
+
+    def dead_under_mask(page, every):
+        keep = np.arange(page.capacity) < int(page.num_valid)
+        keep &= np.arange(page.capacity) % every != 2
+        return dataclasses.replace(
+            page, live=jnp.asarray(keep),
+            num_valid=jnp.asarray(int(keep.sum()), jnp.int32),
+        )
+
+    return (
+        dead_under_mask(probe_page, 6), dead_under_mask(build_page, 7),
+        pkeys, bkeys, LANE_RANGES.get(shape, ()),
+    )
+
+
+def _ranged_join(probe, build, pkeys, bkeys, join_type, unique, ranges):
+    payload = [] if join_type in ("semi", "anti") else ["w", "wd"]
+    with J.record_probe_lanes() as lanes:
+        out, overflow = jax.jit(lambda p, b: hash_join(
+            p, b, pkeys, bkeys, join_type=join_type, build_payload=payload,
+            build_unique=unique, out_capacity=None if unique else 256,
+            key_ranges=ranges,
+        ))(probe, build)
+    return out, overflow, lanes[0]
+
+
+@pytest.mark.parametrize("shape", ["one key with a range", "two packed keys",
+                                   "no range"])
+@pytest.mark.parametrize("unique", [True, False], ids=["unique", "duplicate"])
+@pytest.mark.parametrize(
+    "join_type", ["inner", "left", "full", "semi", "anti"]
+)
+def test_one_lane_join_equals_two_lanes_and_the_reference(
+    join_type, unique, shape
+):
+    """Keys with stated ranges ride one uint32 lane: every join type
+    gives the rows of the two-lane path, in the same order, and of the
+    nested-loop reference — NULL keys and dead rows on both sides, keys
+    at both ends of the ranges, probe keys beyond them (which match
+    nothing). Without a range the key keeps its two lanes."""
+    probe, build, pkeys, bkeys, ranges = _ranged_pages(5, shape, unique)
+    got, overflow, lanes = _ranged_join(
+        probe, build, pkeys, bkeys, join_type, unique, ranges
+    )
+    was, was_overflow, was_lanes = _ranged_join(
+        probe, build, pkeys, bkeys, join_type, unique, ()
+    )
+    assert (lanes, was_lanes) == ((1 if ranges else 2), 2)
+    assert not bool(overflow) and not bool(was_overflow)
+    assert got.names == was.names
+    assert got.to_pylist() == was.to_pylist()
+    cols = list(got.names)
+    assert sorted(
+        (tuple(r[c] for c in cols) for r in got.to_pylist()), key=repr
+    ) == _reference(probe, build, pkeys, bkeys, join_type)
+
+
+def test_a_build_key_outside_its_stated_range_reads_as_an_overflow():
+    """A live build key beyond the stated range would match nothing: the
+    join reports an overflow, whatever its type, so the host runs it
+    again with nothing stated. NULL keys and dead rows beyond it are no
+    one's business."""
+    probe, build, pkeys, bkeys, _ = _ranged_pages(5, "one key with a range",
+                                                  True)
+    for join_type in ("inner", "left", "semi", "anti"):
+        _, overflow, lanes = _ranged_join(
+            probe, build, pkeys, bkeys, join_type, True, ((-3, 11),)
+        )
+        assert lanes == 1 and bool(overflow), join_type
+    bk = build.block("bk")
+    outside = (np.asarray(bk.data) == 12) & np.asarray(build.row_mask())
+    valid = np.ones(build.capacity, bool) if bk.valid is None else np.asarray(
+        bk.valid
+    )
+    nulled = dataclasses.replace(build, blocks=tuple(
+        dataclasses.replace(b, valid=jnp.asarray(valid & ~outside))
+        if n == "bk" else b
+        for n, b in zip(build.names, build.blocks)
+    ))
+    _, overflow, _ = _ranged_join(
+        probe, nulled, pkeys, bkeys, "inner", True, ((-3, 11),)
+    )
+    assert not bool(overflow)

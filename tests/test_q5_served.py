@@ -81,6 +81,9 @@ def test_q5_served_equals_the_numpy_reference_and_sqlite(
     assert grew["join_builds"] == JOINS * len(tasks)
     assert batches > len(tasks)
     assert grew["join_build_slots"] >= grew["join_builds"] * 1_024
+    # every key of the five joins has a stated range: a batch's probes
+    # sort five lanes, not ten (a task's last batch may be a short one)
+    assert batches <= grew["join_probe_lanes"] / JOINS <= batches + len(tasks)
     assert 0 <= grew["device_syncs"] - batches - grew["join_builds"] <= 4
 
 

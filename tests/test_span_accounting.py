@@ -165,7 +165,8 @@ def test_snapshot_always_has_the_keys_the_benchmark_reads():
                           "xla_cache_loads", "xla_compile_ms",
                           "stage_col_hits", "stage_col_misses",
                           "stage_evictions", "stage_resident_bytes",
-                          "program_out_bytes", "dynamic_filter_wait_expired"]:
+                          "program_out_bytes", "dynamic_filter_wait_expired",
+                          "join_probe_lanes"]:
         assert isinstance(snap[k], (int, float)), k
 
 
