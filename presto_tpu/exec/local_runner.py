@@ -1267,13 +1267,14 @@ class LocalQueryRunner:
         return self._run_with_pages(reduced, leaves, pages)
 
     def leaf_pages(
-        self, root: N.PlanNode, pages_map: Optional[Dict[int, Page]] = None
+        self, root: N.PlanNode, pages_map: Optional[Dict[int, Page]] = None,
+        skip: Optional[N.PlanNode] = None,
     ) -> Tuple[List[N.PlanNode], List[Page]]:
         """Collect a fragment's leaves (scans + remote sources) and
         their input pages: scans load (cached) tables, remote sources
         resolve through ``pages_map`` (id(node) -> already-produced
-        page). The one leaf-resolution path for every fragment
-        executor."""
+        page); ``skip``, a scan the caller stages by batch, gets None.
+        The one leaf-resolution path for every fragment executor."""
         pages_map = pages_map or {}
         leaves = [
             n
@@ -1281,7 +1282,8 @@ class LocalQueryRunner:
             if isinstance(n, (N.TableScanNode, N.RemoteSourceNode))
         ]
         pages = [
-            pages_map[id(n)]
+            None if n is skip
+            else pages_map[id(n)]
             if isinstance(n, N.RemoteSourceNode)
             else self._load_table(n)
             for n in leaves
@@ -1431,6 +1433,7 @@ class LocalQueryRunner:
         root: N.PlanNode,
         partitioned: N.PlanNode,
         pages_map: Optional[Dict[int, Page]] = None,
+        batch_rows: Optional[int] = None,
     ) -> Tuple[N.PlanNode, Dict[int, JoinBuild]]:
         """Compute once what every split batch of ``root`` over the
         ``partitioned`` scan would otherwise build again: the build side
@@ -1445,7 +1448,9 @@ class LocalQueryRunner:
         the leaves' ids: the batch program takes them as arguments and
         runs only the probe and the output of those joins.
         ``pages_map`` resolves the build sides' remote leaves
-        (``leaf_pages``)."""
+        (``leaf_pages``); ``batch_rows`` is a split batch's rows
+        (``page_capacity`` where not given), the most a build side's own
+        join runs over in one program (:meth:`_build_in_batches`)."""
         pages_map = dict(pages_map or {})
         builds: Dict[int, JoinBuild] = {}
 
@@ -1455,9 +1460,13 @@ class LocalQueryRunner:
                 and node.join_type != "full"
                 and not any(n is partitioned for n in N.walk(node.right))
             ):
+                side = self._build_in_batches(
+                    node, pages_map,
+                    batch_rows or int(self.session.get("page_capacity")),
+                )
                 leaf = self._execute_to_leaf(
                     N.JoinBuildNode(
-                        node.right, tuple(node.right_keys),
+                        side, tuple(node.right_keys),
                         tuple(node.payload), node.key_ranges,
                     ),
                     pages_map,
@@ -1472,6 +1481,76 @@ class LocalQueryRunner:
 
         with tracing.phase("exec", site="join_build"):
             return rewrite(root), builds
+
+    def _build_in_batches(
+        self, join: N.JoinNode, pages_map: Dict[int, Page], rows: int
+    ) -> N.PlanNode:
+        """A join's build side that joins within itself over a table of
+        more than ``rows`` rows (a split batch's), run over that table's
+        split batches — one program of a batch's size, as a stage's,
+        that ends in the sort by the join's key (``N.JoinBuildNode``
+        with every column riding: the sort is the compaction, where a
+        program's own would gather every column) — and the batches'
+        matchable rows laid end to end on the device
+        (``parallel.exchange.lay_end_to_end``): a leaf of ``pages_map``
+        in its place. A join's rank scans over more rows than a page
+        compile for many minutes on the chip (TPC-H Q18's ``orders``
+        filtered by its IN subquery, 15 M rows at SF10). Any other side
+        is returned as it is."""
+        from presto_tpu.parallel.exchange import lay_end_to_end
+        from presto_tpu.server.scheduler import (
+            _edge_distributive,
+            _path_to,
+            scan_rows,
+        )
+
+        side = join.right
+        scans = [n for n in N.walk(side) if isinstance(n, N.TableScanNode)]
+        if not scans or not any(
+            isinstance(n, N.JoinNode) for n in N.walk(side)
+        ):
+            return side
+        big = max(scans, key=lambda n: scan_rows(self.catalogs, n))
+        total = scan_rows(self.catalogs, big)
+        path = _path_to(side, big)
+        if total <= rows or not all(
+            _edge_distributive(a, b) for a, b in zip(path, path[1:])
+        ):
+            return side
+        schema = dict(side.output_schema())
+        # its own replicated build sides (the IN subquery's keys) once
+        inner, builds = self.prepare_builds(side, big, pages_map, rows)
+        sort = N.JoinBuildNode(
+            inner, tuple(join.right_keys), tuple(schema), join.key_ranges
+        )
+        leaves, pages = self.leaf_pages(
+            sort, {**pages_map, **builds}, skip=big
+        )
+        resolved = self._resolve(sort, leaves, batches=-(-total // rows))
+        outs = []
+        for lo in range(0, total, rows):
+            page, release = self.stage_split(
+                big, lo, min(lo + rows, total), rows
+            )
+            try:
+                outs.append(self._collect(
+                    self._dispatch(resolved, [
+                        page if n is big else p for n, p in zip(leaves, pages)
+                    ]),
+                    fetch_result=False,
+                ))
+            finally:
+                release()
+        with self._device_scope(), tracing.phase("dispatch"):
+            merged, _slots = lay_end_to_end(
+                [b.page for b, _ in outs], [n for _, n in outs], schema
+            )
+        if DEVICE.enabled:
+            DEVICE.count_dispatch()
+            self._fold_device_stat(device_dispatches=1)
+        leaf = N.RemoteSourceNode(fragment_root=side)
+        pages_map[id(leaf)] = merged
+        return leaf
 
     def _make_trace(
         self, croot, cscan_ids, counted, analyzed, out_capacity=None
@@ -2123,6 +2202,7 @@ class LocalQueryRunner:
         stats_out: Optional[List] = None,
         fetch_result: bool = True,
         tries: int = 0,
+        cut: bool = True,
     ):
         """Read a dispatched batch. Round-trip discipline: ONE
         ``jax.device_get`` for all control outputs + the result row
@@ -2134,7 +2214,10 @@ class LocalQueryRunner:
         (my chip runs, PR 32: 76 us of host time to issue a leaf's
         copy; fifteen reads of 25 leaves 33.2 ms, one read of 375
         leaves 31.0 ms — PERF.md §7). An error flag raises; a capacity
-        overflow runs the batch again at four times the capacities."""
+        overflow runs the batch again at four times the capacities.
+        ``fetch_result=False`` leaves the page on the device as
+        ``(page, rows)``, cut to the bucket of its rows unless ``cut``
+        is False."""
         page = pending.page
         res = pending.resolved
         spec = (
@@ -2183,6 +2266,7 @@ class LocalQueryRunner:
                 stats_out=stats_out,
                 fetch_result=fetch_result,
                 tries=tries,
+                cut=cut,
             )
         if res.analyzed:
             stats_out.clear()
@@ -2239,6 +2323,10 @@ class LocalQueryRunner:
                     if DEVICE.enabled:
                         DEVICE.count_dispatch()
                         self._fold_device_stat(device_dispatches=1)
+                return page, n
+            if not cut:
+                # the page as the program left it, for a program of
+                # static shapes that reads many (exchange_spi.device_merge)
                 return page, n
             from presto_tpu.page import pad_capacity
 

@@ -17,10 +17,12 @@ stream costs zero recompiles after the first batch. Host RAM is the
 spill tier (SURVEY.md §5.7 "host-RAM as the spill tier").
 
 Recursion handles multi-big-scan plans (e.g. TPC-H Q18, where both the
-semi-join subquery and the outer pipeline scan SF100 lineitem):
-``plan_stage(replicated_limit=...)`` refuses a cut that would replicate
-an oversized scan, so the inner fragment streams first and its
-materialized (small) result feeds the outer recursion as a leaf.
+semi-join subquery and the outer pipeline scan SF100 lineitem): the
+stage rule both split-stream loops share
+(``server.scheduler.cut_stage``) refuses a cut that would replicate an
+oversized scan, so the inner fragment streams first and its
+materialized (small) result feeds the outer recursion as a leaf — here
+as in the served coordinator.
 """
 
 from __future__ import annotations
@@ -44,8 +46,12 @@ from presto_tpu.server import pages_wire
 from presto_tpu.server.scheduler import (
     _path_to,
     _replace_on_path,
-    plan_stage,
+    cut_stage,
+    oversized_scans,
+    scan_rows,
+    split_final,
 )
+from presto_tpu.utils.telemetry import DEVICE
 
 
 class StreamingError(RuntimeError):
@@ -78,19 +84,10 @@ def _prefetch_splits(runner, scan, ranges, capacity):
     return prefetch_iter(ranges, load, PREFETCH_DEPTH)
 
 
-def _scan_rows(catalogs, scan: N.TableScanNode) -> int:
-    conn = catalogs.get(scan.handle.catalog)
-    stats = conn.metadata().get_table_stats(scan.handle)
-    return int(stats.row_count or 0)
-
-
 def needs_streaming(root: N.PlanNode, catalogs, session) -> bool:
     """True when some scan exceeds the device residency budget."""
-    max_rows = int(session.get("max_device_rows"))
-    return any(
-        isinstance(n, N.TableScanNode)
-        and _scan_rows(catalogs, n) > max_rows
-        for n in N.walk(root)
+    return bool(
+        oversized_scans(root, catalogs, int(session.get("max_device_rows")))
     )
 
 
@@ -133,20 +130,12 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
     oversized scan. ``materialized`` maps id(RemoteSourceNode) -> Page
     produced by an earlier recursion step."""
     max_rows = int(runner.session.get("max_device_rows"))
-    big = [
-        s
-        for s in N.walk(frag_root)
-        if isinstance(s, N.TableScanNode)
-        and _scan_rows(runner.catalogs, s) > max_rows
-    ]
-    if not big:
+    if not oversized_scans(frag_root, runner.catalogs, max_rows):
         leaves, pages = runner.leaf_pages(frag_root, materialized)
         return runner._run_with_pages(frag_root, leaves, pages)
 
-    stage = plan_stage(
-        frag_root, runner.catalogs, replicated_limit=max_rows
-    )
-    if stage is None:
+    cut = cut_stage(frag_root, runner.catalogs, max_rows)
+    if cut is None:
         out = _try_partitioned_join(
             runner, frag_root, materialized, max_rows
         )
@@ -157,9 +146,9 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
             "semantics-preserving streaming cut"
         )
 
-    bucket_root, rest_root, frag_remote, rest_remote = _split_final(
-        stage.final_root, stage.worker_fragment
-    )
+    stage = cut.stage
+    bucket_root, rest_root = cut.bucket_root, cut.rest_root
+    frag_remote, rest_remote = cut.remote, cut.rest_remote
 
     # --- the single input pass: batch -> partial -> bucket spill
     from presto_tpu.exec.staging import bucket_capacity
@@ -179,7 +168,7 @@ def _run_fragment(runner, frag_root: N.PlanNode, materialized: Dict):
     # replicated build sides once for all batches, as the served worker
     # does (LocalQueryRunner.prepare_builds)
     worker_root, base_pages = runner.prepare_builds(
-        worker_root, part_scan, materialized
+        worker_root, part_scan, materialized, batch
     )
 
     leaves = [
@@ -262,7 +251,7 @@ def grouped_final_merge(
             "spill_enabled=false (reference behavior: fail on memory "
             "rather than spill)"
         )
-    bucket_root, rest_root, frag_remote, rest_remote = _split_final(
+    bucket_root, rest_root, frag_remote, rest_remote = split_final(
         final_root, worker_fragment
     )
     n_buckets = _n_buckets_for(total_rows, max_rows)
@@ -289,6 +278,7 @@ def merge_spilled_buckets(
     SURVEY.md §2.4). Shared by the local streamed path and the
     coordinator's distributed gather (which has the same memory-funnel
     shape at scale)."""
+    DEVICE.count_host_merge(sum(n for b in spill for _, _, n in b))
     outs: List[tuple] = []
     out_schema = dict((bucket_root or frag_remote).output_schema())
     for b in range(len(spill)):
@@ -328,56 +318,6 @@ def bucketize_payloads(
     return spill
 
 
-def _split_final(
-    final_root: N.PlanNode, worker_fragment: N.PlanNode = None
-):
-    """Split the coordinator-side plan into the bucket-safe chain (the
-    final agg/distinct merge plus row-wise filters/projections directly
-    above it — safe because groups are complete within one bucket) and
-    the rest. Returns (bucket_root|None, rest_root|None, remote,
-    rest_remote|None) — ``rest_remote`` is the leaf in rest_root the
-    bucket-merged page binds to.
-
-    ``worker_fragment`` identifies THIS stage's remote when the final
-    plan holds several RemoteSourceNodes (recursive streaming leaves
-    earlier fragments' remotes in the tree — picking the first in walk
-    order built bucket chains around, and bound results to, the WRONG
-    exchange)."""
-    remote = next(
-        n
-        for n in N.walk(final_root)
-        if isinstance(n, N.RemoteSourceNode)
-        and (
-            worker_fragment is None
-            or n.fragment_root is worker_fragment
-        )
-    )
-    path = _path_to(final_root, remote)
-    j = len(path) - 2
-    if j >= 0 and isinstance(
-        path[j], (N.AggregationNode, N.DistinctNode)
-    ):
-        j -= 1
-        while j >= 0 and isinstance(
-            path[j], (N.FilterNode, N.ProjectNode)
-        ):
-            j -= 1
-    bucket_root = path[j + 1]
-    if bucket_root is remote:
-        # no bucket-safe chain: the merged page binds to the stage
-        # remote itself inside the (unchanged) rest plan
-        return None, (
-            None if final_root is remote else final_root
-        ), remote, remote
-    if bucket_root is final_root:
-        return bucket_root, None, remote, None
-    rest_remote = N.RemoteSourceNode(fragment_root=bucket_root)
-    rest_root = _replace_on_path(
-        path[: j + 1], bucket_root, rest_remote
-    )
-    return bucket_root, rest_root, remote, rest_remote
-
-
 def _bucket_key_names(worker_root: N.PlanNode) -> List[str]:
     """Group-key output columns of the cut node = the spill partition
     key (DistinctNode dedups whole rows: every column is key)."""
@@ -389,15 +329,6 @@ def _bucket_key_names(worker_root: N.PlanNode) -> List[str]:
 
 
 # ---------------------------------------------- partitioned join spill
-
-
-def _oversized_scans(runner, root: N.PlanNode, max_rows: int):
-    return [
-        s
-        for s in N.walk(root)
-        if isinstance(s, N.TableScanNode)
-        and _scan_rows(runner.catalogs, s) > max_rows
-    ]
 
 
 def _row_distributive_to_root(root: N.PlanNode, scan: N.PlanNode) -> bool:
@@ -430,14 +361,14 @@ def _try_partitioned_join(
     for J in N.walk(frag_root):
         if not isinstance(J, N.JoinNode):
             continue
-        if not _oversized_scans(runner, J.right, max_rows):
+        if not oversized_scans(J.right, runner.catalogs, max_rows):
             continue  # build fits: not this join's problem
         sides = []
         for side_root, keys in (
             (J.left, J.left_keys),
             (J.right, J.right_keys),
         ):
-            big = _oversized_scans(runner, side_root, max_rows)
+            big = oversized_scans(side_root, runner.catalogs, max_rows)
             if len(big) > 1 or (
                 big and not _row_distributive_to_root(side_root, big[0])
             ):
@@ -447,12 +378,12 @@ def _try_partitioned_join(
         if sides is None:
             continue
         probe_rows = sum(
-            _scan_rows(runner.catalogs, s)
+            scan_rows(runner.catalogs, s)
             for s in N.walk(J.left)
             if isinstance(s, N.TableScanNode)
         )
         build_rows = sum(
-            _scan_rows(runner.catalogs, s)
+            scan_rows(runner.catalogs, s)
             for s in N.walk(J.right)
             if isinstance(s, N.TableScanNode)
         )
@@ -562,7 +493,7 @@ def _stream_side_to_buckets(
     # the side is a linear chain and big_scan is its ONLY leaf
     batch = min(int(runner.session.get("page_capacity")), max_rows)
     batch_cap = bucket_capacity(batch)
-    total = _scan_rows(runner.catalogs, big_scan)
+    total = scan_rows(runner.catalogs, big_scan)
     ranges = [
         (lo, min(lo + batch, total)) for lo in range(0, total, batch)
     ]

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map as _shard_map
 
 from presto_tpu.ops.common import (
@@ -40,7 +41,7 @@ from presto_tpu.ops.common import (
     lexsort_u32,
     orderable_i64,
 )
-from presto_tpu.page import Block, Page, nonzero_1d
+from presto_tpu.page import Block, Dictionary, Page, nonzero_1d
 
 _NULL_SENTINEL = 0xA5A5_A5A5_DEAD_BEEF
 
@@ -713,3 +714,219 @@ def ici_append(
             valid = valid.at[pos].set(v, mode="drop")
         new_out[name] = {"data": data, "valid": valid}
     return new_out
+
+
+def union_dictionaries(pages, names):
+    """Per column the dictionary every page's ids go into, the sorted
+    union of theirs (merge_payloads' rule; one dictionary the pages
+    share — the batches of one staged table — is its own union, so the
+    1.5 M values of TPC-H Q18's ``c_name`` are never rebuilt), the
+    per-page remap tables into it (None where a page's ids stay), and
+    which columns have a validity mask. Returns ``(union, remaps,
+    has_valid)``."""
+    union, has_valid = {}, {}
+    for name in names:
+        dicts = {}
+        for pg in pages:
+            d = pg.block(name).dictionary
+            if d is not None:
+                dicts[id(d)] = d
+        if len(dicts) == 1:
+            (union[name],) = dicts.values()
+        else:
+            union[name] = (
+                Dictionary(np.asarray(sorted(set().union(
+                    *(tuple(d.values) for d in dicts.values())
+                )), object))
+                if dicts else None
+            )
+        has_valid[name] = any(pg.block(name).valid is not None for pg in pages)
+    tables = {}  # (column, id of a page's dictionary) -> remap
+
+    def remap(name, d):
+        u = union[name]
+        if u is None or d is None or d is u:
+            return None
+        if (name, id(d)) not in tables:
+            tables[(name, id(d))] = jnp.asarray(np.searchsorted(
+                np.asarray(u.values, object), np.asarray(d.values, object)
+            ).astype(np.int64))
+        return tables[(name, id(d))]
+
+    remaps = [
+        {name: remap(name, pg.block(name).dictionary) for name in names}
+        for pg in pages
+    ]
+    return union, remaps, has_valid
+
+
+def lay_end_to_end(pages, counts, schema, page_rows=None):
+    """Prefix-form pages with ``counts`` live rows laid end to end on the
+    device in ONE program (``agg_merge``): each page's rows up to the
+    bucket of the largest count, in a buffer that holds the last write
+    whole — the bucket of the total plus that bucket, plus ``page_rows``
+    where that exceeds ``page_rows`` (room for ``agg_merge_chunk``'s
+    slices). Dictionary ids go into the union dictionary. Returns the
+    page (its row count the total) and the slots the program read."""
+    from presto_tpu.exec.staging import bucket_capacity
+
+    names = tuple(schema.keys())
+    union, remaps, has_valid = union_dictionaries(pages, names)
+    total = int(sum(counts))
+    # a page with no live row writes nothing (the first stands in when
+    # none has one: the buffer is then all padding)
+    keep = [i for i, c in enumerate(counts) if c] or [0]
+    widest = bucket_capacity(max(counts[i] for i in keep))
+    sizes = tuple(min(widest, pages[i].capacity) for i in keep)
+    cap = bucket_capacity(total + max(sizes))
+    if page_rows is not None and cap > page_rows:
+        cap = bucket_capacity(total + max(max(sizes), page_rows))
+    offsets = np.concatenate(
+        [[0], np.cumsum([counts[i] for i in keep])[:-1]]
+    ).astype(np.int32)
+    out = agg_merge(
+        tuple(strip_dictionaries(pages[i]) for i in keep),
+        jnp.asarray(offsets),
+        tuple(
+            {k: v for k, v in remaps[i].items() if v is not None}
+            for i in keep
+        ),
+        dtypes=tuple(
+            (n, np.dtype(pages[keep[0]].block(n).data.dtype)) for n in names
+        ),
+        valid=tuple(n for n in names if has_valid[n]),
+        cap=cap,
+        sizes=sizes,
+    )
+    page = Page(
+        blocks=tuple(
+            Block(data=out[n]["data"], valid=out[n]["valid"],
+                  dtype=schema[n], dictionary=union[n])
+            for n in names
+        ),
+        num_valid=jnp.asarray(total, jnp.int32),
+        names=names,
+    )
+    return page, sum(sizes)
+
+
+@partial(jax.jit, static_argnames=("dtypes", "valid", "cap", "sizes"))
+def agg_merge(
+    pages: Tuple[Page, ...],
+    offsets: jnp.ndarray,
+    remaps: Tuple[Dict[str, jnp.ndarray], ...],
+    dtypes: Tuple[Tuple[str, object], ...],
+    valid: Tuple[str, ...],
+    cap: int,
+    sizes: Tuple[int, ...],
+) -> Dict[str, dict]:
+    """One worker's partial pages laid end to end in ONE buffer of
+    ``cap`` rows, for the final aggregation that merges them
+    (``server.exchange_spi.device_merge``, one partition): the first
+    ``sizes[i]`` rows of page ``i`` are written at ``offsets[i]``, the
+    sum of the live rows of the pages before it. A page's rows past its
+    live ones are overwritten by the next page or lie past the total,
+    so the buffer's live prefix is the pages' live rows in order — what
+    ``ici_append`` gathers row by row, with no gather and no scatter.
+    The caller sizes ``cap`` to hold the last write whole (an
+    out-of-range start would be clamped, not dropped). ``remaps`` maps a
+    dictionary column's ids into the union dictionary, page by page;
+    ``valid`` names the columns with a validity mask."""
+    out = {}
+    for name, dtype in dtypes:
+        first = pages[0].block(name).data
+        tail = first.shape[1:]
+        buf = jnp.zeros((cap,) + tail, dtype)
+        vbuf = jnp.zeros((cap,), jnp.bool_) if name in valid else None
+        for i, (pg, size) in enumerate(zip(pages, sizes)):
+            blk = pg.block(name)
+            d = blk.data[:size]
+            rmp = remaps[i].get(name)
+            if rmp is not None and rmp.shape[0]:
+                d = rmp[jnp.clip(d.astype(jnp.int64), 0, rmp.shape[0] - 1)]
+            start = (offsets[i],) + (0,) * len(tail)
+            buf = jax.lax.dynamic_update_slice(buf, d.astype(dtype), start)
+            if vbuf is not None:
+                v = (
+                    blk.valid[:size]
+                    if blk.valid is not None
+                    else jnp.ones((size,), jnp.bool_)
+                )
+                vbuf = jax.lax.dynamic_update_slice(vbuf, v, (offsets[i],))
+        out[name] = {"data": buf, "valid": vbuf}
+    return out
+
+
+@partial(jax.jit, static_argnames=("keys", "step", "window"))
+def agg_merge_sort(
+    cols: Dict[str, tuple],
+    total: jnp.ndarray,
+    keys: Tuple[str, ...],
+    step: int,
+    window: int,
+):
+    """The rows of a merged page of partial groups (``agg_merge``'s
+    buffer, ``total`` live rows first) sorted by their group ``keys``
+    (NULL a value of its own, padding last), and where to cut them into
+    chunks of whole groups: the first row of a group at or after every
+    ``step`` rows, found in the ``window`` rows from there. ``cols``
+    maps a column to ``(data, valid)``. Returns the sorted columns and
+    the chunks' bounds, ``total`` where nothing is left."""
+    cap = next(iter(cols.values()))[0].shape[0]
+    dead = jnp.arange(cap, dtype=jnp.int32) >= total
+    sort_keys = [dead]
+    for k in keys:
+        d, v = cols[k]
+        if v is not None:
+            sort_keys += [~v, jnp.where(v, d, jnp.zeros_like(d))]
+        else:
+            sort_keys.append(d)
+    # the other columns ride; a key comes back from its sorted operands
+    rest = [n for n in cols if n not in keys]
+    rides = [a for n in rest for a in cols[n] if a is not None]
+    out = jax.lax.sort(
+        tuple(sort_keys) + tuple(rides), num_keys=len(sort_keys),
+        is_stable=True,
+    )
+    skeys, rode = list(out[: len(sort_keys)]), list(out[len(sort_keys):])
+    first = jnp.zeros((cap,), jnp.bool_).at[0].set(True)
+    for a in skeys:
+        first = first | jnp.concatenate(
+            [jnp.ones((1,), jnp.bool_), a[1:] != a[:-1]]
+        )
+    first = first | dead
+    # bounds past the last window read padding's True: `total` at most
+    padded = jnp.concatenate([first, jnp.ones((window,), jnp.bool_)])
+    bounds = [jnp.int32(0)]
+    for c in range(1, -(-cap // step)):
+        at = jax.lax.dynamic_slice(padded, (c * step,), (window,))
+        bounds.append(jnp.minimum(
+            jnp.int32(c * step) + jnp.argmax(at).astype(jnp.int32), total
+        ))
+    bounds.append(total.astype(jnp.int32))
+    sorted_cols = {}
+    skeys.pop(0)
+    for k in keys:
+        if cols[k][1] is not None:
+            nulls, d = skeys.pop(0), skeys.pop(0)
+            sorted_cols[k] = (d, ~nulls)
+        else:
+            sorted_cols[k] = (skeys.pop(0), None)
+    for n in rest:
+        d, v = cols[n]
+        sorted_cols[n] = (rode.pop(0), None if v is None else rode.pop(0))
+    return {n: sorted_cols[n] for n in cols}, jnp.stack(bounds)
+
+
+@partial(jax.jit, static_argnames=("rows",))
+def agg_merge_chunk(cols: Dict[str, tuple], start: jnp.ndarray, rows: int):
+    """``rows`` rows of ``agg_merge_sort``'s columns from ``start`` (its
+    buffer holds ``rows`` rows past its live ones, so no start is
+    clamped)."""
+    return {
+        n: (
+            jax.lax.dynamic_slice_in_dim(d, start, rows),
+            None if v is None else jax.lax.dynamic_slice_in_dim(v, start, rows),
+        )
+        for n, (d, v) in cols.items()
+    }

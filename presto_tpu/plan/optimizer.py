@@ -399,6 +399,45 @@ def _expr_columns(e: E.Expr, out: Set[str]) -> None:
         _expr_columns(c, out)
 
 
+def push_semi_joins(node: N.PlanNode) -> N.PlanNode:
+    """Move each semi join (``IN`` / ``EXISTS``) below the inner joins
+    of its probe side, onto the side whose output holds its probe keys:
+    a semi join only drops probe rows, so it commutes with an inner
+    join on either side. TPC-H Q18's ``o_orderkey in (subquery)`` so
+    filters ``orders`` before ``lineitem`` joins it, and the build of
+    that join holds the few matching orders, not all of them. An anti
+    join (``NOT IN``, with its NULL rule) and a semi join with a
+    residual stay where they are."""
+    node = N.map_children(node, push_semi_joins)
+    if not (
+        isinstance(node, N.JoinNode)
+        and node.join_type == "semi"
+        and node.residual is None
+    ):
+        return node
+    below = node.left
+    if not (isinstance(below, N.JoinNode) and below.join_type == "inner"):
+        return node
+    keys = set(node.left_keys)
+    rename = dict(below.payload_rename)
+    from_build = {rename.get(c, c) for c in below.payload}
+    if keys <= set(below.left.output_schema()) and not keys & from_build:
+        return dataclasses.replace(
+            below,
+            left=push_semi_joins(dataclasses.replace(node, left=below.left)),
+        )
+    if (
+        keys <= set(below.payload)
+        and not keys & set(rename)
+        and not keys & set(below.left.output_schema())
+    ):
+        return dataclasses.replace(
+            below,
+            right=push_semi_joins(dataclasses.replace(node, left=below.right)),
+        )
+    return node
+
+
 def normalize_interior_outputs(
     node: N.PlanNode, is_root: bool = True
 ) -> N.PlanNode:

@@ -172,7 +172,8 @@ class _Planner:
             raise PlanningError(f"cannot plan {type(stmt).__name__}")
         node, scope, names = self.plan_select(stmt, outer=None)
         return Plan(
-            root=self._state_join_ranges(node), params=self.params,
+            root=self._state_join_ranges(optimizer.push_semi_joins(node)),
+            params=self.params,
             output_names=names,
         )
 

@@ -38,9 +38,12 @@ from presto_tpu.server.journal import CoordinatorJournal
 from presto_tpu.server.protocol import FragmentSpec
 from presto_tpu.server.scheduler import (
     assign_ranges,
+    cut_stage,
+    device_merge_slice,
     plan_stage,
     select_exchange_edges,
     select_exchange_transport,
+    split_final,
     stable_workers,
 )
 from presto_tpu.server.spool import ExchangeSpool
@@ -152,6 +155,11 @@ class _Query:
         #: the probe stage, reused by the replicated join's
         #: dynamic-filter plane instead of a second summary stage
         self._df_probe_reuse: Dict[str, tuple] = {}
+        #: results of stages run ahead of the stage that reads them
+        #: (``_run_subquery_stage``), by id of the RemoteSourceNode that
+        #: reads each, and their serialized wire pages for task specs
+        self._materialized: Dict[int, object] = {}
+        self._leaf_wire: Dict[int, str] = {}
         self._task_stage: Dict[str, StageStats] = {}
         self._recorded: set = set()
         self._adopted = False  # registered in the runner's QueryHistory
@@ -2568,10 +2576,35 @@ class CoordinatorServer:
         self, q: _Query, stage: StageStats, spec: FragmentSpec
     ) -> FragmentSpec:
         """Remember which stage a task belongs to, so its final status
-        rolls up into the right StageStats."""
+        rolls up into the right StageStats; a fragment that reads the
+        result of a stage run ahead of it carries that result along
+        (``FragmentSpec.leaf_pages``)."""
         with q._stats_lock:
             q._task_stage[spec.task_id] = stage
+        if q._materialized:
+            leaves = tuple(
+                (i, self._leaf_wire_page(q, n))
+                for i, n in enumerate(N.walk(spec.fragment))
+                if id(n) in q._materialized
+            )
+            if leaves:
+                spec = dataclasses.replace(spec, leaf_pages=leaves)
         return spec
+
+    @staticmethod
+    def _leaf_wire_page(q: _Query, node: N.RemoteSourceNode) -> str:
+        """The serialized wire page of one materialized stage result,
+        made once a query."""
+        import base64
+
+        if id(node) not in q._leaf_wire:
+            cols, n = pages_wire.page_to_wire_columns(
+                q._materialized[id(node)]
+            )
+            q._leaf_wire[id(node)] = base64.b64encode(
+                pages_wire.serialize_page(cols, n)
+            ).decode("ascii")
+        return q._leaf_wire[id(node)]
 
     def _record_task_status(self, q: _Query, task_id: str, st: dict):
         """Fold one task's status JSON into the query rollup and graft
@@ -2906,7 +2939,13 @@ class CoordinatorServer:
                     J = n
                     probe_steps = path[i + 1 : -1]  # J.left -> scan
                 break
-        if J is None:
+        if J is None or any(
+            isinstance(n, N.JoinNode) for n in N.walk(J.right)
+        ):
+            # a build side that joins within itself (TPC-H Q18's orders
+            # semi-joined to its IN subquery): a summary stage would run
+            # that join over the whole table once more, for a filter the
+            # few rows the probe stage builds already are
             return None
         left_schema = J.left.output_schema()
         build_schema = J.right.output_schema()
@@ -3442,7 +3481,13 @@ class CoordinatorServer:
         ``order_by`` (ordered MERGE exchange): wrap the worker fragment
         in the given root SortNode so workers emit sorted runs, and
         k-way merge the runs at the gather instead of re-sorting. The
-        caller guarantees the stage has no aggregation cut."""
+        caller guarantees the stage has no aggregation cut.
+
+        No scan of more than ``max_device_rows`` rows is replicated into
+        a stage (``scheduler.cut_stage``, the rule the runner's own
+        stream keeps too): a subquery over such a table runs first as a
+        stage of its own (``_run_subquery_stage``). A ``stage`` given by
+        the caller runs as it is."""
         # QoS: stage boundaries are suspension points too — a query
         # suspended between stages parks before scheduling the next
         self._qos_checkpoint(q)
@@ -3451,6 +3496,7 @@ class CoordinatorServer:
         ).upper()
         if (
             order_by is None
+            and stage is None
             and len(workers) > 1
             and jdt in ("PARTITIONED", "AUTOMATIC", "AUTO")
         ):
@@ -3466,7 +3512,19 @@ class CoordinatorServer:
             if out is not None:
                 return out
         if stage is None:
-            stage = plan_stage(fragment_root, self.local.catalogs)
+            max_rows = int(self.local.session.get("max_device_rows"))
+            cut = cut_stage(fragment_root, self.local.catalogs, max_rows)
+            if cut is not None and cut.feeds_a_stage(
+                self.local.catalogs, max_rows
+            ):
+                return self._run_subquery_stage(
+                    fragment_root, workers, q, cut
+                )
+            stage = (
+                cut.stage
+                if cut is not None
+                else plan_stage(fragment_root, self.local.catalogs)
+            )
         if stage is None:
             # no scan admits a semantics-preserving partitioning:
             # single-task fallback on the coordinator's local engine
@@ -3532,14 +3590,21 @@ class CoordinatorServer:
         # stages keep the direct gather (nothing to repartition).
         from presto_tpu.exec import streaming as S
 
+        # One worker runs the shuffle too where its partial groups can
+        # outgrow a batch's page: the pages stay on its device and one
+        # merge task merges them there (scheduler.device_merge_slice)
         key_names = S._bucket_key_names(stage.worker_fragment)
+        merge_slice = device_merge_slice(
+            workers, stage.worker_fragment,
+            int(self.local.session.get("page_capacity")),
+        )
         if (
             order_by is None
-            and len(workers) > 1
+            and (len(workers) > 1 or merge_slice)
             and key_names
             and bool(self.local.session.get("distributed_final"))
         ):
-            bucket_root, rest_root, _, _ = S._split_final(
+            bucket_root, rest_root, _, _ = split_final(
                 stage.final_root, stage.worker_fragment
             )
             if bucket_root is not None:
@@ -3550,6 +3615,7 @@ class CoordinatorServer:
                         worker_fragment=worker_fragment,
                         partition_scan_idx=partition_scan_idx,
                         ranges_override=dyn_ranges,
+                        merge_slice=merge_slice,
                     )
                 except Exception as e:
                     out = self._local_fallback(q, fragment_root, None, e)
@@ -3631,11 +3697,6 @@ class CoordinatorServer:
             if order_by is not None:
                 merged = _merge_sorted_runs(payloads, schema, order_by)
                 return stage_page(merged, schema)
-            remote = [
-                n
-                for n in N.walk(stage.final_root)
-                if isinstance(n, N.RemoteSourceNode)
-            ]
             # bucketed gather (reference: grouped execution at the
             # merge): partial states beyond the device budget
             # hash-bucket by group key and merge one bucket at a time
@@ -3657,24 +3718,57 @@ class CoordinatorServer:
             # the root stage's merge of the tasks' partial pages: host
             # work (a concatenation a column, dictionaries united) that
             # is `exec` time; the site names it in a span profile
+            if isinstance(
+                stage.worker_fragment, (N.AggregationNode, N.DistinctNode)
+            ):
+                DEVICE.count_host_merge(sum(n for _, _, n in payloads))
             with tracing.phase("exec", site="merge_partials"):
                 merged = pages_wire.merge_payloads(payloads, schema)
             page = stage_page(merged, schema)
             # the final plan may contain real scans above the cut (e.g.
             # a join against another table after the final aggregation)
             # — load those locally alongside the gathered remote page
-            local_scans = [
-                n
-                for n in N.walk(stage.final_root)
-                if isinstance(n, N.TableScanNode)
-            ]
-            leaves = remote + local_scans
-            pages = [page] + [
-                self.local._load_table(s) for s in local_scans
-            ]
+            leaves, pages = self._final_leaves(q, stage.final_root, page)
             return self.local._run_with_pages(
                 stage.final_root, leaves, pages
             )
+
+    def _final_leaves(self, q: _Query, root, page):
+        """The leaves of a plan the coordinator runs above a stage and
+        their pages: the stage's remote source reads ``page``, a remote
+        source of an earlier stage's result reads that result, a real
+        scan (e.g. a table joined after the final aggregation) loads
+        whole."""
+        leaves, pages = [], []
+        for n in N.walk(root):
+            if isinstance(n, N.RemoteSourceNode):
+                leaves.append(n)
+                pages.append(q._materialized.get(id(n), page))
+            elif isinstance(n, N.TableScanNode):
+                leaves.append(n)
+                pages.append(self.local._load_table(n))
+        return leaves, pages
+
+    def _run_subquery_stage(self, fragment_root, workers, q: _Query, cut):
+        """A stage whose consumer scans a table too large to replicate
+        (``StageCut.feeds_a_stage``; TPC-H Q18's IN subquery over
+        ``lineitem``): its tasks stream their split ranges, its bucket
+        chain (final merge, HAVING) runs by the rules of any stage, and
+        its small result becomes the leaf ``cut.rest_remote`` of the
+        rest, which is planned and run as the next stage. The result
+        rides to the rest's tasks in their specs
+        (``FragmentSpec.leaf_pages``)."""
+        sub = dataclasses.replace(cut.stage, final_root=cut.bucket_root)
+        try:
+            with tracing.phase("schedule", site="subquery_stage"):
+                page = self._run_stage(None, workers, q, stage=sub)
+        except Exception as e:
+            out = self._local_fallback(q, fragment_root, None, e)
+            if out is None:
+                raise
+            return out
+        q._materialized[id(cut.rest_remote)] = page
+        return self._run_stage(cut.rest_root, workers, q)
 
     def _local_fallback(self, q: _Query, fragment_root, order_by, exc):
         """Graceful degradation, last resort: when a distributed stage
@@ -3683,6 +3777,8 @@ class CoordinatorServer:
         local engine instead of failing the query. Returns None when
         degradation does NOT apply — execution errors, or live workers
         remaining — so the caller re-raises."""
+        if fragment_root is None:
+            return None  # a stage run for another: its caller falls back
         degradable = rpc.is_task_recoverable(exc) or isinstance(
             exc, NoLiveWorkers
         )
@@ -4090,7 +4186,7 @@ class CoordinatorServer:
     def _run_stage_shuffled(
         self, stage, workers, q: _Query, key_names, bucket_root,
         rest_root, worker_fragment=None, partition_scan_idx=None,
-        ranges_override=None,
+        ranges_override=None, merge_slice="",
     ):
         """Two-stage execution with a worker<->worker data plane.
 
@@ -4103,7 +4199,9 @@ class CoordinatorServer:
         concatenates — correct because the hash partitions the group
         space. Sources attach when stage 1 completes (no pipelined
         shuffle start yet — documented simplification vs the reference's
-        incremental addExchangeLocations)."""
+        incremental addExchangeLocations). ``merge_slice``: one worker's
+        own slice (``scheduler.device_merge_slice``) — its producers keep
+        their partial pages on its device for its one merge task."""
         REGISTRY.counter("coordinator.shuffled_stages").update()
         # dynamic-filter overrides from _run_stage (None = legacy)
         if worker_fragment is None:
@@ -4126,7 +4224,7 @@ class CoordinatorServer:
         # producer/merge workers exchange partitions as device
         # collectives; "" keeps the serialized HTTP wire, and a lone
         # cross-slice worker settles its own edges at run time
-        ici_slice = self._select_transport(
+        ici_slice = merge_slice or self._select_transport(
             workers,
             schemas=(dict(worker_fragment.output_schema()),),
         )
@@ -4219,6 +4317,11 @@ class CoordinatorServer:
                         partition_scan=-1,
                         split_start=0,
                         split_end=0,
+                        # the rows of a batch's page, which the merge
+                        # keeps its programs to (worker._run_final)
+                        split_batch_rows=int(
+                            self.local.session.get("page_capacity")
+                        ),
                         partition=i,
                         spool=self._spooling(),
                         ici_slice=ici_slice,
@@ -4332,22 +4435,8 @@ class CoordinatorServer:
         page = stage_page(merged, schema)
         if rest_root is None:
             return page
-        rest_remote = [
-            n
-            for n in N.walk(rest_root)
-            if isinstance(n, N.RemoteSourceNode)
-        ]
-        local_scans = [
-            n
-            for n in N.walk(rest_root)
-            if isinstance(n, N.TableScanNode)
-        ]
-        pages = [page] + [
-            self.local._load_table(s) for s in local_scans
-        ]
-        return self.local._run_with_pages(
-            rest_root, rest_remote + local_scans, pages
-        )
+        leaves, pages = self._final_leaves(q, rest_root, page)
+        return self.local._run_with_pages(rest_root, leaves, pages)
 
     def _ranged_tasks(
         self, workers, ranges, make_spec, consume,

@@ -54,9 +54,12 @@ log = logging.getLogger("presto_tpu.exchange")
 def _fetch_dest(dest, nr: int):
     """The ONE destination-vector fetch of the ICI lane (a small
     device->host control transfer per batch), accounted on the
-    device-plane telemetry counters."""
+    device-plane telemetry counters. ``None`` (one partition) sends
+    every row to partition 0 and reads nothing."""
     import jax
 
+    if dest is None:
+        return np.zeros(nr, np.int64)
     arr = np.asarray(jax.device_get(dest))
     DEVICE.count_d2h(int(arr.nbytes))
     return arr[:nr].astype(np.int64)
@@ -297,7 +300,25 @@ def _serialize_partition_slices(payload, schema, nrows, buckets):
         yield int(b), pages_wire.serialize_page(cols, n), n
 
 
-def emit_partitioned(task, out, *, slice_id: str, pool, fold=None) -> None:
+def keeps_on_device(spec, slice_id: str) -> bool:
+    """Whether a producer's partitioned output stays on this worker's
+    device whole, as it leaves the batch's program: one partition
+    (``scheduler.device_merge_slice``: one worker merges its own
+    pages) of fixed-width columns, on the lane the scheduler chose."""
+    return (
+        slice_id != ""
+        and spec.ici_slice == slice_id
+        and spec.n_partitions == 1
+        and all(
+            not (t.is_array or t.is_map or t.is_row)
+            for t in spec.fragment.output_schema().values()
+        )
+    )
+
+
+def emit_partitioned(
+    task, out, *, slice_id: str, pool, fold=None, n=None
+) -> None:
     """The ONE partitioned-output emit (reference:
     PartitionedOutputOperator): routes this batch onto the transport
     the scheduler chose for the stage.
@@ -308,6 +329,11 @@ def emit_partitioned(task, out, *, slice_id: str, pool, fold=None) -> None:
     segment; consumers gather their rows device-to-device. The spool
     tee still serializes under ``retry_policy=TASK`` (durability needs
     bytes on disk; the data plane between live peers stays on device).
+
+    One partition (one worker merging its own pages) routes nothing:
+    the page enters the segment with no destination vector (``None``),
+    and ``n``, its live rows as the batch's read brought them, spares
+    a read of the device.
 
     HTTP lane (everything else): serialize, slice per partition, offer
     to the per-partition output buffers — bit-exact legacy behavior.
@@ -324,21 +350,23 @@ def emit_partitioned(task, out, *, slice_id: str, pool, fold=None) -> None:
 
         import jax.numpy as jnp
 
-        n = int(out.num_valid)
+        n = int(out.num_valid) if n is None else int(n)
         if n == 0:
             return
-        keys = tuple(spec.partition_keys)
-        crc = {
-            c: X.wire_crc_table(out.block(c).dictionary)
-            for c in keys
-            if out.block(c).dictionary is not None
-        }
-        stripped = X.strip_dictionaries(out)
-        dest = X.bucket_dest(
-            stripped, crc, jnp.asarray(spec.n_partitions), keys
-        )
-        _count_dispatch(1, fold)
-        nbytes = page_nbytes(out) + int(dest.nbytes)
+        dest = None
+        if spec.n_partitions > 1:
+            keys = tuple(spec.partition_keys)
+            crc = {
+                c: X.wire_crc_table(out.block(c).dictionary)
+                for c in keys
+                if out.block(c).dictionary is not None
+            }
+            stripped = X.strip_dictionaries(out)
+            dest = X.bucket_dest(
+                stripped, crc, jnp.asarray(spec.n_partitions), keys
+            )
+            _count_dispatch(1, fold)
+        nbytes = page_nbytes(out) + (0 if dest is None else int(dest.nbytes))
         if pool is not None:
             # same accounting as HTTP shuffle buffers: the pages are
             # stage-lifetime, reserved under the task's buffer key and
@@ -436,7 +464,7 @@ def _ici_emit_ok(spec, out, slice_id: str) -> bool:
     return (
         slice_id != ""
         and spec.ici_slice == slice_id
-        and 1 < spec.n_partitions <= X.MAX_ICI_PARTS
+        and 1 <= spec.n_partitions <= X.MAX_ICI_PARTS
         and _page_eligible(out)
         and all(k in out.names for k in spec.partition_keys)
     )
@@ -661,7 +689,8 @@ def ici_batches_to_payloads(batches, part: int, schema):
 
 
 def device_merge(
-    batches_by_source, part: int, schema, max_rows=None, fold=None
+    batches_by_source, part: int, schema, max_rows=None, fold=None,
+    page_rows=None,
 ):
     """Build the merge task's input page ON DEVICE from ICI batches:
     per-source partition rows gather-scattered into one zero-padded
@@ -669,7 +698,12 @@ def device_merge(
     into the sorted union dictionary — the same union, row order, and
     capacity bucket the HTTP path's ``merge_payloads`` + ``stage_page``
     produce, so the downstream fragment compiles and computes
-    identically.
+    identically. Batches with no destination vector (one partition: a
+    worker merging its own pages, ``scheduler.device_merge_slice``) are
+    laid end to end by one program (``parallel.exchange.
+    lay_end_to_end``); where that buffer would hold more than
+    ``page_rows`` rows it is made ``page_rows`` larger still, room for
+    the page-sized slices ``merge_chunks`` cuts it into.
 
     Returns ``(page, total_rows)``, or None when the partition exceeds
     ``max_rows`` — the caller degrades to the grouped host merge
@@ -680,47 +714,50 @@ def device_merge(
     import jax.numpy as jnp
 
     from presto_tpu.exec.staging import bucket_capacity
-    from presto_tpu.page import Block, Dictionary, Page
+    from presto_tpu.page import Block, Page
     from presto_tpu.parallel import exchange as X
 
     flat: List[tuple] = [
         b for src in batches_by_source for b in src
     ]
     names = tuple(schema.keys())
+    # one partition (every destination None: a worker merging its own
+    # pages) routes nothing: the live counts are the pages' own
+    whole = all(d is None for _, d in flat)
     # one small device->host fetch sizes the buffer (counts only —
     # the data plane stays on device)
-    count_vecs = jax.device_get(
-        [X.ici_partition_counts(pg, d) for pg, d in flat]
-    )
+    if whole:
+        count_vecs = jax.device_get([pg.num_valid for pg, _ in flat])
+    else:
+        count_vecs = jax.device_get(
+            [X.ici_partition_counts(pg, d) for pg, d in flat]
+        )
+        _count_dispatch(len(flat), fold)
     d2h = sum(int(np.asarray(c).nbytes) for c in count_vecs)
     DEVICE.count_d2h(d2h)
-    _count_dispatch(len(flat), fold)
     if fold is not None:
         fold(device_d2h_bytes=d2h)
-    counts = [int(np.asarray(c)[part]) for c in count_vecs]
+    counts = [
+        int(np.asarray(c) if whole else np.asarray(c)[part])
+        for c in count_vecs
+    ]
     total = int(sum(counts))
     if max_rows is not None and total > max_rows:
         return None
+    if whole:
+        with tracing.phase("dispatch"):
+            page, slots = X.lay_end_to_end(
+                [pg for pg, _ in flat], counts, schema, page_rows
+            )
+        _count_dispatch(1, fold)
+        DEVICE.count_agg_merge(slots)
+        return page, total
     cap = bucket_capacity(total)
-
     # per-column union dictionary + per-source remap tables, exactly
     # merge_payloads' sorted-union searchsorted
-    union: Dict[str, Optional[list]] = {}
-    has_valid: Dict[str, bool] = {}
-    for name in names:
-        dicts = []
-        anyv = False
-        for pg, _ in flat:
-            blk = pg.block(name)
-            if blk.dictionary is not None:
-                dicts.append(tuple(blk.dictionary.values))
-            if blk.valid is not None:
-                anyv = True
-        union[name] = (
-            sorted(set().union(*dicts)) if dicts else None
-        )
-        has_valid[name] = anyv
-
+    union, all_remaps, has_valid = X.union_dictionaries(
+        [pg for pg, _ in flat], names
+    )
     out = {}
     for name in names:
         t = schema[name]
@@ -737,21 +774,8 @@ def device_merge(
                 else None
             ),
         }
-
     offset = 0
-    for (pg, dest), cnt in zip(flat, counts):
-        remaps = {}
-        for name in names:
-            u = union[name]
-            blk = pg.block(name)
-            if u is not None and blk.dictionary is not None:
-                uarr = np.asarray(u, object)
-                vals = np.asarray(blk.dictionary.values, object)
-                remaps[name] = jnp.asarray(
-                    np.searchsorted(uarr, vals).astype(np.int64)
-                )
-            else:
-                remaps[name] = None
+    for (pg, dest), cnt, remaps in zip(flat, counts, all_remaps):
         out = X.ici_append(
             out,
             X.strip_dictionaries(pg),
@@ -762,20 +786,15 @@ def device_merge(
         )
         _count_dispatch(1, fold)
         offset += cnt
-
+    DEVICE.count_agg_merge(sum(pg.capacity for pg, _ in flat))
     blocks = []
     for name in names:
-        u = union[name]
         blocks.append(
             Block(
                 data=out[name]["data"],
                 valid=out[name]["valid"],
                 dtype=schema[name],
-                dictionary=(
-                    Dictionary(np.asarray(u, object))
-                    if u is not None
-                    else None
-                ),
+                dictionary=union[name],
             )
         )
     page = Page(
@@ -784,6 +803,67 @@ def device_merge(
         names=names,
     )
     return page, total
+
+
+def merge_chunks(page, keys, total: int, page_rows: int, batches: int,
+                 fold=None):
+    """A merged page of partial groups larger than a batch's page, as
+    page-sized pages for the final aggregation, each holding whole
+    groups: its rows sorted by the group ``keys`` in one program, then
+    cut where a group starts near every ``page_rows - batches - 1``
+    rows (a group has at most one row a merged batch, so one starts in
+    the next ``batches + 1`` rows). A final aggregation over more rows
+    than a page compiles for many minutes (its scans), a sort does not.
+    None where a key cannot be sorted as it is stored (a float's NaN
+    and -0.0, a long decimal's two limbs) or the pages were too many:
+    the caller runs the final aggregation over the whole page."""
+    import jax
+    import jax.numpy as jnp
+
+    from presto_tpu.page import Block, Page
+    from presto_tpu.parallel import exchange as X
+
+    window = batches + 1
+    step = page_rows - window
+    names = tuple(page.names)
+    blocks = {n: page.block(n) for n in names}
+    if step < page_rows // 2 or any(
+        blocks[k].data.ndim != 1
+        or not (
+            jnp.issubdtype(blocks[k].data.dtype, jnp.integer)
+            or blocks[k].data.dtype == jnp.bool_
+        )
+        for k in keys
+    ):
+        return None
+    with tracing.phase("dispatch"):
+        cols, starts = X.agg_merge_sort(
+            {n: (b.data, b.valid) for n, b in blocks.items()},
+            jnp.asarray(total, jnp.int32),
+            keys=tuple(keys), step=step, window=window,
+        )
+    _count_dispatch(1, fold)
+    with tracing.phase("fetch", site="merge_chunks"):
+        starts = np.asarray(jax.device_get(starts))
+    DEVICE.count_d2h(int(starts.nbytes))
+    out = []
+    for lo, hi in zip(starts[:-1], starts[1:]):
+        if hi <= lo:
+            continue
+        with tracing.phase("dispatch"):
+            part = X.agg_merge_chunk(cols, jnp.asarray(lo, jnp.int32),
+                                     rows=page_rows)
+        _count_dispatch(1, fold)
+        out.append(Page(
+            blocks=tuple(
+                Block(data=part[n][0], valid=part[n][1],
+                      dtype=blocks[n].dtype, dictionary=blocks[n].dictionary)
+                for n in names
+            ),
+            num_valid=jnp.asarray(hi - lo, jnp.int32),
+            names=names,
+        ))
+    return out
 
 
 # ------------------------------------------------- collective stages

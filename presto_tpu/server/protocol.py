@@ -229,6 +229,11 @@ class FragmentSpec:
     #: cross-slice) silently uses HTTP; recovery and drain degrade the
     #: same way.
     ici_slice: str = ""
+    #: the materialized result of an earlier stage that a remote source
+    #: of ``fragment`` reads (an IN subquery's few keys, the
+    #: coordinator's ``StageCut.feeds_a_stage``): ``(walk index of the
+    #: RemoteSourceNode, base64 of its serialized wire page)`` pairs
+    leaf_pages: tuple = ()
     #: trace context (utils.tracing traceparent header value): the
     #: coordinator stamps every task with the query's trace so
     #: worker-side spans join the query's span tree; also sent as the
@@ -252,6 +257,7 @@ class FragmentSpec:
             "dynfilter_ndv": self.dynfilter_ndv,
             "spool": self.spool,
             "ici_slice": self.ici_slice,
+            "leaf_pages": [list(x) for x in self.leaf_pages],
             "traceparent": self.traceparent,
         }
 
@@ -275,5 +281,8 @@ class FragmentSpec:
             dynfilter_ndv=d.get("dynfilter_ndv", 0),
             spool=bool(d.get("spool", False)),
             ici_slice=d.get("ici_slice", ""),
+            leaf_pages=tuple(
+                (int(i), str(b)) for i, b in d.get("leaf_pages", ())
+            ),
             traceparent=d.get("traceparent", ""),
         )

@@ -212,6 +212,139 @@ def _replace_on_path(
     return new
 
 
+def split_final(
+    final_root: N.PlanNode, worker_fragment: N.PlanNode = None
+):
+    """Split the coordinator-side plan into the bucket-safe chain (the
+    final agg/distinct merge plus row-wise filters/projections directly
+    above it — safe because groups are complete within one bucket) and
+    the rest. Returns (bucket_root|None, rest_root|None, remote,
+    rest_remote|None) — ``rest_remote`` is the leaf in rest_root the
+    bucket-merged page binds to.
+
+    ``worker_fragment`` identifies THIS stage's remote when the final
+    plan holds several RemoteSourceNodes (recursive streaming leaves
+    earlier fragments' remotes in the tree — picking the first in walk
+    order built bucket chains around, and bound results to, the WRONG
+    exchange)."""
+    remote = next(
+        n
+        for n in N.walk(final_root)
+        if isinstance(n, N.RemoteSourceNode)
+        and (
+            worker_fragment is None
+            or n.fragment_root is worker_fragment
+        )
+    )
+    path = _path_to(final_root, remote)
+    j = len(path) - 2
+    if j >= 0 and isinstance(
+        path[j], (N.AggregationNode, N.DistinctNode)
+    ):
+        j -= 1
+        while j >= 0 and isinstance(
+            path[j], (N.FilterNode, N.ProjectNode)
+        ):
+            j -= 1
+    bucket_root = path[j + 1]
+    if bucket_root is remote:
+        # no bucket-safe chain: the merged page binds to the stage
+        # remote itself inside the (unchanged) rest plan
+        return None, (
+            None if final_root is remote else final_root
+        ), remote, remote
+    if bucket_root is final_root:
+        return bucket_root, None, remote, None
+    rest_remote = N.RemoteSourceNode(fragment_root=bucket_root)
+    rest_root = _replace_on_path(
+        path[: j + 1], bucket_root, rest_remote
+    )
+    return bucket_root, rest_root, remote, rest_remote
+
+
+def scan_rows(catalogs, scan: N.TableScanNode) -> int:
+    """The row count the connector's statistics state for a scan."""
+    conn = catalogs.get(scan.handle.catalog)
+    return int(conn.metadata().get_table_stats(scan.handle).row_count or 0)
+
+
+def oversized_scans(
+    root: N.PlanNode, catalogs, max_rows: int
+) -> List[N.TableScanNode]:
+    """The scans of ``root`` (not of the fragments its remote sources
+    read) with more rows than a device holds at once."""
+    return [
+        s
+        for s in N.walk(root)
+        if isinstance(s, N.TableScanNode) and scan_rows(catalogs, s) > max_rows
+    ]
+
+
+@dataclasses.dataclass(frozen=True)
+class StageCut:
+    """A stage and the split of its final plan (``split_final``)."""
+
+    stage: StagePlan
+    bucket_root: Optional[N.PlanNode]
+    rest_root: Optional[N.PlanNode]
+    remote: N.RemoteSourceNode
+    rest_remote: Optional[N.RemoteSourceNode]
+
+    def feeds_a_stage(self, catalogs, max_rows: int) -> bool:
+        """Whether what is left above the stage's bucket chain still
+        scans a table of more than ``max_rows`` rows: it is then the
+        stage's consumer, a stage of its own, and this stage runs
+        first through its bucket chain, whose small result becomes the
+        consumer's leaf ``rest_remote`` (TPC-H Q18's IN subquery)."""
+        return (
+            self.bucket_root is not None
+            and self.rest_root is not None
+            and bool(oversized_scans(self.rest_root, catalogs, max_rows))
+        )
+
+
+def cut_stage(fragment_root: N.PlanNode, catalogs, max_rows: int):
+    """THE stage rule of both loops over a split stream — the served
+    coordinator and the runner's own stream (``exec/streaming.py``): no
+    scan of more than ``max_rows`` rows is replicated into a stage, so
+    a subquery over such a table streams first as a stage of its own
+    (``StageCut.feeds_a_stage``) and its result feeds the rest as a
+    leaf. None when no cut obeys that."""
+    stage = plan_stage(fragment_root, catalogs, replicated_limit=max_rows)
+    if stage is None:
+        return None
+    return StageCut(stage, *split_final(stage.final_root, stage.worker_fragment))
+
+
+def device_merge_slice(workers, worker_fragment: N.PlanNode, page_capacity: int) -> str:
+    """The slice on which a stage run by ONE worker keeps its partial
+    pages and merges them on that worker's device, or "": a keyed
+    partial aggregation whose groups can outgrow one batch's page — the
+    planner's bucket (``AggregationNode.max_groups``) exceeds
+    ``page_capacity``, and so does the range the connector's statistics
+    state for one of its keys (``AggregationNode.key_ranges``), the
+    proof that the groups may really be that many (the three groups of
+    TPC-H Q1's dictionary keys have the same planner's bucket as Q18's
+    15 M orders). Its producers publish their pages to the worker's own
+    exchange segment and one merge task merges them there
+    (``exchange_spi.device_merge``: into a buffer of at most
+    ``max_device_rows`` rows, else the host merge), so no page of
+    partial groups crosses the host link. Several workers exchange by
+    ``select_exchange_edges``' rule instead."""
+    if (
+        len(workers) != 1
+        or not isinstance(worker_fragment, N.AggregationNode)
+        or not worker_fragment.group_keys
+        or worker_fragment.max_groups <= page_capacity
+        or not any(
+            r is not None and r[1] - r[0] + 1 > page_capacity
+            for r in worker_fragment.key_ranges
+        )
+    ):
+        return ""
+    return str(getattr(workers[0], "slice_id", "") or "")
+
+
 def stable_workers(workers) -> list:
     """Placement set for gather/merge/join stages under preemptible-
     aware scheduling: these stages hold the only copy of merged state

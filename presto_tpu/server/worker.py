@@ -45,6 +45,8 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from presto_tpu.connectors.spi import ConnectorSplit
 from presto_tpu.exec.staging import (
     PREFETCH_DEPTH,
@@ -936,7 +938,7 @@ class WorkerServer:
                 task.spec.ici_slice
                 and task.spec.ici_slice == self.slice_id
                 and (
-                    task.spec.n_partitions > 1
+                    task.spec.partition_keys
                     or getattr(task, "_ici_gather", False)
                 )
             ):
@@ -1020,6 +1022,7 @@ class WorkerServer:
         # the per-query budget (exec.host_ops)
         from presto_tpu.exec.host_ops import apply_host_ops, peel_host_ops
 
+        results = self._leaf_pages(spec)
         root, pushed_ops = peel_host_ops(root)
         part_scan = next(
             (n for i, n in enumerate(N.walk(root))
@@ -1033,7 +1036,9 @@ class WorkerServer:
         repl_pages = {}
         if part_scan is not None:
             t_build = time.perf_counter()
-            root, repl_pages = self.runner.prepare_builds(root, part_scan)
+            root, repl_pages = self.runner.prepare_builds(
+                root, part_scan, results, spec.split_batch_rows
+            )
             task.stats.execute_ms += (
                 time.perf_counter() - t_build
             ) * 1000.0
@@ -1043,7 +1048,10 @@ class WorkerServer:
         ]
         for s in scans:
             if s is not part_scan and id(s) not in repl_pages:
-                repl_pages[id(s)] = self.runner._load_table(s)
+                repl_pages[id(s)] = (
+                    results[id(s)] if id(s) in results
+                    else self.runner._load_table(s)
+                )
 
         total = spec.split_end - spec.split_start
         batch = spec.split_batch_rows or max(total, 1)
@@ -1099,6 +1107,15 @@ class WorkerServer:
             root, scans, batches=len(ranges),
             cut_agg=isinstance(root, (N.AggregationNode, N.DistinctNode)),
         )
+        # partitioned output this worker's own exchange segment carries
+        # stays on the device: the batch reads back its control outputs
+        # alone, and the page keeps its static shape
+        on_device = (
+            bool(spec.partition_keys)
+            and not spec.dynfilter_keys
+            and not pushed_ops
+            and exchange_spi.keeps_on_device(spec, self.slice_id)
+        )
 
         def exec_batch(split_page, release):
             pages = [
@@ -1107,12 +1124,15 @@ class WorkerServer:
             ]
             t_exec = time.perf_counter()
             try:
-                out = self.runner._collect(
-                    self.runner._dispatch(resolved, pages)
-                )
+                pending = self.runner._dispatch(resolved, pages)
+                if on_device:
+                    return self.runner._collect(
+                        pending, fetch_result=False, cut=False
+                    )
+                out = self.runner._collect(pending)
                 if pushed_ops:
                     out = apply_host_ops(out, pushed_ops)
-                return out
+                return out, None
             finally:
                 task.stats.execute_ms += (
                     time.perf_counter() - t_exec
@@ -1126,7 +1146,7 @@ class WorkerServer:
         # coordinator reads the merged summary off the status response
         summary_cell: List = []
 
-        def emit(out) -> None:
+        def emit(out, n) -> None:
             if spec.dynfilter_keys:
                 from presto_tpu.exec import dynfilter
 
@@ -1138,7 +1158,7 @@ class WorkerServer:
                 )
                 summary_cell.append(s)
                 return
-            if spec.n_partitions > 1:
+            if spec.partition_keys:
                 # partitioned output rides the unified exchange SPI:
                 # the scheduler-chosen transport (device-resident ICI
                 # publish for in-slice stages, serialized HTTP buffers
@@ -1146,7 +1166,7 @@ class WorkerServer:
                 return exchange_spi.emit_partitioned(
                     task, out,
                     slice_id=self.slice_id, pool=self.memory_pool,
-                    fold=self.runner._fold_device_stat,
+                    fold=self.runner._fold_device_stat, n=n,
                 )
             self._emit_result(task, out)
 
@@ -1177,12 +1197,30 @@ class WorkerServer:
         )
         try:
             for page, release in batches:
-                emit(exec_batch(page, release))
+                emit(*exec_batch(page, release))
         finally:
             # deterministic close: joins the prefetch thread and
             # drops queued batches BEFORE _run_task's release-all
             batches.close()
         finish_summary()
+
+    def _leaf_pages(self, spec: FragmentSpec) -> Dict[int, object]:
+        """The pages of the fragment's remote sources that read an
+        earlier stage's result, which the spec carries
+        (``FragmentSpec.leaf_pages``), staged, by id of the node."""
+        if not spec.leaf_pages:
+            return {}
+        import base64
+
+        walk = list(N.walk(spec.fragment))
+        out = {}
+        for i, wire in spec.leaf_pages:
+            node = walk[i]
+            payload, _schema, _n = pages_wire.deserialize_page(
+                base64.b64decode(wire)
+            )
+            out[id(node)] = stage_page(payload, dict(node.output_schema()))
+        return out
 
     def _emit_result(self, task: "_Task", out) -> None:
         """Root-stage (single-partition) result emit: when the
@@ -1244,9 +1282,14 @@ class WorkerServer:
         max_rows = int(self.runner.session.get("max_device_rows"))
         fold = self.runner._fold_device_stat
         ici_srcs = tuple(s for k, _, s in entries if k == "ici")
+        # one worker's own pages (every destination None: one partition)
+        # need no collective to route them
+        routed = any(
+            d is not None for k, b, _ in entries if k == "ici" for _, d in b
+        )
         if entries and len(ici_srcs) == len(entries):
             res = None
-            if self.single_program:
+            if self.single_program and routed:
                 try:
                     res = exchange_spi.collective_merge(
                         self.slice_id,
@@ -1276,6 +1319,7 @@ class WorkerServer:
                         rschema,
                         max_rows=max_rows,
                         fold=fold,
+                        page_rows=self._page_rows(task.spec),
                     )
                 except Exception:
                     REGISTRY.counter(
@@ -1292,7 +1336,7 @@ class WorkerServer:
                     task.stats.input_rows += total
                 return page, None
         spliced = None
-        if self.single_program and ici_srcs:
+        if self.single_program and ici_srcs and routed:
             try:
                 spliced = exchange_spi.collective_payloads(
                     self.slice_id,
@@ -1530,8 +1574,6 @@ class WorkerServer:
             # group's entries merge + stage separately (on device when
             # the whole group arrived in-slice), then the fragment
             # runs once over all leaves
-            import numpy as np
-
             pages = []
             for i, r in enumerate(remotes):
                 rschema = dict(r.fragment_root.output_schema())
@@ -1576,23 +1618,30 @@ class WorkerServer:
                 f"got {len(remotes)}"
             )
         schema = dict(remotes[0].fragment_root.output_schema())
-        page0, payloads = self._merge_group_page(
-            task, by_group.get(0, []), schema
-        )
+        # the merge on the device: its buffer, then the final step
+        with tracing.phase("exec", site="merge_final"):
+            page0, payloads = self._merge_group_page(
+                task, by_group.get(0, []), schema
+            )
+            if page0 is not None:
+                # all-in-slice merge: the input page was assembled on
+                # device (bit-compatible with the wire path's staged page)
+                staged = sum(int(b.data.nbytes) for b in page0.blocks)
+                self.memory_pool.reserve(spec.query_id, staged)
+                task.stats.input_bytes += staged
+                t_exec = time.perf_counter()
+                try:
+                    out = self._run_final(
+                        root, remotes[0], page0,
+                        sum(len(b) for _, b, _ in by_group.get(0, [])),
+                        self._page_rows(spec),
+                    )
+                finally:
+                    task.stats.execute_ms += (
+                        time.perf_counter() - t_exec
+                    ) * 1000.0
+                    self.memory_pool.release(spec.query_id, staged)
         if page0 is not None:
-            # all-in-slice merge: the input page was assembled on
-            # device (bit-compatible with the wire path's staged page)
-            staged = sum(int(b.data.nbytes) for b in page0.blocks)
-            self.memory_pool.reserve(spec.query_id, staged)
-            task.stats.input_bytes += staged
-            t_exec = time.perf_counter()
-            try:
-                out = self.runner._run_with_pages(root, remotes, [page0])
-            finally:
-                task.stats.execute_ms += (
-                    time.perf_counter() - t_exec
-                ) * 1000.0
-                self.memory_pool.release(spec.query_id, staged)
             self._emit_result(task, out)
             return
         # same grouped-execution discipline as the coordinator gather:
@@ -1610,6 +1659,7 @@ class WorkerServer:
             int(self.runner.session.get("max_device_rows")),
         )
         if out is None:
+            DEVICE.count_host_merge(sum(n for _, _, n in payloads))
             merged = pages_wire.merge_payloads(payloads, schema)
             page = stage_page(merged, schema)
             staged = sum(int(b.data.nbytes) for b in page.blocks)
@@ -1624,6 +1674,42 @@ class WorkerServer:
                 ) * 1000.0
                 self.memory_pool.release(spec.query_id, staged)
         self._emit_result(task, out)
+
+    def _page_rows(self, spec: FragmentSpec) -> int:
+        """A batch's rows as the stage runs them: the coordinator's
+        ``page_capacity``, which a merge task's spec carries too."""
+        return spec.split_batch_rows or int(
+            self.runner.session.get("page_capacity")
+        )
+
+    def _run_final(self, root, remote, page, batches: int, page_rows: int):
+        """The merge fragment over the page ``device_merge`` built: a
+        page larger than a batch's runs as page-sized chunks of whole
+        groups (``exchange_spi.merge_chunks``), the chunks' results put
+        together on the host (after a final aggregation and its HAVING
+        they are the stage's result rows)."""
+        from presto_tpu.exec import streaming as S
+
+        chunks = None
+        if page.capacity > page_rows:
+            chunks = exchange_spi.merge_chunks(
+                page, S._bucket_key_names(remote.fragment_root),
+                page.num_valid, page_rows, batches,
+                fold=self.runner._fold_device_stat,
+            )
+        if chunks is None:
+            return self.runner._run_with_pages(root, [remote], [page])
+        schema = dict(root.output_schema())
+        outs = [
+            S._page_to_payload(self.runner._run_with_pages(root, [remote], [c]))
+            for c in chunks
+        ]
+        outs = [o for o in outs if o[2]]
+        if not outs:
+            return stage_page(
+                {n: np.empty(0, t.np_dtype) for n, t in schema.items()}, schema
+            )
+        return stage_page(pages_wire.merge_payloads(outs, schema), schema)
 
     # ------------------------------------------------------------- status
 

@@ -129,6 +129,10 @@ class DeviceTelemetry:
         self._agg_row_moves = REGISTRY.counter("device.agg_row_moves")
         # key lanes the join probes of a program sort
         self._join_probe_lanes = REGISTRY.counter("device.join_probe_lanes")
+        # partial groups a final aggregation merges: slots on the device,
+        # rows on the host
+        self._agg_merge_slots = REGISTRY.counter("device.agg_merge_slots")
+        self._host_merge_rows = REGISTRY.counter("device.host_merge_rows")
         # does the results long-poll engage (server/rpc.pull_pages)
         self._results_waits = REGISTRY.counter("worker.results_waits")
         self._results_wait_timeouts = REGISTRY.counter(
@@ -211,6 +215,22 @@ class DeviceTelemetry:
         device."""
         if self.enabled and n:
             self._join_probe_lanes.update(int(n))
+
+    def count_agg_merge(self, slots: int) -> None:
+        """The slots of the pages of partial groups that one final
+        aggregation merges on the device (``exchange_spi.device_merge``),
+        as the merge program reads them: shapes only, nothing read from
+        the device."""
+        if self.enabled:
+            self._agg_merge_slots.update(int(slots))
+
+    def count_host_merge(self, rows: int) -> None:
+        """The rows of pages of partial groups that a final aggregation
+        merges on the host (``pages_wire.merge_payloads`` at the
+        coordinator's gather, ``streaming.grouped_final_merge``), counted
+        from the payloads it holds."""
+        if self.enabled and rows:
+            self._host_merge_rows.update(int(rows))
 
     def count_compile(self, ms: float) -> None:
         """A fresh compile-cache entry paid trace + XLA compile.
@@ -298,7 +318,9 @@ class DeviceTelemetry:
         programs' grouped aggregations issue (0 where no aggregation
         takes the lane-by-lane branch). ``join_probe_lanes`` counts the
         key lanes the dispatched programs' join probes sort (one a probe
-        on one uint32 lane, two on two).
+        on one uint32 lane, two on two). ``agg_merge_slots`` counts the
+        slots of partial pages that final aggregations merge on the
+        device, ``host_merge_rows`` the rows of those merged on the host.
         ``stage_col_hits`` / ``stage_col_misses`` count the columns a
         streamed split batch found resident in the staging cache or had
         to stage, ``stage_evictions`` the entries it dropped for room
@@ -327,6 +349,8 @@ class DeviceTelemetry:
             "join_build_slots": int(self._join_build_slots.total),
             "agg_row_moves": int(self._agg_row_moves.total),
             "join_probe_lanes": int(self._join_probe_lanes.total),
+            "agg_merge_slots": int(self._agg_merge_slots.total),
+            "host_merge_rows": int(self._host_merge_rows.total),
             "xla_compiles": xla["requests"] - xla["cache_loads"],
             "xla_cache_loads": xla["cache_loads"],
             "xla_compile_ms": xla["compile_s"] * 1000.0,

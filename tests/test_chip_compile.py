@@ -329,6 +329,12 @@ CASES = {
     "partition_exchange[2x2 mesh]": _case_partition_exchange,
 }
 
+#: cases compiled one at a time ahead of the others: side by side with
+#: them the compiler takes this one four times as long as alone, most of
+#: it in one thread (1,112 s against 279 s on an 8-core x86 host, the
+#: whole file 643 s where it was 1,115 s), and it bounded the file's time
+ALONE = ("hash_aggregate[double, date keys]",)
+
 
 # ------------------------------------------------------------- fixtures
 
@@ -357,10 +363,11 @@ def mesh(topo):
 
 @pytest.fixture(scope="module")
 def compiled(one_chip, mesh):
-    """Every case lowered and compiled once, side by side (the compiler
-    releases the GIL; a sort costs it tens of seconds whatever the row
-    count), persistent cache off so nothing is read from or written to
-    it. Maps case name -> (compiled | exception, seconds)."""
+    """Every case lowered and compiled once — those of ``ALONE`` one at
+    a time first, then the rest side by side (the compiler releases the
+    GIL; a sort costs it tens of seconds whatever the row count) —
+    persistent cache off so nothing is read from or written to it. Maps
+    case name -> (compiled | exception, seconds)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
@@ -377,8 +384,11 @@ def compiled(one_chip, mesh):
         return name, (out, time.monotonic() - t0)
 
     try:
-        with concurrent.futures.ThreadPoolExecutor(len(CASES)) as pool:
-            yield dict(pool.map(one, CASES))
+        out = dict(one(name) for name in ALONE)
+        rest = [name for name in CASES if name not in ALONE]
+        with concurrent.futures.ThreadPoolExecutor(len(rest)) as pool:
+            out.update(pool.map(one, rest))
+        yield out
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
